@@ -53,7 +53,6 @@ from __future__ import annotations
 import argparse
 import json
 import os
-import subprocess
 import sys
 import time
 
@@ -61,6 +60,7 @@ import numpy as np
 
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, _REPO)
+from raft_tpu.utils.provenance import git_commit  # noqa: E402
 OUT_PATH = os.path.join(_REPO, "BENCH_ANN.json")
 SCHEMA = 2
 RECALL_FLOOR = 0.95
@@ -80,19 +80,6 @@ PQ_SCALE_LISTS = 50_000
 # per-platform shapes: (rows, d, nq, k, n_lists sweep)
 TPU_SHAPE = (1_000_000, 128, 2048, 10, (1024,))
 CPU_SHAPE = (20_000, 32, 256, 10, (16, 64))
-
-
-def _git_commit() -> str:
-    try:
-        r = subprocess.run(["git", "-C", _REPO, "rev-parse", "--short",
-                            "HEAD"], capture_output=True, text=True,
-                           timeout=10)
-        head = r.stdout.strip() or "unknown"
-        s = subprocess.run(["git", "-C", _REPO, "status", "--porcelain"],
-                           capture_output=True, text=True, timeout=10)
-        return head + "-dirty" if s.stdout.strip() else head
-    except Exception:
-        return "unknown"
 
 
 def _pq_cert_counts():
@@ -140,6 +127,9 @@ def _probe_schedule(L: int):
 
 
 def main(argv=None) -> int:
+    from raft_tpu.utils.compile_cache import use_compile_cache
+
+    use_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--rows", type=int, default=None)
     ap.add_argument("--dim", type=int, default=None)
@@ -475,7 +465,7 @@ def main(argv=None) -> int:
         "chip": spec.name,
         "errors": errors[:8],
         "platform": jax.default_backend(),
-        "git_commit": _git_commit(),
+        "git_commit": git_commit(),
         "timestamp": time.strftime("%Y-%m-%dT%H:%M:%SZ", time.gmtime()),
     }
     if degr:

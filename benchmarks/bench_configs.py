@@ -11,7 +11,7 @@ Configs (BASELINE.json "configs"):
      requires >1 physical chips; otherwise only code-path timings are
      recorded and the row is tagged ``representative: false``.
 
-Probe-guarded like bench.py; RAFT_TPU_BENCH_FORCE=cpu runs a tiny-scale
+Fails without a TPU like bench.py; JAX_PLATFORMS=cpu runs a tiny-scale
 dry-run to validate the harness without recording an artifact.
 """
 
@@ -28,10 +28,7 @@ OUT = os.path.join(os.path.dirname(__file__), os.pardir, "CONFIG_BENCH.json")
 
 
 def main():
-    dry, skip = gate()
-    if skip:
-        print(json.dumps({"skipped": skip}))
-        return 0
+    dry = gate()
 
     import jax
     import jax.numpy as jnp
@@ -137,7 +134,6 @@ def main():
         # sizes-sweep harness is benchmarks/bench_busbw.py; this row is
         # its 64 MB point so CONFIG_BENCH stays one-command.
         from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P)
-        from jax.experimental.shard_map import shard_map
 
         devices = jax.devices()
         ndev = len(devices)
@@ -146,10 +142,10 @@ def main():
         xs = jax.device_put(jnp.ones((ndev, per_rank // 4), jnp.float32),
                             NamedSharding(mesh, P("x", None)))
         jax.block_until_ready(xs)
-        ar = jax.jit(shard_map(lambda a: jax.lax.psum(a, "x"), mesh=mesh,
+        ar = jax.jit(jax.shard_map(lambda a: jax.lax.psum(a, "x"), mesh=mesh,
                                in_specs=P("x", None),
                                out_specs=P("x", None)))
-        ag = jax.jit(shard_map(
+        ag = jax.jit(jax.shard_map(
             lambda a: jax.lax.all_gather(a, "x", axis=0, tiled=True),
             mesh=mesh, in_specs=P("x", None), out_specs=P("x", None)))
         if devices[0].platform != "tpu":

@@ -37,7 +37,6 @@ from __future__ import annotations
 import argparse
 import json
 import os
-import subprocess
 import sys
 import threading
 import time
@@ -46,6 +45,7 @@ import numpy as np
 
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, _REPO)
+from raft_tpu.utils.provenance import git_commit  # noqa: E402
 OUT_PATH = os.path.join(_REPO, "BENCH_MUTATION.json")
 SCHEMA = 1
 RECALL_FLOOR = 0.95
@@ -54,19 +54,6 @@ RECALL_FLOOR = 0.95
 # (index rows, d, k, n_reads, readers, write_batches, upserts/batch)
 TPU_SHAPE = (1_000_000, 128, 64, 1500, 6, 40, 256)
 CPU_SHAPE = (2048, 32, 8, 120, 3, 10, 32)
-
-
-def _git_commit() -> str:
-    try:
-        r = subprocess.run(["git", "-C", _REPO, "rev-parse", "--short",
-                            "HEAD"], capture_output=True, text=True,
-                           timeout=10)
-        head = r.stdout.strip() or "unknown"
-        s = subprocess.run(["git", "-C", _REPO, "status", "--porcelain"],
-                           capture_output=True, text=True, timeout=10)
-        return head + "-dirty" if s.stdout.strip() else head
-    except Exception:
-        return "unknown"
 
 
 def _fold_windows():
@@ -86,6 +73,9 @@ def _fold_windows():
 
 
 def main(argv=None) -> int:
+    from raft_tpu.utils.compile_cache import use_compile_cache
+
+    use_compile_cache()
     p = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     p.add_argument("--reads", type=int, default=None)
     p.add_argument("--readers", type=int, default=None)
@@ -287,7 +277,7 @@ def main(argv=None) -> int:
         "shed": st.get("shed", 0),
         "errors": errors[:8],
         "platform": jax.default_backend(),
-        "git_commit": _git_commit(),
+        "git_commit": git_commit(),
         "timestamp": time.strftime("%Y-%m-%dT%H:%M:%SZ", time.gmtime()),
     }
     try:

@@ -1,7 +1,7 @@
 #!/usr/bin/env python
 """North-star-shape measurement: fused KNN at 10M×256, k=64.
 
-(VERDICT r2 item 2; the BASELINE.json "metric" shape. Until this runs,
+(round-2 review item 2; the BASELINE.json "metric" shape. Until this runs,
 the project's central claim is unevidenced at its own declared scale.)
 
 A 10M×256 f32 index is ~10.2 GB — more than half of v5e's 16 GB HBM
@@ -33,10 +33,7 @@ OUT = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
 
 
 def main():
-    dry, skip = gate()
-    if skip:
-        print(json.dumps({"skipped": True, "reason": skip}))
-        return
+    dry = gate()
 
     import jax
     import jax.numpy as jnp

@@ -34,7 +34,6 @@ import argparse
 import json
 import os
 import shutil
-import subprocess
 import sys
 import tempfile
 import time
@@ -43,6 +42,7 @@ import numpy as np
 
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, _REPO)
+from raft_tpu.utils.provenance import git_commit  # noqa: E402
 OUT_PATH = os.path.join(_REPO, "BENCH_RECOVERY.json")
 SCHEMA = 1
 
@@ -55,19 +55,6 @@ CPU_SHAPE = (512, 32, 8, 12, 16, (16, 48))
 # an absolute wall-clock promise across machines)
 TPU_RECOVERY_BOUND_MS = 30_000.0
 CPU_RECOVERY_BOUND_MS = 120_000.0
-
-
-def _git_commit() -> str:
-    try:
-        r = subprocess.run(["git", "-C", _REPO, "rev-parse", "--short",
-                            "HEAD"], capture_output=True, text=True,
-                           timeout=10)
-        head = r.stdout.strip() or "unknown"
-        s = subprocess.run(["git", "-C", _REPO, "status", "--porcelain"],
-                           capture_output=True, text=True, timeout=10)
-        return head + "-dirty" if s.stdout.strip() else head
-    except Exception:
-        return "unknown"
 
 
 def _live_state(idx) -> dict:
@@ -104,6 +91,9 @@ def _drive_writes(idx, model, rng, batches: int, wbatch: int,
 
 
 def main(argv=None) -> int:
+    from raft_tpu.utils.compile_cache import use_compile_cache
+
+    use_compile_cache()
     p = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     p.add_argument("--write-batches", type=int, default=None)
     p.add_argument("--seed", type=int, default=0)
@@ -225,7 +215,7 @@ def main(argv=None) -> int:
         "rows_per_batch": wbatch,
         "errors": errors[:8],
         "platform": jax.default_backend(),
-        "git_commit": _git_commit(),
+        "git_commit": git_commit(),
         "timestamp": time.strftime("%Y-%m-%dT%H:%M:%SZ", time.gmtime()),
     }
     if degr:

@@ -37,7 +37,6 @@ from __future__ import annotations
 import argparse
 import json
 import os
-import subprocess
 import sys
 import threading
 import time
@@ -46,6 +45,7 @@ import numpy as np
 
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, _REPO)
+from raft_tpu.utils.provenance import git_commit  # noqa: E402
 OUT_PATH = os.path.join(_REPO, "BENCH_SERVING.json")
 TRACE_PATH = os.path.join(_REPO, "BENCH_SERVING_TRACE.json")
 SCHEMA = 1
@@ -53,19 +53,6 @@ SCHEMA = 1
 # per-platform shapes: (index rows, d, k, n_requests, clients)
 TPU_SHAPE = (1_000_000, 128, 64, 2000, 8)
 CPU_SHAPE = (4096, 32, 8, 120, 4)
-
-
-def _git_commit() -> str:
-    try:
-        r = subprocess.run(["git", "-C", _REPO, "rev-parse", "--short",
-                            "HEAD"], capture_output=True, text=True,
-                           timeout=10)
-        head = r.stdout.strip() or "unknown"
-        s = subprocess.run(["git", "-C", _REPO, "status", "--porcelain"],
-                           capture_output=True, text=True, timeout=10)
-        return head + "-dirty" if s.stdout.strip() else head
-    except Exception:
-        return "unknown"
 
 
 def _compile_miss_count() -> int:
@@ -159,6 +146,9 @@ def run_load(engine, queries, sizes, n_requests: int, clients: int,
 
 
 def main(argv=None) -> int:
+    from raft_tpu.utils.compile_cache import use_compile_cache
+
+    use_compile_cache()
     p = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     p.add_argument("--clients", type=int, default=None)
     p.add_argument("--requests", type=int, default=None)
@@ -299,7 +289,7 @@ def main(argv=None) -> int:
         "parity_checked": parity_checked,
         "errors": errors[:8],
         "platform": jax.default_backend(),
-        "git_commit": _git_commit(),
+        "git_commit": git_commit(),
         "timestamp": time.strftime("%Y-%m-%dT%H:%M:%SZ", time.gmtime()),
     }
     # quality block (ISSUE 10): fixup-rate counters from the serving

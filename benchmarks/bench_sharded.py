@@ -29,7 +29,6 @@ from __future__ import annotations
 
 import json
 import os
-import subprocess
 import sys
 import time
 
@@ -37,6 +36,7 @@ import numpy as np
 
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, _REPO)
+from raft_tpu.utils.provenance import git_commit  # noqa: E402
 OUT_PATH = os.path.join(_REPO, "MULTICHIP_SHARDED.json")
 TRACE_PATH = os.path.join(_REPO, "MULTICHIP_SHARDED_TRACE.json")
 DRIFT_PATH = os.path.join(_REPO, "DRIFT_LEDGER.json")
@@ -57,26 +57,14 @@ def _ensure_virtual_devices(n: int = 8) -> None:
         ).strip()
 
 
-def _git_commit() -> str:
-    try:
-        r = subprocess.run(["git", "-C", _REPO, "rev-parse", "--short",
-                            "HEAD"], capture_output=True, text=True,
-                           timeout=10)
-        head = r.stdout.strip() or "unknown"
-        s = subprocess.run(["git", "-C", _REPO, "status", "--porcelain"],
-                           capture_output=True, text=True, timeout=10)
-        return head + "-dirty" if s.stdout.strip() else head
-    except Exception:
-        return "unknown"
-
-
 def main() -> int:
+    from raft_tpu.utils.compile_cache import use_compile_cache
+
+    use_compile_cache()
     if os.environ.get("JAX_PLATFORMS", "").startswith("cpu"):
         _ensure_virtual_devices()
     import jax
 
-    if os.environ.get("JAX_PLATFORMS", "").startswith("cpu"):
-        jax.config.update("jax_platforms", "cpu")
     measured = jax.default_backend() == "tpu" and len(jax.devices()) > 1
     if not measured and jax.default_backend() != "tpu":
         _ensure_virtual_devices()
@@ -228,7 +216,7 @@ def main() -> int:
         "quantized": quantized,
         "strategies": strategies,
         "platform": jax.default_backend(),
-        "git_commit": _git_commit(),
+        "git_commit": git_commit(),
         "timestamp": time.strftime("%Y-%m-%dT%H:%M:%SZ", time.gmtime()),
     }
     # quality block (ISSUE 10): per-shard certificate/fixup counters

@@ -1,4 +1,4 @@
-"""Unexpanded pairwise metrics at scale (VERDICT r3 item 5).
+"""Unexpanded pairwise metrics at scale (round-3 review item 5).
 
 Measures the streaming Pallas kernel (ops/unexpanded_pallas.py) and the
 jitted-XLA fused path at the driver shape (2048×1M×128) plus a smaller
@@ -25,13 +25,9 @@ OUT = os.path.join(os.path.dirname(__file__), os.pardir,
 
 
 def main():
-    dry, skip = gate()
+    dry = gate()
     results = {"platform": "tpu" if not dry else "cpu-forced",
                "unit": "ms", "representative": not dry}
-    if skip:
-        results["skipped"] = skip
-        print(json.dumps(results))
-        return
     import jax
     import jax.numpy as jnp
     import numpy as np

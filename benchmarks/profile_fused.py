@@ -15,7 +15,7 @@ actual bottleneck instead of a guess. Stages, each timed separately:
 
 The non-dry config is ``fused_defaults()`` — the config production
 ``knn_fused`` actually ships. Writes PROFILE_FUSED.json (repo root)
-incrementally. Probe-guarded; RAFT_TPU_BENCH_FORCE=cpu runs a tiny-shape
+incrementally. Fails without a TPU; JAX_PLATFORMS=cpu runs a tiny-shape
 harness validation (no artifact).
 """
 
@@ -33,10 +33,7 @@ OUT = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
 
 
 def main():
-    dry, skip = gate()
-    if skip:
-        print(json.dumps({"skipped": True, "reason": skip}))
-        return
+    dry = gate()
 
     import jax
     import jax.numpy as jnp

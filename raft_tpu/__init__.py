@@ -33,31 +33,6 @@ surface TPU-first:
   (ref: core/nvtx.hpp + mr/resource_monitor.hpp, unified)
 """
 
-import jax as _jax
-
-# jax promoted shard_map out of jax.experimental (~0.5); the sharded
-# primitives are written against the new ``jax.shard_map`` spelling.
-# Alias it on older jax so the comms/sharded layers (and their tier-1
-# coverage) work on both sides of the promotion — this package is always
-# imported before any submodule, so one gated alias covers every call
-# site. (Same pattern as the pltpu.CompilerParams shim in ops/utils.py.)
-if not hasattr(_jax, "shard_map"):
-    try:
-        import functools as _functools
-
-        from jax.experimental.shard_map import shard_map as _shard_map
-
-        @_functools.wraps(_shard_map)
-        def _compat_shard_map(*args, **kwargs):
-            # new-jax kwarg spelling → old (check_vma was check_rep)
-            if "check_vma" in kwargs:
-                kwargs["check_rep"] = kwargs.pop("check_vma")
-            return _shard_map(*args, **kwargs)
-
-        _jax.shard_map = _compat_shard_map
-    except ImportError:
-        pass
-
 from raft_tpu.version import __version__
 
 from raft_tpu.core import (

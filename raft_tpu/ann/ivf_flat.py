@@ -62,7 +62,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from raft_tpu.core import env
-from raft_tpu.core.error import DeadlineExceededError, expects
+from raft_tpu.core.error import DeviceError, expects
 from raft_tpu.core.resources import ensure_resources
 from raft_tpu.observability import explain, instrument
 from raft_tpu.observability.flight import get_flight_recorder
@@ -420,10 +420,11 @@ def _fine_scan_q8(x, slab, slab_q, row_scale, ids, yy_q, starts, psizes,
 class _ListSchedule:
     """Host-built list-major schedule for one query chunk: the
     transposed probe table. ``sched [4, Lp]`` int32 rows are (clamped
-    window start, real list length, list offset within the window,
-    list id); Lp is padded to the 8-list cell quantum with the cell
-    count rounded to a power of two (capped at the index's own cell
-    count), so one compiled program serves a whole probes sweep. The
+    window start, real rows in the window, their offset within the
+    window, list id) — one entry per kernel window of a probed list;
+    Lp is padded to the 8-entry cell quantum with the cell count
+    rounded to a power of two (capped at the index's own cell count),
+    so one compiled program serves a whole probes sweep. The
     [L_probed, q_max] query-group table (q_max padded to 8) + its
     never-wins mask ride along for the cost model and tests — the
     kernel itself consumes the resident probe table directly."""
@@ -442,20 +443,38 @@ class _ListSchedule:
         self.stream_rows = stream_rows
 
 
-def _list_cells(n_probed: int, n_lists: int) -> int:
-    """Schedule cell count: probed lists bucket into 8-list cells,
-    rounded up to a power of two (compile-cache stability across
-    batches) and capped at the whole index's cell count."""
+def _list_cells(n_entries: int, max_entries: int) -> int:
+    """Schedule cell count: entries bucket into 8-entry cells, rounded
+    up to a power of two (compile-cache stability across batches) and
+    capped at the cells of the whole index (:func:`_max_entries`)."""
     from raft_tpu.ops.fine_scan_pallas import LISTS_PER_CELL
 
-    cells = max(1, -(-n_probed // LISTS_PER_CELL))
-    cap = max(1, -(-n_lists // LISTS_PER_CELL))
+    cells = max(1, -(-n_entries // LISTS_PER_CELL))
+    cap = max(1, -(-max_entries // LISTS_PER_CELL))
     return min(1 << (cells - 1).bit_length(), cap)
+
+
+def _list_segments(index: IvfFlatIndex, lists) -> np.ndarray:
+    """Schedule entries per list: one per kernel window of its rows
+    (an empty list still takes one)."""
+    from raft_tpu.ops.fine_scan_pallas import pad_window
+
+    Wk = pad_window(index.probe_window)
+    return np.maximum(1, -(-index._np_sizes[lists] // Wk))
+
+
+def _max_entries(index: IvfFlatIndex,
+                 n_probed: Optional[int] = None) -> int:
+    """Most schedule entries of a chunk that probes at most
+    ``n_probed`` distinct lists (default: every list)."""
+    seg = np.sort(_list_segments(index, np.arange(index.n_lists)))
+    return int(seg[::-1][:n_probed].sum())
 
 
 def build_list_schedule(index: IvfFlatIndex, probes_np) -> _ListSchedule:
     """Invert a chunk's per-query probe lists [nq, P] into the
-    per-list query-group schedule (see :class:`_ListSchedule`).
+    per-list query-group schedule (see :class:`_ListSchedule`); a list
+    longer than the kernel window takes one entry per window of rows.
     Host-side numpy — the probe table is tiny next to the slab."""
     from raft_tpu.ops.fine_scan_pallas import (LISTS_PER_CELL,
                                                pad_window)
@@ -467,18 +486,22 @@ def build_list_schedule(index: IvfFlatIndex, probes_np) -> _ListSchedule:
     Lp = int(plist.size)
     Wk = pad_window(index.probe_window)
     R = index.slab_rows
-    Lp_pad = _list_cells(Lp, index.n_lists) * LISTS_PER_CELL
+    nseg = _list_segments(index, plist)
+    lid = np.repeat(plist, nseg)
+    seg = np.arange(lid.size) - np.repeat(np.cumsum(nseg) - nseg, nseg)
+    n_ent = int(lid.size)
+    Lp_pad = _list_cells(n_ent, _max_entries(index)) * LISTS_PER_CELL
     sched = np.zeros((4, Lp_pad), np.int32)
     sched[3, :] = -1
-    starts = index._np_offsets[plist].astype(np.int64)
+    starts = index._np_offsets[lid].astype(np.int64) + seg * Wk
     clamped = np.clip(np.minimum(starts, R - Wk), 0, None)
-    sched[0, :Lp] = clamped
-    sched[1, :Lp] = index._np_sizes[plist]
-    sched[2, :Lp] = starts - clamped
-    sched[3, :Lp] = plist
+    sched[0, :n_ent] = clamped
+    sched[1, :n_ent] = np.clip(index._np_sizes[lid] - seg * Wk, 0, Wk)
+    sched[2, :n_ent] = starts - clamped
+    sched[3, :n_ent] = lid
     scale_l = np.ones(Lp_pad, np.float32)
     if index.db_dtype == "int8":
-        scale_l[:Lp] = _list_host(index)["scale"][plist]
+        scale_l[:n_ent] = _list_host(index)["scale"][lid]
     # the transposed [L_probed, q_max] query-group table: group g holds
     # the query indices probing plist[g], padded to the 8-row quantum
     # with the never-wins mask marking real entries
@@ -777,7 +800,7 @@ def warm_fine_scan(res, index: IvfFlatIndex, nq: int, k: int,
         return 0
     chunk = max(8, _FINE_TILE // max(1, P * W * max(d, 1)))
     sizes = sorted({min(nq, chunk), nq % chunk or min(nq, chunk)})
-    cap = max(1, -(-index.n_lists // LISTS_PER_CELL))
+    cap = max(1, -(-_max_entries(index) // LISTS_PER_CELL))
     rungs = sorted({min(1 << b, cap)
                     for b in range(cap.bit_length() + 1)})
     host = _list_host(index)
@@ -1028,10 +1051,12 @@ def search_ivf_flat(res, index, queries, k: int,
     certified bit-identical to the query-major scan; int8 id sets
     identical, ties canonicalized to f32 position order), ``auto`` runs
     the :func:`resolve_fine_scan` cost-model crossover on the index's
-    actual probed-list histogram. A failing list-major dispatch
-    degrades back to query-major with a logged degradation (fault
-    site ``fine_scan_list``). The sharded path keeps the query-major
-    shard-local scan.
+    actual probed-list histogram. A list-major dispatch that raises a
+    :class:`~raft_tpu.core.error.DeviceError` (an injected fault or a
+    classified device failure) degrades back to query-major with a
+    logged degradation (fault site ``fine_scan_list``); any other
+    error, such as a kernel the compiler refuses, propagates. The
+    sharded path keeps the query-major shard-local scan.
 
     ``n_probes ≥ n_lists`` (or ``k`` beyond the probed capacity)
     degrades to EXACT search with a logged reason — the certified
@@ -1141,9 +1166,9 @@ def search_ivf_flat(res, index, queries, k: int,
             return _search_list_major(res, index, x, probes,
                                       probes_host, starts, psizes,
                                       k, P, W, chunk)
-        except DeadlineExceededError:
-            raise               # the caller's global budget — never eaten
-        except Exception as e:
+        except DeviceError as e:
+            # injected faults and classified device failures only: a
+            # kernel that fails to compile or lower propagates
             from raft_tpu.core.logger import log_warn
 
             record_degradation("fine_scan_list", "query")

@@ -41,10 +41,11 @@ compared against ``θ`` plus only the kernel-precision envelope — no
 per-list worst-case ``Eq`` widening. Certificate failures climb a
 three-rung ladder: (1) certified as-is, (2) the ``pq_widen`` rung
 re-runs the ADC scan with a 2×/4× deeper candidate pool and
-re-certifies, (3) the exact f32 rerun. The ``pq_scan`` fault site
-degrades any kernel failure to the f32/int8 query-major scan — so
-returned id sets NEVER degrade below the flat scan's, whatever the
-compression does to the approximate scores.
+re-certifies, (3) the exact f32 rerun. A device failure at the
+``pq_scan`` site (injected, or classified as a ``DeviceError``)
+degrades to the f32/int8 query-major scan — so returned id sets NEVER
+degrade below the flat scan's, whatever the compression does to the
+approximate scores. A kernel the compiler refuses propagates.
 
 ``pq_mode`` picks the quantizer: ``"plain"`` trains codebooks on raw
 residuals; ``"opq"`` learns an orthogonal rotation first (OPQ
@@ -73,7 +74,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from raft_tpu.core import env
-from raft_tpu.core.error import DeadlineExceededError, expects
+from raft_tpu.core.error import DeviceError, expects
 from raft_tpu.core.resources import ensure_resources
 from raft_tpu.observability import explain, instrument
 from raft_tpu.observability.quality import (record_certificate,
@@ -182,7 +183,7 @@ class IvfPqIndex(IvfFlatIndex):
         self.pq_rot = pq_rot                 # [d, d] f32 or None
         self.pq_eq_qlist = pq_eq_qlist       # [L, 3] q50/q90/max sketch
         self.pq_resid_med = float(pq_resid_med)  # median ‖y − c‖
-        self._pq_eq_col = None               # lazy [R, 1] kernel view
+        self._pq_views = None                # lazy ADC kernel views
 
     @property
     def dsub(self) -> int:
@@ -198,13 +199,16 @@ class IvfPqIndex(IvfFlatIndex):
         return self.pq_dim if self.pq_bits == 8 else self.pq_dim // 2
 
     @property
-    def pq_eq_col(self):
-        """[R, 1] device view of ``pq_eq_rows`` — the adaptive-
-        certificate sidecar the ADC kernel streams (built once)."""
-        if self._pq_eq_col is None:
-            self._pq_eq_col = jnp.reshape(
-                jnp.asarray(self.pq_eq_rows, jnp.float32), (-1, 1))
-        return self._pq_eq_col
+    def pq_kernel_views(self):
+        """(codes, ‖ŷ‖², Eq) in the layout the ADC kernel streams
+        (:func:`~raft_tpu.ops.pq_scan_pallas.kernel_layout` — built
+        once; the slab is immutable)."""
+        if self._pq_views is None:
+            from raft_tpu.ops.pq_scan_pallas import kernel_layout
+
+            self._pq_views = kernel_layout(self.codes, self.yy_pq,
+                                           self.pq_eq_rows)
+        return self._pq_views
 
     def __repr__(self):
         return (f"IvfPqIndex(n_rows={self.n_rows}, "
@@ -598,8 +602,8 @@ def pq_scan_chunk(index: IvfPqIndex, xs, probes_np, pr, st, ps,
     cdot = jnp.einsum("qd,ld->ql", xp, cents,
                       precision=jax.lax.Precision.HIGHEST)
     pool = pq_scan_list_major(
-        jnp.asarray(sched.sched), xxp, pp, cdot, lut, index.codes,
-        index.yy_pq, index.pq_eq_col, Wk=Wk, pq_bits=index.pq_bits,
+        jnp.asarray(sched.sched), xxp, pp, cdot, lut,
+        *index.pq_kernel_views, Wk=Wk, pq_bits=index.pq_bits,
         pool_depth=pool_depth)
     rows = jnp.concatenate(
         [pool[2 * t + 1][:nq] for t in range(pool_depth)], axis=1)
@@ -702,7 +706,8 @@ def resolve_pq_scan(index: IvfPqIndex, nq: int, k: int, P: int, W: int,
                                                   ivf_traffic_model)
     from raft_tpu.ops.fine_scan_pallas import pad_window
     from raft_tpu.ops.fused_l2_topk_pallas import vmem_budget
-    from raft_tpu.ops.pq_scan_pallas import pq_scan_vmem_footprint
+    from raft_tpu.ops.pq_scan_pallas import (kernel_rows, pq_window,
+                                             pq_scan_vmem_footprint)
     from raft_tpu.ops.utils import interpret_mode
 
     req = requested if requested is not None \
@@ -715,14 +720,15 @@ def resolve_pq_scan(index: IvfPqIndex, nq: int, k: int, P: int, W: int,
     Wk = pad_window(W)
     S, K = index.pq_dim, index.pq_k
     nqp = -(-min(nq, chunk or nq) // 8) * 8
-    from raft_tpu.ann.ivf_flat import _list_cells
+    from raft_tpu.ann.ivf_flat import _list_cells, _max_entries
     from raft_tpu.ops.fine_scan_pallas import LISTS_PER_CELL
 
-    Lp = _list_cells(min(nq, chunk or nq) * P, index.n_lists) \
-        * LISTS_PER_CELL
+    Lp = _list_cells(_max_entries(index, min(nq, chunk or nq) * P),
+                     _max_entries(index)) * LISTS_PER_CELL
     reason = None
-    if index.slab_rows < Wk:
-        reason = f"slab rows {index.slab_rows} < kernel window {Wk}"
+    if kernel_rows(index.slab_rows) < pq_window(Wk):
+        reason = (f"slab rows {index.slab_rows} < kernel window "
+                  f"{pq_window(Wk)}")
     elif k > _LIST_K_MAX:
         reason = f"k={k} > {_LIST_K_MAX} exceeds the candidate pool"
     elif P > 128:
@@ -784,9 +790,10 @@ def search_ivf_pq(res, index: IvfPqIndex, queries, k: int,
     rescored from the retained f32 slab — the mandatory refine), and
     the id set is certified identical to the flat scan's over the same
     probe lists: a failed completeness certificate reruns the exact
-    f32 scan for that chunk, and a failed kernel dispatch (fault site
-    ``pq_scan``) degrades to the f32/int8 query-major scan with a
-    recorded degradation.
+    f32 scan for that chunk, and a device failure at the kernel
+    dispatch (a ``DeviceError``, fault site ``pq_scan``) degrades to
+    the f32/int8 query-major scan with a recorded degradation; any
+    other error propagates.
 
     ``pq_scan`` ∈ :data:`PQ_SCANS` picks the schedule (``None`` reads
     ``RAFT_TPU_IVF_PQ_SCAN``); ``n_probes ≥ n_lists`` (or ``k`` past
@@ -862,9 +869,9 @@ def search_ivf_pq(res, index: IvfPqIndex, queries, k: int,
             fault_point("pq_scan")
             return _search_pq(res, index, x, probes, probes_host,
                               starts, psizes, k, P, W, chunk)
-        except DeadlineExceededError:
-            raise               # the caller's global budget — never eaten
-        except Exception as e:
+        except DeviceError as e:
+            # injected faults and classified device failures only: a
+            # kernel that fails to compile or lower propagates
             from raft_tpu.core.logger import log_warn
 
             record_degradation("pq_scan", "flat")
@@ -893,7 +900,7 @@ def _search_pq(res, index: IvfPqIndex, x, probes, probes_host, starts,
     pool, re-ADC, re-certify), and rerun whatever still fails through
     the exact f32 scan — returned id sets match the flat scan's over
     the same probes in EVERY case."""
-    from raft_tpu.ann.ivf_flat import _list_cells
+    from raft_tpu.ann.ivf_flat import _list_cells, _max_entries
     from raft_tpu.ops.fine_scan_pallas import (LISTS_PER_CELL,
                                                pad_window)
     from raft_tpu.ops.fused_l2_topk_pallas import vmem_budget
@@ -925,7 +932,8 @@ def _search_pq(res, index: IvfPqIndex, x, probes, probes_host, starts,
             # pool holds the near-boundary candidates
             Wk = pad_window(W)
             nqp = -(-nq_c // 8) * 8
-            Lp = _list_cells(nq_c * P, index.n_lists) * LISTS_PER_CELL
+            Lp = _list_cells(_max_entries(index, nq_c * P),
+                             _max_entries(index)) * LISTS_PER_CELL
             for factor in (2, 4):
                 if factor > widen_cap or not n_fail:
                     break
@@ -940,9 +948,8 @@ def _search_pq(res, index: IvfPqIndex, x, probes, probes_host, starts,
                     wv, wi, wok, _wm = pq_scan_chunk(
                         index, xs, probes_host[s0:s1], pr, st, ps,
                         k, P, W, pool_depth=depth)
-                except DeadlineExceededError:
-                    raise       # the global budget — never eaten
-                except Exception as e:
+                except DeviceError as e:
+                    # injected / classified device failures only
                     from raft_tpu.core.logger import log_warn
 
                     record_degradation("pq_widen", "exact")
@@ -1008,6 +1015,7 @@ def warm_pq_scan(res, index: IvfPqIndex, nq: int, k: int,
     never pays a compile whichever way the chooser (or the
     certificate) lands. Returns the warmed ADC program count (0 =
     outside the ADC envelope)."""
+    from raft_tpu.ann.ivf_flat import _max_entries
     from raft_tpu.ops.fine_scan_pallas import (LISTS_PER_CELL,
                                                pad_window)
     from raft_tpu.ops.pq_scan_pallas import pq_scan_list_major
@@ -1025,7 +1033,7 @@ def warm_pq_scan(res, index: IvfPqIndex, nq: int, k: int,
         return 0
     chunk = max(8, _FINE_TILE // max(1, P * W * max(d, 1)))
     sizes = sorted({min(nq, chunk), nq % chunk or min(nq, chunk)})
-    cap = max(1, -(-index.n_lists // LISTS_PER_CELL))
+    cap = max(1, -(-_max_entries(index) // LISTS_PER_CELL))
     rungs = sorted({min(1 << b, cap)
                     for b in range(cap.bit_length() + 1)})
     widen_cap = int(env.get("RAFT_TPU_ANN_PQ_WIDEN"))
@@ -1045,7 +1053,7 @@ def warm_pq_scan(res, index: IvfPqIndex, nq: int, k: int,
                 out = pq_scan_list_major(
                     jnp.asarray(sched), xx0, pp0,
                     jnp.zeros((nqp, Lp), jnp.float32), lut0,
-                    index.codes, index.yy_pq, index.pq_eq_col,
+                    *index.pq_kernel_views,
                     Wk=Wk, pq_bits=index.pq_bits, pool_depth=depth)
                 jax.block_until_ready(out)
                 warmed += 1

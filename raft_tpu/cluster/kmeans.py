@@ -235,12 +235,15 @@ def kmeans_fit(res, X, n_clusters: int, max_iter: int = 20,
     labels = jnp.zeros((n,), jnp.int32)
     inertia = float("inf")
     it = 0
+    stable = False
     for it in range(1, max_iter + 1):
         fault_point("kmeans_iteration")
         if balanced and balance_alpha > 0.0:
             weights = _balance_weights(counts, balance_alpha)
+        prev_labels = labels
         labels, ine, sums, counts = _assign_sweep(
             X, centroids, weights, k, res)
+        stable = it > 1 and bool(jnp.array_equal(labels, prev_labels))
         new_centroids = jnp.where(
             counts[:, None] > 0,
             sums / jnp.maximum(counts[:, None], 1.0), centroids)
@@ -257,6 +260,14 @@ def kmeans_fit(res, X, n_clusters: int, max_iter: int = 20,
             inertia = min(inertia, ine)
             break
         inertia = ine
+    if not stable:
+        # Stopped on the inertia tolerance or max_iter while labels were
+        # still moving: the labels belong to the centroids before the
+        # last update. Re-assign once so labels, inertia and sizes
+        # describe the returned centroids (sklearn's final E-step).
+        labels, ine, _, counts = _assign_sweep(
+            X, centroids, weights, k, res)
+        inertia = float(ine)
     return KMeansResult(centroids, labels, inertia, it,
                         counts.astype(jnp.int32))
 

@@ -183,11 +183,6 @@ _knob("RAFT_TPU_WAL_SEGMENT_MB", "float", 64.0,
       "WAL segment rotation size (MB)")
 
 # -- bench harness ------------------------------------------------------
-_knob("RAFT_TPU_BENCH_RETRY_S", "float", None,
-      "outage-riding retry budget for bench.py / measurement scripts")
-_knob("RAFT_TPU_BENCH_FORCE", "enum", None,
-      "harness-validation dry mode for benchmarks/* (cpu = tiny "
-      "shapes, no TPU artifacts)", choices=("cpu",))
 _knob("RAFT_TPU_SOLVERS_BUDGET_S", "float", None,
       "wall-clock budget for benchmarks/bench_solvers_scale.py")
 

@@ -94,7 +94,7 @@ def _is_xla_error(exc: BaseException) -> bool:
 
 
 def classify_xla_error(exc: BaseException) -> Optional[RaftException]:
-    """Map a raw runtime exception onto the raft taxonomy, or None.
+    """Map a raw runtime exception onto the raft error classes, or None.
 
     (ref: core/error.hpp's per-status ``RAFT_CUDA_TRY`` expansion — each
     vendor status code became a typed raft exception. On TPU the vendor
@@ -103,7 +103,7 @@ def classify_xla_error(exc: BaseException) -> Optional[RaftException]:
     :class:`OutOfMemoryError`; DEADLINE_EXCEEDED/timeout →
     :class:`DeadlineExceededError`; INTERNAL/ABORTED (or any other
     jaxlib-layer failure) → :class:`DeviceError`. Exceptions already in
-    the taxonomy pass through unchanged; exceptions that are neither
+    the error classes pass through unchanged; exceptions that are neither
     (``ValueError`` from user input, ``KeyboardInterrupt``…) return
     None — the caller re-raises them unwrapped.
 
@@ -153,7 +153,7 @@ def _flight_on_classify(error: RaftException) -> None:
 @contextlib.contextmanager
 def device_errors(context: str = "") -> Iterator[None]:
     """Scope that re-raises device-layer failures classified into the
-    raft taxonomy (chained via ``raise ... from``), so callers of the
+    raft error classes (chained via ``raise ... from``), so callers of the
     runtime entry points never see raw jaxlib exceptions. Non-device
     exceptions propagate unwrapped. (ref: the RAFT_CUDA_TRY macro
     bracket around every launch.)"""

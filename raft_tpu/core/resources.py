@@ -112,14 +112,16 @@ class WorkspaceResource:
 
     @staticmethod
     def _default_limit() -> int:
-        try:
-            stats = jax.devices()[0].memory_stats()
-            if stats and "bytes_limit" in stats:
-                # match the reference's default: a fraction of device memory
-                return int(stats["bytes_limit"]) // 4
-        except Exception:
-            pass
-        return 1 << 30  # 1 GiB fallback (e.g. CPU test platform)
+        dev = jax.devices()[0]
+        stats = dev.memory_stats()
+        if stats and "bytes_limit" in stats:
+            # match the reference's default: a fraction of device memory
+            return int(stats["bytes_limit"]) // 4
+        if dev.platform == "tpu":
+            raise RuntimeError(
+                "WorkspaceResource: the TPU reports no memory_stats() "
+                "bytes_limit — cannot size the workspace budget")
+        return 1 << 30  # 1 GiB on the CPU test platform
 
     def batch_rows(self, row_bytes: int, minimum: int = 1) -> int:
         """How many rows of ``row_bytes`` fit in the budget."""
@@ -312,15 +314,6 @@ def _default_device_index() -> int:
     return 0
 
 
-def _default_metrics_factory(res: Resources):
-    """Default METRICS slot: the process-global observability registry
-    (one substrate shared by all handles; override per handle with
-    ``set_metrics``)."""
-    from raft_tpu.observability import get_registry
-
-    return get_registry()
-
-
 def _default_resilience_factory(res: Resources):
     """Default RESILIENCE slot: the process-global recovery-policy
     table (override per handle with ``set_resilience``)."""
@@ -387,7 +380,9 @@ class DeviceResources(Resources):
         )
         self.add_resource_factory(ResourceType.MEMORY_KIND, lambda r: "device")
         self.add_resource_factory(ResourceType.HOST_MEMORY_KIND, lambda r: "pinned_host")
-        self.add_resource_factory(ResourceType.METRICS, _default_metrics_factory)
+        # no METRICS factory: ``metrics`` resolves the process-global
+        # registry on every access, so a swapped global (tests, tenants)
+        # is never shadowed by a stale cached one
         self.add_resource_factory(ResourceType.PROFILER, _default_profiler_factory)
         self.add_resource_factory(ResourceType.RESILIENCE,
                                   _default_resilience_factory)

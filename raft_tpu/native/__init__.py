@@ -25,12 +25,24 @@ _lib_lock = threading.Lock()
 _load_attempted = False
 
 
+def _stale() -> bool:
+    """Missing, or older than its source: the build output is not
+    committed, so a copied tree may carry one built elsewhere."""
+    src = os.path.join(_CPP_DIR, "hostops.cpp")
+    return (not os.path.exists(_SO_PATH)
+            or os.path.getmtime(_SO_PATH) < os.path.getmtime(src))
+
+
 def _try_build() -> bool:
+    """Build into a per-process file, then rename over the library:
+    concurrent test workers never load a half-written one."""
+    tmp = os.path.join("build", f".hostops.{os.getpid()}.so")
     try:
-        subprocess.run(["make", "-C", _CPP_DIR], check=True,
-                       capture_output=True, timeout=120)
-        return os.path.exists(_SO_PATH)
-    except Exception:
+        subprocess.run(["make", "-B", "-C", _CPP_DIR, f"OUT={tmp}"],
+                       check=True, capture_output=True, timeout=120)
+        os.replace(os.path.join(_CPP_DIR, tmp), _SO_PATH)
+        return True
+    except (OSError, subprocess.SubprocessError):
         return False
 
 
@@ -41,7 +53,7 @@ def load() -> Optional[ctypes.CDLL]:
         if _lib is not None or _load_attempted:
             return _lib
         _load_attempted = True
-        if not os.path.exists(_SO_PATH) and not _try_build():
+        if _stale() and not _try_build():
             return None
         try:
             lib = ctypes.CDLL(_SO_PATH)
@@ -93,29 +105,26 @@ def load() -> Optional[ctypes.CDLL]:
             np.ctypeslib.ndpointer(np.int32, flags="C_CONTIGUOUS"),
             np.ctypeslib.ndpointer(np.int32, flags="C_CONTIGUOUS"),
             np.ctypeslib.ndpointer(np.uint8, flags="C_CONTIGUOUS")]
-        try:
-            lib.tiled_layout_v2_sizes.argtypes = [
-                np.ctypeslib.ndpointer(np.int32, flags="C_CONTIGUOUS"),
-                np.ctypeslib.ndpointer(np.int32, flags="C_CONTIGUOUS"),
-                ctypes.c_int64, ctypes.c_int64, ctypes.c_int64,
-                ctypes.c_int64, ctypes.c_int64, ctypes.c_int64,
-                np.ctypeslib.ndpointer(np.int64, flags="C_CONTIGUOUS")]
-            lib.tiled_layout_v2_fill.argtypes = [
-                np.ctypeslib.ndpointer(np.int32, flags="C_CONTIGUOUS"),
-                np.ctypeslib.ndpointer(np.int32, flags="C_CONTIGUOUS"),
-                np.ctypeslib.ndpointer(np.float32, flags="C_CONTIGUOUS"),
-                ctypes.c_int64, ctypes.c_int64, ctypes.c_int64,
-                ctypes.c_int64, ctypes.c_int64, ctypes.c_int64,
-                ctypes.c_int64, ctypes.c_int64,
-                np.ctypeslib.ndpointer(np.float32, flags="C_CONTIGUOUS"),
-                np.ctypeslib.ndpointer(np.int32, flags="C_CONTIGUOUS"),
-                np.ctypeslib.ndpointer(np.int32, flags="C_CONTIGUOUS"),
-                np.ctypeslib.ndpointer(np.int32, flags="C_CONTIGUOUS"),
-                np.ctypeslib.ndpointer(np.int32, flags="C_CONTIGUOUS"),
-                np.ctypeslib.ndpointer(np.int32, flags="C_CONTIGUOUS"),
-                np.ctypeslib.ndpointer(np.uint8, flags="C_CONTIGUOUS")]
-        except AttributeError:
-            pass   # stale .so predating the v2 symbols — v2 falls back
+        lib.tiled_layout_v2_sizes.argtypes = [
+            np.ctypeslib.ndpointer(np.int32, flags="C_CONTIGUOUS"),
+            np.ctypeslib.ndpointer(np.int32, flags="C_CONTIGUOUS"),
+            ctypes.c_int64, ctypes.c_int64, ctypes.c_int64,
+            ctypes.c_int64, ctypes.c_int64, ctypes.c_int64,
+            np.ctypeslib.ndpointer(np.int64, flags="C_CONTIGUOUS")]
+        lib.tiled_layout_v2_fill.argtypes = [
+            np.ctypeslib.ndpointer(np.int32, flags="C_CONTIGUOUS"),
+            np.ctypeslib.ndpointer(np.int32, flags="C_CONTIGUOUS"),
+            np.ctypeslib.ndpointer(np.float32, flags="C_CONTIGUOUS"),
+            ctypes.c_int64, ctypes.c_int64, ctypes.c_int64,
+            ctypes.c_int64, ctypes.c_int64, ctypes.c_int64,
+            ctypes.c_int64, ctypes.c_int64,
+            np.ctypeslib.ndpointer(np.float32, flags="C_CONTIGUOUS"),
+            np.ctypeslib.ndpointer(np.int32, flags="C_CONTIGUOUS"),
+            np.ctypeslib.ndpointer(np.int32, flags="C_CONTIGUOUS"),
+            np.ctypeslib.ndpointer(np.int32, flags="C_CONTIGUOUS"),
+            np.ctypeslib.ndpointer(np.int32, flags="C_CONTIGUOUS"),
+            np.ctypeslib.ndpointer(np.int32, flags="C_CONTIGUOUS"),
+            np.ctypeslib.ndpointer(np.uint8, flags="C_CONTIGUOUS")]
         lib.pair_layout_sizes.argtypes = [
             np.ctypeslib.ndpointer(np.int32, flags="C_CONTIGUOUS"),
             np.ctypeslib.ndpointer(np.int32, flags="C_CONTIGUOUS"),
@@ -286,11 +295,10 @@ def tiled_layout_v2(rows: np.ndarray, cols: np.ndarray, vals: np.ndarray,
     """Native v2 tiled-ELL layout (8-aligned buckets, ROW-granular perm
     — see cpp/hostops.cpp tiled_layout_v2_*). Returns (pv, pc, cct,
     perm_rows, rloc, crt, visited) bit-identical to the numpy v2 branch
-    in sparse/tiled.py, or None when the native library is unavailable
-    (or predates the symbol)."""
+    in sparse/tiled.py, or None when the native library is
+    unavailable."""
     lib = load()
-    if lib is None or len(rows) == 0 or not hasattr(lib,
-                                                    "tiled_layout_v2_fill"):
+    if lib is None or len(rows) == 0:
         return None
     rows = np.asarray(rows)
     cols = np.asarray(cols)

@@ -70,9 +70,10 @@ def fine_scan_vmem_footprint(Wk: int, nqp: int, d: int,
     2 DMA window slots (f32 or int8), the resident query block (f32 +
     the bf16 hi/lo split), the resident probe table, ~3 live [nqp, Wk]
     f32 score temporaries (d2 + mask/select intermediates), and the
-    5-buffer fold state. UNCALIBRATED (no Mosaic compile/reject
-    measured for this kernel yet) — conservative, same spirit as the
-    ``stream_dbuf`` factors in ``ops.fused_l2_topk_pallas``."""
+    5-buffer fold state. Conservative: the SIFT-1M cells (Wk=2048,
+    nqp 16/64) compile for a described v5e (tests/test_tpu_aot.py), and
+    the served list-major path ran on the chip (PR 21); no reject has
+    calibrated it."""
     bytes_ = 2 * Wk * d * (1 if q8 else 4)        # 2 DMA window slots
     bytes_ += nqp * d * (4 + 2 + 2)               # x f32 + hi/lo bf16
     bytes_ += nqp * _LANES * 4                    # probe table (Pp=128)
@@ -254,7 +255,7 @@ def _fine_scan_pallas_call(kernel, n_prefetch: int, n_cells: int,
                          memory_space=pltpu.VMEM),          # xx
             pl.BlockSpec((nqp, _LANES), lambda s, *_: (0, 0),
                          memory_space=pltpu.VMEM),          # probes
-            pl.BlockSpec(memory_space=pltpu.ANY),           # slab (DMA)
+            pl.BlockSpec(memory_space=pl.ANY),           # slab (DMA)
         ],
         out_specs=[out_spec] * 5,
     )
@@ -359,7 +360,16 @@ def fine_scan_list_major_q8(sched, scale_l, x, xx, probes, slab_q,
         (sched, scale_l, x, xx, probes, slab_q))
 
 
+#: the most rows of one list a schedule entry covers: a longer list
+#: streams as several entries (same list id, consecutive windows), so
+#: the kernel window — and its scoped VMEM — stays bounded whatever the
+#: list-size skew (a 7k+-row list at the SIFT-1M smoke shape overran
+#: the 15 MiB budget)
+SEGMENT_ROWS = 2048
+
+
 def pad_window(W: int) -> int:
-    """The kernel window for a probe window ``W``: rounded up to the
-    128-lane quantum (the fold iterates lane chunks)."""
-    return round_up(max(W, 1), _LANES)
+    """The kernel window for a probe window ``W``: capped at
+    :data:`SEGMENT_ROWS` and rounded up to the 128-lane quantum (the
+    fold iterates lane chunks)."""
+    return round_up(min(max(W, 1), SEGMENT_ROWS), _LANES)

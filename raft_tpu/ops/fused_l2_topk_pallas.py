@@ -1343,7 +1343,7 @@ def _group_pallas_call_db(dbuf: bool, x, y_hi, y_lo, yy_half, m_real,
         grid = (n_groups,)
         x_spec = pl.BlockSpec((Qb, d), lambda s, *_: (0, 0),
                               memory_space=pltpu.VMEM)
-        y_spec = pl.BlockSpec(memory_space=pltpu.ANY)   # manual DMA
+        y_spec = pl.BlockSpec(memory_space=pl.ANY)   # manual DMA
         yy_spec = pl.BlockSpec((8, tpg * T), lambda s, *_: (0, s),
                                memory_space=pltpu.VMEM)
         xx_spec = pl.BlockSpec((Qb, 1), lambda s, *_: (0, 0),

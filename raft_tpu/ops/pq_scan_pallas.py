@@ -84,26 +84,73 @@ PQ_BITS = (4, 8)
 PQ_POOL_DEPTHS = (2, 4, 8)
 
 
+def pq_window(Wk: int) -> int:
+    """The DMA window of a probe window ``Wk``: one extra 128-lane
+    quantum, because window starts are aligned DOWN to 128 rows (the
+    transposed code and sidecar views are sliced along lanes)."""
+    return Wk + _LANES
+
+
+def kernel_rows(R: int) -> int:
+    """Row count of the kernel views of an ``R``-row slab (lane-padded
+    so every aligned window stays inside)."""
+    return -(-R // _LANES) * _LANES
+
+
 def pq_scan_vmem_footprint(Wk: int, nqp: int, pq_dim: int, K: int,
                            Lp: int, pq_bits: int = 8,
                            pool_depth: int = 2) -> int:
-    """Estimated scoped-VMEM bytes of one PQ ADC cell: 2 DMA slots for
-    the code window (+ the two f32 sidecars), the resident ADC table
-    (f32 + its bf16 hi/lo split), the resident probe + centroid-dot
-    tables, the per-subspace one-hot staging block, ~3 live [nqp, Wk]
-    f32 score temporaries and the (2·depth+1)-buffer fold state.
-    UNCALIBRATED — conservative, same spirit as
-    ``fine_scan_vmem_footprint``."""
-    code_bytes = pq_dim if pq_bits == 8 else -(-pq_dim // 2)
-    bytes_ = 2 * Wk * code_bytes                 # 2 code DMA slots
-    bytes_ += 2 * 2 * Wk * 4                     # 2×(‖ŷ‖², Eq) DMA slots
+    """Estimated scoped-VMEM bytes of one PQ ADC cell at probe window
+    ``Wk``: 2 DMA slots for the transposed code window and the two f32
+    sidecar rows (sublane-padded), the resident ADC table (f32 + its
+    bf16 hi/lo split), the resident probe + centroid-dot tables, the
+    one-hot staging block of one subspace group (x2 for the compare
+    temporaries), ~3 live [nqp, Wa] f32 score temporaries and the
+    (2·depth+1)-buffer fold state. Checked against described-v5e
+    compiles in tests/test_tpu_aot.py, not calibrated on a chip."""
+    Wa = pq_window(Wk)
+    code_rows = -(-_code_rows(pq_dim, pq_bits) // 32) * 32
+    bytes_ = 2 * code_rows * Wa                  # 2 code DMA slots
+    bytes_ += 2 * 2 * 8 * Wa * 4                 # 2×(‖ŷ‖², Eq) DMA slots
     bytes_ += nqp * pq_dim * K * (4 + 2 + 2)     # lut f32 + hi/lo bf16
     bytes_ += nqp * _LANES * 4                   # probe table
-    bytes_ += nqp * Lp * 4                       # per-list x·c table
-    bytes_ += Wk * pq_dim * K * 2                # one-hot staging (bf16)
-    bytes_ += 3 * nqp * Wk * 4                   # d2/lb + temporaries
+    bytes_ += nqp * Lp * 4 * 2                   # x·c table + lane mask
+    bytes_ += 2 * _onehot_rows(K) * Wa * 2       # one-hot group (bf16)
+    bytes_ += 3 * nqp * Wa * 4                   # d2/lb + temporaries
     bytes_ += (2 * pool_depth + 1) * nqp * _LANES * 4 * 2  # fold state
     return bytes_
+
+
+def _code_rows(pq_dim: int, pq_bits: int) -> int:
+    return pq_dim if pq_bits == 8 else -(-pq_dim // 2)
+
+
+#: one-hot rows (subspace × codeword) contracted per MXU step: bounds
+#: the [rows, Wa] bf16 staging block whatever pq_dim·K is
+_ONEHOT_ROWS = 512
+
+
+def _onehot_rows(K: int) -> int:
+    return max(1, _ONEHOT_ROWS // K) * K
+
+
+def kernel_layout(codes, yy_pq, eq_rows):
+    """The kernel views of a PQ slab: codes transposed to
+    ``[code rows (padded to 32), R']`` int8 and the two sidecars as
+    ``[1, R']`` f32 rows, ``R' = kernel_rows(R)`` (pad columns zero).
+    Mosaic DMAs a window of these along lanes; the row-major [R, 32]
+    codes and [R, 1] sidecars are narrower than one lane tile, which
+    it cannot slice. Built once per index (``IvfPqIndex``)."""
+    R = codes.shape[0]
+    pad = kernel_rows(R) - R
+    rows = -(-codes.shape[1] // 32) * 32
+    ct = jnp.pad(jnp.asarray(codes, jnp.int8).T,
+                 ((0, rows - codes.shape[1]), (0, pad)))
+    yy = jnp.pad(jnp.reshape(jnp.asarray(yy_pq, jnp.float32), (1, -1)),
+                 ((0, 0), (0, pad)))
+    eq = jnp.pad(jnp.reshape(jnp.asarray(eq_rows, jnp.float32), (1, -1)),
+                 ((0, 0), (0, pad)))
+    return ct, yy, eq
 
 
 def _pq_pool_out_shape(nqp: int, depth: int):
@@ -148,45 +195,54 @@ def _fold_pool_deep(acc, d2, base_row, nqp: int, Wk: int, depth: int):
 
 
 def _decode_subspaces(codes, pq_dim: int, pq_bits: int):
-    """Per-subspace int32 code columns of a streamed window. 8-bit
-    codes are stored BIASED (code − 128) so the full 0..255 range fits
-    int8; 4-bit codes are packed two per byte (low nibble = even
-    subspace) and unpack with pure arithmetic — no bitwise ops on the
-    possibly-negative int8 lanes."""
+    """Per-subspace ``[1, Wa]`` int32 code rows of a streamed window of
+    the transposed codes view. 8-bit codes are stored BIASED
+    (code − 128) so the full 0..255 range fits int8; 4-bit codes are
+    packed two per byte (low nibble = even subspace) and unpack with
+    pure arithmetic — no bitwise ops on the possibly-negative int8
+    lanes."""
     v = codes.astype(jnp.int32)
     if pq_bits == 8:
-        return [v[:, s] + 128 for s in range(pq_dim)]
+        return [v[s:s + 1, :] + 128 for s in range(pq_dim)]
     vu = jnp.where(v < 0, v + 256, v)
     cols = []
     for s in range(pq_dim):
-        byte = vu[:, s // 2]
+        byte = vu[s // 2:s // 2 + 1, :]
         cols.append(byte % 16 if s % 2 == 0 else byte // 16)
     return cols
 
 
 def _adc_scores(lut_hi, lut_lo, codes, pq_dim: int, K: int,
-                pq_bits: int, Wk: int):
-    """``Σ_s lut[q, s, code[w, s]]`` for every (query, row) of one
-    window — the table gather evaluated as a one-hot MXU contraction
+                pq_bits: int, Wa: int):
+    """``Σ_s lut[q, s, code[s, w]]`` for every (query, row) of one
+    window — the table gather evaluated as one-hot MXU contractions
     (one-hot lanes are exact in bf16, so only the hi/lo split of the
-    table itself carries rounding)."""
-    iota = jax.lax.broadcasted_iota(jnp.int32, (Wk, K), 1)
-    hot = []
-    for s, col in enumerate(_decode_subspaces(codes, pq_dim, pq_bits)):
-        hot.append((col[:, None] == iota).astype(jnp.bfloat16))
-    onehot = jnp.concatenate(hot, axis=1)          # [Wk, pq_dim·K]
-    acc = jax.lax.dot_general(lut_hi, onehot, _NT,
+    table itself carries rounding), one subspace group of
+    ``_onehot_rows(K)`` one-hot rows at a time."""
+    iota = jax.lax.broadcasted_iota(jnp.int32, (K, Wa), 0)
+    cols = _decode_subspaces(codes, pq_dim, pq_bits)
+    G = _onehot_rows(K) // K
+    acc = None
+    for s0 in range(0, pq_dim, G):
+        grp = range(s0, min(s0 + G, pq_dim))
+        onehot = jnp.concatenate(
+            [(cols[t] == iota).astype(jnp.bfloat16) for t in grp],
+            axis=0)                                 # [|grp|·K, Wa]
+        lo, hi = s0 * K, (s0 + len(grp)) * K
+        part = jnp.dot(lut_hi[:, lo:hi], onehot,
+                       preferred_element_type=jnp.float32)
+        part = part + jnp.dot(lut_lo[:, lo:hi], onehot,
                               preferred_element_type=jnp.float32)
-    acc = acc + jax.lax.dot_general(lut_lo, onehot, _NT,
-                                    preferred_element_type=jnp.float32)
-    return acc                                      # [nqp, Wk]
+        acc = part if acc is None else acc + part
+    return acc                                      # [nqp, Wa]
 
 
 def _pq_kernel_body(sched_ref, xx_ref, probes_ref, cdot_ref, lut_ref,
-                    codes_ref, yy_ref, eq_ref, *out_refs, Wk: int,
+                    codes_ref, yy_ref, eq_ref, *out_refs, Wa: int,
                     pq_dim: int, K: int, pq_bits: int, depth: int):
-    """One grid cell: stream LISTS_PER_CELL probed lists' code windows
-    (+ norm and error sidecars) through the 2-slot DMA pipeline,
+    """One grid cell: stream LISTS_PER_CELL probed lists' 128-aligned
+    code windows of ``Wa`` lanes (+ the norm and error sidecar rows)
+    through the 2-slot DMA pipeline,
     evaluate the ADC scores against the resident lookup table, subtract
     each row's recorded error bound into the certified lower-bound
     score, mask non-member queries / out-of-window columns to +inf and
@@ -205,14 +261,16 @@ def _pq_kernel_body(sched_ref, xx_ref, probes_ref, cdot_ref, lut_ref,
 
     def body(cscratch, yscratch, escratch, csem, ysem, esem):
         def dma(slot, j):
+            # the wrapper aligned every window start to 128 lanes
+            st = pl.multiple_of(sched_ref[0, j], _LANES)
             return (pltpu.make_async_copy(
-                codes_ref.at[pl.ds(sched_ref[0, j], Wk), :],
+                codes_ref.at[:, pl.ds(st, Wa)],
                 cscratch.at[slot], csem.at[slot]),
                 pltpu.make_async_copy(
-                    yy_ref.at[pl.ds(sched_ref[0, j], Wk), :],
+                    yy_ref.at[:, pl.ds(st, Wa)],
                     yscratch.at[slot], ysem.at[slot]),
                 pltpu.make_async_copy(
-                    eq_ref.at[pl.ds(sched_ref[0, j], Wk), :],
+                    eq_ref.at[:, pl.ds(st, Wa)],
                     escratch.at[slot], esem.at[slot]))
 
         def start(slot, j):
@@ -229,23 +287,31 @@ def _pq_kernel_body(sched_ref, xx_ref, probes_ref, cdot_ref, lut_ref,
         probes = probes_ref[...]                         # [nqp, Pp]
         cdot = cdot_ref[...]                             # [nqp, Lp]
         lut_hi, lut_lo = _split_hi_lo(lut_ref[...])      # [nqp, S·K]
-        colv = jax.lax.broadcasted_iota(jnp.int32, (nqp, Wk), 1)
-        acc = tuple(ref[...] for ref in out_refs)
-        for jj in range(LISTS_PER_CELL):
+        colv = jax.lax.broadcasted_iota(jnp.int32, (nqp, Wa), 1)
+        lanes_l = jax.lax.broadcasted_iota(jnp.int32, cdot.shape, 1)
+        def one_list(jj, acc):
+            # a rolled loop: the unrolled 8-list cell took minutes to
+            # compile at the SIFT-1M window
             j = j0 + jj
             slot = jj % 2
-            if jj + 1 < LISTS_PER_CELL:
-                start((jj + 1) % 2, j + 1)           # prefetch next
+
+            @pl.when(jj + 1 < LISTS_PER_CELL)
+            def _():
+                start(1 - slot, j + 1)                   # prefetch next
+
             wait(slot, j)
             st = sched_ref[0, j]
             lsize = sched_ref[1, j]
             off = sched_ref[2, j]
             lid = sched_ref[3, j]
             adc = _adc_scores(lut_hi, lut_lo, cscratch[slot], pq_dim,
-                              K, pq_bits, Wk)
-            yyw = yscratch[slot].reshape(1, Wk)          # ‖ŷ‖² lanes
-            eqw = escratch[slot].reshape(1, Wk)          # Eq_row lanes
-            qc = jax.lax.dynamic_slice_in_dim(cdot, j, 1, 1)
+                              K, pq_bits, Wa)
+            yyw = yscratch[slot]                         # [1, Wa] ‖ŷ‖²
+            eqw = escratch[slot]                         # [1, Wa] Eq_row
+            # column j of the centroid-dot table by a lane mask: Mosaic
+            # has no dynamic_slice on values
+            qc = jnp.sum(jnp.where(lanes_l == j, cdot, 0.0), axis=1,
+                         keepdims=True)                  # [nqp, 1]
             d2 = xx + yyw - 2.0 * qc - 2.0 * adc
             # the certified lower bound on the TRUE distance: pull the
             # ADC score toward 0 by the row's recorded round-trip
@@ -259,16 +325,18 @@ def _pq_kernel_body(sched_ref, xx_ref, probes_ref, cdot_ref, lut_ref,
             lb = jnp.where(member > 0.0, lb, jnp.inf)
             valid = (colv >= off) & (colv < off + lsize)
             lb = jnp.where(valid, lb, jnp.inf)
-            acc = _fold_pool_deep(acc, lb, st, nqp, Wk, depth)
+            return _fold_pool_deep(acc, lb, st, nqp, Wa, depth)
+
+        acc = jax.lax.fori_loop(0, LISTS_PER_CELL, one_list,
+                                tuple(ref[...] for ref in out_refs))
         for t, ref in enumerate(out_refs):
             ref[...] = acc[t]
 
-    code_bytes = pq_dim if pq_bits == 8 else pq_dim // 2
     pl.run_scoped(
         body,
-        cscratch=pltpu.VMEM((2, Wk, code_bytes), jnp.int8),
-        yscratch=pltpu.VMEM((2, Wk, 1), jnp.float32),
-        escratch=pltpu.VMEM((2, Wk, 1), jnp.float32),
+        cscratch=pltpu.VMEM((2, codes_ref.shape[0], Wa), jnp.int8),
+        yscratch=pltpu.VMEM((2, 1, Wa), jnp.float32),
+        escratch=pltpu.VMEM((2, 1, Wa), jnp.float32),
         csem=pltpu.SemaphoreType.DMA((2,)),
         ysem=pltpu.SemaphoreType.DMA((2,)),
         esem=pltpu.SemaphoreType.DMA((2,)))
@@ -292,12 +360,16 @@ def pq_scan_list_major(sched, xx, probes, cdot, lut, codes, yy_pq,
         column j; pad-list columns are never read through the mask).
       lut: [nqp, pq_dim·K] f32 ADC table — ``lut[q, s·K + j] =
         x_{q,s} · cb_s[j]`` flattened subspace-major.
-      codes: [R, pq_dim] int8 biased codes (8-bit: stored code−128) or
-        [R, pq_dim/2] packed nibbles (4-bit).
-      yy_pq: [R, 1] f32 reconstructed row norms ``‖ŷ‖²`` (pads 0).
-      eq_rows: [R, 1] f32 recorded per-row round-trip error bounds
-        ``‖y − ŷ‖`` (pads 0) — the adaptive-certificate sidecar.
-      Wk: static window length, a multiple of 128.
+      codes, yy_pq, eq_rows: the :func:`kernel_layout` views of the
+        slab — transposed int8 codes ``[rows, R']`` (8-bit: biased
+        code−128 per subspace row; 4-bit: packed nibble pairs), the
+        reconstructed row norms ``‖ŷ‖²`` and the recorded per-row
+        round-trip error bounds ``‖y − ŷ‖`` (the adaptive-certificate
+        sidecar) as ``[1, R']`` rows; pad columns zero.
+      Wk: static probe window, a multiple of 128. Each window start is
+        aligned down to 128 here and the window widened to
+        :func:`pq_window` lanes, the in-window offsets shifted to
+        match.
       pq_bits: 4 or 8 (static — decides the decode path).
       pool_depth: static per-lane-class pool depth ∈ (2, 4, 8) —
         2 is the base 256-slot pool, 4/8 the ``pq_widen`` rungs.
@@ -321,18 +393,28 @@ def pq_scan_list_major(sched, xx, probes, cdot, lut, codes, yy_pq,
         raise ValueError(f"pq_scan_list_major: schedule length {Lp} "
                          f"must be a multiple of {LISTS_PER_CELL}")
     nqp = xx.shape[0]
-    code_bytes = codes.shape[1]
-    pq_dim = code_bytes if pq_bits == 8 else 2 * code_bytes
     K = 1 << pq_bits
-    if lut.shape[1] != pq_dim * K:
+    pq_dim = lut.shape[1] // K
+    if lut.shape[1] != pq_dim * K or \
+            codes.shape[0] < _code_rows(pq_dim, pq_bits):
         raise ValueError(f"pq_scan_list_major: lut width "
-                         f"{lut.shape[1]} != pq_dim·K = {pq_dim * K}")
+                         f"{lut.shape[1]} / code rows {codes.shape[0]} "
+                         f"do not describe pq_dim·K with K={K}")
+    Wa = pq_window(Wk)
+    R = codes.shape[1]
+    if R % _LANES or R < Wa:
+        raise ValueError(f"pq_scan_list_major: kernel view of {R} rows "
+                         f"must be a multiple of {_LANES} and cover the "
+                         f"window {Wa}")
+    start = sched[0]
+    ws = jnp.maximum(jnp.minimum(start // _LANES * _LANES, R - Wa), 0)
+    sched = sched.at[0].set(ws).at[2].add(start - ws)
 
     def kernel(sched_ref, xx_ref, probes_ref, cdot_ref, lut_ref,
                codes_ref, yy_ref, eq_ref, *out_refs):
         _pq_kernel_body(sched_ref, xx_ref, probes_ref, cdot_ref,
                         lut_ref, codes_ref, yy_ref, eq_ref, *out_refs,
-                        Wk=Wk, pq_dim=pq_dim, K=K, pq_bits=pq_bits,
+                        Wa=Wa, pq_dim=pq_dim, K=K, pq_bits=pq_bits,
                         depth=pool_depth)
 
     n_cells = Lp // LISTS_PER_CELL
@@ -351,21 +433,21 @@ def pq_scan_list_major(sched, xx, probes, cdot, lut, codes, yy_pq,
                          memory_space=pltpu.VMEM),           # cdot
             pl.BlockSpec((nqp, pq_dim * K), lambda s, *_: (0, 0),
                          memory_space=pltpu.VMEM),           # lut
-            pl.BlockSpec(memory_space=pltpu.ANY),            # codes DMA
-            pl.BlockSpec(memory_space=pltpu.ANY),            # yy DMA
-            pl.BlockSpec(memory_space=pltpu.ANY),            # eq DMA
+            pl.BlockSpec(memory_space=pl.ANY),            # codes DMA
+            pl.BlockSpec(memory_space=pl.ANY),            # yy DMA
+            pl.BlockSpec(memory_space=pl.ANY),            # eq DMA
         ],
         out_specs=[out_spec] * n_out,
     )
     L = n_cells * LISTS_PER_CELL
     cost = pl.CostEstimate(
         # 2 hi/lo ADC contractions over the pq_dim·K one-hot lanes
-        flops=2 * nqp * L * Wk * pq_dim * K * 2,
-        bytes_accessed=(L * Wk * (code_bytes + 8)
+        flops=2 * nqp * L * Wa * pq_dim * K * 2,
+        bytes_accessed=(L * Wa * (codes.shape[0] + 8)
                         + nqp * pq_dim * K * 4
                         + nqp * _LANES * 8 * n_out),
         # one sqrt per (query, streamed row) for the certified bound
-        transcendentals=nqp * L * Wk,
+        transcendentals=nqp * L * Wa,
     )
     return pl.pallas_call(
         kernel,

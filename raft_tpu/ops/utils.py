@@ -5,18 +5,8 @@ from __future__ import annotations
 import functools
 
 import jax
-import jax.experimental.pallas.tpu as pltpu
 
 from raft_tpu.utils.pow2 import round_up_safe as round_up  # canonical helper
-
-# jax renamed TPUCompilerParams → CompilerParams (~0.5); the kernels are
-# written against the new name. Alias it on older jaxlib so every kernel
-# module (they all import this one first) works on both sides of the
-# rename — without this, EVERY Pallas path raises AttributeError on the
-# older CPU test environment.
-if not hasattr(pltpu, "CompilerParams") and hasattr(pltpu,
-                                                    "TPUCompilerParams"):
-    pltpu.CompilerParams = pltpu.TPUCompilerParams
 
 
 @functools.lru_cache(maxsize=1)

@@ -65,7 +65,7 @@ class PoisonedOutputError(DeviceError):
 @dataclasses.dataclass(frozen=True)
 class RetryPolicy:
     """Bounded-retry parameters for one site. ``retry_on`` must name
-    taxonomy classes (see core.error) — raw jaxlib exceptions are
+    error classes (see core.error) — raw jaxlib exceptions are
     classified before matching."""
 
     max_retries: int = 2
@@ -207,7 +207,7 @@ def run_with_policy(site: str, fn: Callable[[int], object],
                     policy: Optional[RetryPolicy] = None,
                     on_retry: Optional[Callable] = None):
     """Run ``fn(attempt)`` under ``policy``: device-layer exceptions are
-    classified into the raft taxonomy, matching ones are retried up to
+    classified into the raft error classes, matching ones are retried up to
     ``max_retries`` with backoff, and exhaustion re-raises the last
     classified error. Deadline errors always propagate immediately."""
     if policy is None:

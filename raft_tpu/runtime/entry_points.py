@@ -33,7 +33,7 @@ def _aot_call(res, name: str, statics: tuple, fn, *args):
 
     Resilience contract: compile AND dispatch run inside
     ``device_errors`` — callers never see raw jaxlib exceptions, only
-    the classified taxonomy (OutOfMemoryError / DeviceError /
+    the classified error classes (OutOfMemoryError / DeviceError /
     DeadlineExceededError) — and the whole attempt is retried under the
     handle's ``runtime`` RetryPolicy (a failed compile is NOT cached,
     so a retry recompiles). Fault sites: ``aot_compile`` (inside the

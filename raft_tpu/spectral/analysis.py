@@ -110,8 +110,8 @@ def fit_embedding(res, A: Sparse, n_components: int, ncv=None,
                      and L.values.dtype == jnp.float32)
         if tiled:
             L = prepare_spmv(L)
-    # jit_loop=True compiles the whole solve into one program (best for
-    # remote/tunneled devices); the host loop (default) keeps cancellation
+    # jit_loop=True compiles the whole solve into one program; the
+    # host loop (default) keeps cancellation
     # points and the stagnation early-exit for large zero clusters
     config = LanczosSolverConfig(
         n_components=k, max_iterations=max_iterations, ncv=ncv,

@@ -106,14 +106,14 @@ def chip_spec(device: Optional[jax.Device] = None) -> ChipSpec:
     """Roofline peaks for ``device`` (default: the first device).
 
     TPU kinds resolve by generation + lite/p variant (``TPU v5 lite`` /
-    ``TPU v5e`` → v5e; ``TPU v5p`` → v5p); an unknown TPU generation
-    falls back to the nearest known one so the report stays usable on
-    new silicon (labelled by the table entry's name, never the device's).
+    ``TPU v5e`` → v5e; ``TPU v5p`` → v5p). A TPU kind that is not in
+    :data:`TPU_SPECS` is an error: a roofline share against another
+    chip's peaks would be a wrong number, not an approximate one.
     Non-TPU platforms get :data:`CPU_SPEC` — synthetic, but fixed, so
     tier-1 tests exercise the full classification path."""
     kind = device_kind(device).lower()
     gen = tpu_generation(device)
-    if gen == 0:
+    if gen == 0 and "tpu" not in kind:
         return CPU_SPEC
     variant = ""
     if "lite" in kind or re.search(r"v\d+\s*e", kind):
@@ -122,12 +122,8 @@ def chip_spec(device: Optional[jax.Device] = None) -> ChipSpec:
         variant = "p"
     spec = TPU_SPECS.get((gen, variant)) or TPU_SPECS.get((gen, ""))
     if spec is None:
-        # unknown (gen, variant): nearest known generation, e-variant first
-        for g in sorted({k[0] for k in TPU_SPECS}, key=lambda g: abs(g - gen)):
-            spec = TPU_SPECS.get((g, variant)) or TPU_SPECS.get(
-                (g, "")) or TPU_SPECS.get((g, "e"))
-            if spec is not None:
-                break
+        raise ValueError(f"chip_spec: TPU kind {device_kind(device)!r} "
+                         f"has no entry in TPU_SPECS")
     return spec
 
 

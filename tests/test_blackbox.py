@@ -32,7 +32,8 @@ from raft_tpu.observability.flight import (FlightRecorder,
                                            sync_dropped_metric,
                                            FLIGHT_DROPPED,
                                            KNOWN_EVENT_KINDS)
-from raft_tpu.observability.metrics import get_registry
+from raft_tpu.observability.metrics import (MetricsRegistry, get_registry,
+                                            set_registry)
 from raft_tpu.observability.timeline import (emit_epilogue, emit_marker,
                                              emit_stall)
 from raft_tpu.observability.watchdog import (Watchdog, dump_stacks,
@@ -48,16 +49,21 @@ rng = np.random.default_rng(7)
 
 @pytest.fixture(autouse=True)
 def _fresh_forensics():
-    """Every test starts and ends with no installed blackbox and a
-    fresh flight recorder (the mirror is process-global state)."""
+    """Every test starts and ends with no installed blackbox, a fresh
+    flight recorder and a fresh metrics registry (process-global state:
+    the snapshots a blackbox records carry the whole registry, so the
+    metrics earlier files left in this worker would crowd dumps out of
+    the small test rings)."""
     prev_bb = bb_mod.install(None)
     if prev_bb is not None:
         prev_bb.close(reason="test-cleanup")
     prev_rec = set_flight_recorder(FlightRecorder(capacity=512))
+    prev_reg = set_registry(MetricsRegistry())
     yield
     leaked = bb_mod.install(None)
     if leaked is not None:
         leaked.close(reason="test-cleanup")
+    set_registry(prev_reg)
     set_flight_recorder(prev_rec)
 
 

@@ -78,6 +78,83 @@ def test_workspace_budget():
     assert res.workspace.batch_rows(row_bytes=1024) == 1024
 
 
+class _FakeDevice:
+    def __init__(self, platform, stats):
+        self.platform, self._stats = platform, stats
+
+    def memory_stats(self):
+        return self._stats
+
+
+def test_workspace_default_limit_needs_bytes_limit_on_tpu(monkeypatch):
+    """A TPU that reports no bytes_limit is an error, not a silent
+    1 GiB budget; the CPU platform keeps its fixed default."""
+    from raft_tpu.core.resources import WorkspaceResource
+
+    monkeypatch.setattr(jax, "devices",
+                        lambda *a: [_FakeDevice("tpu", {})])
+    with pytest.raises(RuntimeError, match="bytes_limit"):
+        WorkspaceResource()
+    monkeypatch.setattr(jax, "devices", lambda *a: [
+        _FakeDevice("tpu", {"bytes_limit": 16 << 30})])
+    assert WorkspaceResource().allocation_limit == 4 << 30
+    monkeypatch.setattr(jax, "devices",
+                        lambda *a: [_FakeDevice("cpu", None)])
+    assert WorkspaceResource().allocation_limit == 1 << 30
+
+
+def test_compile_cache_dir_from_env_or_repo(monkeypatch):
+    """The entry-script helper: JAX_COMPILATION_CACHE_DIR wins and is
+    left to JAX; otherwise the fixed, git-ignored <repo>/.jax_cache."""
+    import os
+    import subprocess
+
+    from raft_tpu.utils import compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/some/dir")
+    assert compile_cache.use_compile_cache() == "/some/dir"
+    assert jax.config.jax_compilation_cache_dir == before
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    try:
+        assert compile_cache.use_compile_cache() == \
+            compile_cache.REPO_CACHE_DIR
+        assert jax.config.jax_compilation_cache_dir == \
+            compile_cache.REPO_CACHE_DIR
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+    repo = os.path.dirname(compile_cache.REPO_CACHE_DIR)
+    assert os.path.basename(compile_cache.REPO_CACHE_DIR) == ".jax_cache"
+    ignored = subprocess.run(["git", "-C", repo, "check-ignore", "-q",
+                              ".jax_cache/x"], capture_output=True)
+    assert ignored.returncode in (0, 128)   # 128: not a git checkout
+
+
+def test_compile_cache_lands_in_env_dir(tmp_path):
+    """End to end in a fresh process: with JAX_COMPILATION_CACHE_DIR
+    set, the compiled programs land there."""
+    import os
+    import subprocess
+    import sys
+
+    from raft_tpu.utils import compile_cache
+
+    repo = os.path.dirname(compile_cache.REPO_CACHE_DIR)
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path),
+               JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0",
+               JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES="0")
+    code = ("from raft_tpu.utils.compile_cache import use_compile_cache\n"
+            "print(use_compile_cache())\n"
+            "import jax, jax.numpy as jnp\n"
+            "jax.block_until_ready(jax.jit(lambda x: x * 2)(jnp.ones(4)))\n")
+    r = subprocess.run([sys.executable, "-c", code], cwd=repo, env=env,
+                       capture_output=True, text=True, timeout=120)
+    assert r.returncode == 0, r.stderr
+    assert r.stdout.strip() == str(tmp_path)
+    assert any(n.endswith("-cache") for n in os.listdir(tmp_path))
+
+
 def test_compile_cache():
     res = DeviceResources()
     cache = res.compile_cache

@@ -163,6 +163,57 @@ def test_fine_scan_list_fault_degrades(fixture):
     assert np.array_equal(np.asarray(vq), np.asarray(vl))
 
 
+def test_fine_scan_list_kernel_error_propagates(fixture, monkeypatch):
+    """Only injected/classified device failures degrade: any other
+    error of the list-major path (a kernel the compiler refuses)
+    reaches the caller, and no degradation is recorded."""
+    import raft_tpu.ann.ivf_flat as ivf_flat_mod
+
+    res, _, Q, idx, _ = fixture
+
+    def refused(*a, **kw):
+        raise RuntimeError("Mosaic failed to compile TPU kernel")
+
+    monkeypatch.setattr(ivf_flat_mod, "_search_list_major", refused)
+    before = policy.degradation_count()
+    with pytest.raises(RuntimeError, match="Mosaic"):
+        search_ivf_flat(res, idx, Q, 10, n_probes=3, fine_scan="list")
+    assert policy.degradation_count() == before
+
+
+@pytest.mark.parametrize("dtype", ["f32", "int8"])
+def test_long_lists_stream_as_segments(fixture, monkeypatch, dtype):
+    """A list longer than the kernel window takes one schedule entry
+    per window of rows — every real row covered exactly once — and
+    the list-major ids stay identical to the query-major scan's."""
+    import raft_tpu.ops.fine_scan_pallas as fsp
+    from raft_tpu.ann.ivf_flat import _coarse_probe
+
+    res, _, Q, idx, idx8 = fixture
+    index = idx if dtype == "f32" else idx8
+    monkeypatch.setattr(fsp, "SEGMENT_ROWS", 128)
+    assert index.probe_window > 128          # the fixture's lists split
+    probes = np.asarray(_coarse_probe(res, index.centroids, Q, 3))
+    s = build_list_schedule(index, probes).sched
+    offs, sizes = np.asarray(index.offsets), np.asarray(index.sizes)
+    for lid in np.unique(probes):
+        ent = s[:, s[3] == lid]
+        assert ent[1].sum() == sizes[lid]
+        rows = np.concatenate([np.arange(st + off, st + off + n)
+                               for st, n, off, _ in ent.T])
+        assert np.array_equal(np.sort(rows),
+                              offs[lid] + np.arange(sizes[lid]))
+        assert np.all(ent[2] + ent[1] <= 128)
+    vq, iq = search_ivf_flat(res, index, Q, 10, n_probes=3,
+                             fine_scan="query")
+    vl, il = search_ivf_flat(res, index, Q, 10, n_probes=3,
+                             fine_scan="list")
+    assert all(set(a) == set(b) for a, b in zip(np.asarray(iq),
+                                                 np.asarray(il)))
+    np.testing.assert_allclose(np.asarray(vl), np.asarray(vq),
+                               rtol=1e-4, atol=1e-3)
+
+
 def test_fine_scan_list_site_registered():
     assert "fine_scan_list" in resilience.KNOWN_SITES
     assert "autotune_fine_scan" in resilience.KNOWN_SITES

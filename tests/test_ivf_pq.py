@@ -222,6 +222,24 @@ def test_pq_scan_fault_degrades_to_flat(fixture):
     assert _sets(pi) == _sets(fi)
 
 
+def test_pq_scan_kernel_error_propagates(fixture, monkeypatch):
+    """Only injected/classified device failures degrade: an error the
+    ADC path raises for any other reason (a kernel the compiler
+    refuses) reaches the caller, and no degradation is recorded."""
+    from raft_tpu.resilience.policy import degradation_count
+
+    res, X, Q, _, idx8 = fixture
+
+    def refused(*a, **kw):
+        raise RuntimeError("Mosaic failed to compile TPU kernel")
+
+    monkeypatch.setattr(ivf_pq_mod, "_search_pq", refused)
+    before = degradation_count()
+    with pytest.raises(RuntimeError, match="Mosaic"):
+        search_ivf_pq(res, idx8, Q, 6, n_probes=4, pq_scan="pq")
+    assert degradation_count() == before
+
+
 # ----------------------------------------------- error envelope tests
 class TestPqErrorEnvelope:
     """The recorded per-subspace bounds must ENVELOPE every encoded

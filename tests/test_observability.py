@@ -198,7 +198,6 @@ def test_nested_primitive_attributes_to_parent_span():
 
 # ------------------------------------------------------------------- comms
 def test_comms_counters_one_device_mesh():
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from raft_tpu.comms import MeshComms
@@ -213,7 +212,8 @@ def test_comms_counters_one_device_mesh():
         return y + w.sum()
 
     x = np.ones((4, 32), np.float32)
-    shard_map(fn, mesh=mesh, in_specs=(P("obx"),), out_specs=P("obx"))(x)
+    jax.shard_map(fn, mesh=mesh, in_specs=(P("obx"),),
+                  out_specs=P("obx"))(x)
     reg = obs.get_registry()
     for coll, nbytes in (("allreduce", 4 * 32 * 4), ("allgather", 4 * 32 * 4),
                          ("reducescatter", 4 * 32 * 4)):
@@ -548,6 +548,31 @@ def test_chip_spec_cpu_fallback_and_tpu_table():
     assert v5e.ridge > 100  # TPUs: heavily compute-biased ridge
 
 
+class _Dev:
+    def __init__(self, kind):
+        self.device_kind = kind
+
+
+@pytest.mark.parametrize("kind,name", [("TPU v5 lite", "tpu v5e"),
+                                       ("TPU v5e", "tpu v5e"),
+                                       ("TPU v5p", "tpu v5p"),
+                                       ("TPU v4", "tpu v4")])
+def test_chip_spec_resolves_known_tpu_kinds(kind, name):
+    from raft_tpu.utils import arch
+
+    assert arch.chip_spec(_Dev(kind)).name == name
+
+
+@pytest.mark.parametrize("kind", ["TPU v7x", "TPU v9 ultra", "TPU"])
+def test_chip_spec_refuses_unknown_tpu_kinds(kind):
+    """No nearest-generation guess: a roofline share against another
+    chip's peaks would be a wrong number."""
+    from raft_tpu.utils import arch
+
+    with pytest.raises(ValueError, match="TPU_SPECS"):
+        arch.chip_spec(_Dev(kind))
+
+
 def test_cost_capture_pairwise_distance():
     import jax.numpy as jnp
 
@@ -813,12 +838,21 @@ def test_bench_report_check_on_repo_is_noop():
     assert proc.returncode == 0, proc.stdout + proc.stderr
 
 
-def test_bench_report_trajectory_on_repo_artifacts():
-    """Acceptance: a trajectory over the committed BENCH_r01..r05.json."""
+def test_bench_report_trajectory_over_rounds(tmp_path):
+    """Acceptance: the CLI prints a trajectory over BENCH_r01..r05.json
+    and the last-good baseline (built here: the repo keeps no chip
+    headline yet)."""
+    metric = "fused top-64 2048x1000000x128"
+    for n in range(1, 6):
+        _write(tmp_path / f"BENCH_r0{n}.json",
+               {"n": n, "parsed": {"metric": metric, "value": 100.0 + n,
+                                   "unit": "GB/s"}})
+    _write(tmp_path / "BENCH_LAST_GOOD.json",
+           {"metric": metric, "value": 104.0, "unit": "GB/s"})
     root = os.path.join(os.path.dirname(__file__), "..")
     proc = subprocess.run(
-        [sys.executable, os.path.join(root, "tools", "bench_report.py")],
-        capture_output=True, text=True)
+        [sys.executable, os.path.join(root, "tools", "bench_report.py"),
+         "--dir", str(tmp_path)], capture_output=True, text=True)
     assert proc.returncode == 0, proc.stderr
     for tag in ("r01", "r05", "LAST_GOOD"):
         assert tag in proc.stdout

@@ -5,7 +5,7 @@ exhausted-retries with deterministic triggers), the graceful-degradation
 ladders (fused OOM rungs and tournament→allgather→host merge — each rung
 bit-identical in ids to the undegraded oracle), deadline scopes
 converting injected hangs into ``DeadlineExceededError`` within 2× the
-budget, the XLA error taxonomy, the zero-overhead no-fault contract,
+budget, the XLA error error classes, the zero-overhead no-fault contract,
 the tune-table degraded-load counter, and the perf-evidence guard that
 keeps degraded runs out of the baseline.
 """
@@ -89,7 +89,7 @@ def test_probabilistic_trigger_is_seed_deterministic():
     assert any(fires1) and not all(fires1)   # actually probabilistic
 
 
-def test_classify_xla_error_taxonomy():
+def test_classify_xla_error_classes():
     XlaRuntimeError = type("XlaRuntimeError", (Exception,), {})
     assert isinstance(
         classify_xla_error(XlaRuntimeError(
@@ -110,7 +110,7 @@ def test_classify_xla_error_taxonomy():
         classify_xla_error(RuntimeError(
             "Mosaic failed: scoped-vmem limit exceeded")),
         OutOfMemoryError)
-    # taxonomy members pass through unchanged
+    # error-class members pass through unchanged
     e = LogicError("x")
     assert classify_xla_error(e) is e
     # unrelated host errors are NOT wrapped
