@@ -48,12 +48,21 @@ merge with the PR-4 rank-ordered machinery
 
 Observability: build and search are ``@instrument``-ed, carry the
 ``ivf_build`` / ``ivf_search`` fault sites, emit ``marker`` flight
-events (probed-bytes fraction rides the search event), and the fine
-scan's XLA cost is captured through ``res.profiler.capture_fn``.
+events (probed-rows fraction rides the search event), and the fine
+scan's XLA cost is captured through ``res.profiler.capture_fn`` once
+per bucket shape at warm-up (:func:`warm_fine_scan`). Each host
+boundary of a search is a span nested under ``ann.search_ivf_flat``:
+``ann.coarse_probe``, ``ann.probe_fetch`` (the probe table's wait and
+copy to the host), ``ann.fine_scan_plan`` (schedule resolution and the
+per-chunk list schedule), ``ann.fine_scan`` (one per chunk's scan
+dispatch), ``ann.certificate_sync`` (the per-chunk rerun decision's
+host sync) and ``ann.fine_scan_rerun``. The probe table the plan
+already holds counts :data:`PROBED_ROWS` with no device work.
 """
 
 from __future__ import annotations
 
+import contextlib
 from functools import partial
 from typing import Optional, Tuple
 
@@ -64,13 +73,17 @@ import numpy as np
 from raft_tpu.core import env
 from raft_tpu.core.error import DeviceError, expects
 from raft_tpu.core.resources import ensure_resources
-from raft_tpu.observability import explain, instrument
+from raft_tpu.observability import explain, instrument, span
 from raft_tpu.observability.flight import get_flight_recorder
 from raft_tpu.observability.quality import (record_certificate,
                                             record_pending)
 from raft_tpu.observability.timeline import emit_marker
 from raft_tpu.resilience import fault_point
 from raft_tpu.resilience.policy import record_degradation
+
+#: database rows the fine scan's probe tables name (the sum of the
+#: probed lists' real sizes), counted on the host
+PROBED_ROWS = "raft_tpu_ivf_probed_rows_total"
 
 #: inverted-list row quantum: every list pads to a multiple of this
 #: (the fused pipeline's 8-row sublane multiple — a slab built at this
@@ -782,13 +795,18 @@ def warm_fine_scan(res, index: IvfFlatIndex, nq: int, k: int,
     frozen by the index. Called from the snapshot warmup so a live
     request can never pay a compile whichever way the
     :func:`resolve_fine_scan` crossover lands. Returns the list-major
-    rung count (0 = the bucket is outside the list-major envelope)."""
+    rung count (0 = the bucket is outside the list-major envelope).
+
+    The query-major chunk's XLA cost is captured here, once per bucket
+    shape, through ``res.profiler.capture_fn`` — never on a live
+    search."""
     from raft_tpu.ops.fine_scan_pallas import (LISTS_PER_CELL,
                                                pad_window)
 
     P = min(max(1, int(n_probes)), index.n_lists)
     if P >= index.n_lists or nq < 1:
         return 0            # the degenerate-exact plane — one schedule
+    res = ensure_resources(res)
     W = index.probe_window
     Wk = pad_window(W)
     d = index.d_orig
@@ -796,9 +814,18 @@ def warm_fine_scan(res, index: IvfFlatIndex, nq: int, k: int,
     out = search_ivf_flat(res, index, x0, k, n_probes=P,
                           fine_scan="query")
     jax.block_until_ready(out)
+    chunk = max(8, _FINE_TILE // max(1, P * W * max(d, 1)))
+    nq_c = min(nq, chunk)
+    try:
+        zeros = jnp.zeros((nq_c, P), jnp.int32)
+        res.profiler.capture_fn(
+            "ann.ivf_fine_scan", _fine_scan,
+            jnp.zeros((nq_c, d), jnp.float32), index.slab, index.ids,
+            index.yy_slab, zeros, zeros, k=k, P=P, W=W)
+    except Exception:
+        pass
     if resolve_fine_scan(index, nq, k, P, W, "list") != "list":
         return 0
-    chunk = max(8, _FINE_TILE // max(1, P * W * max(d, 1)))
     sizes = sorted({min(nq, chunk), nq % chunk or min(nq, chunk)})
     cap = max(1, -(-_max_entries(index) // LISTS_PER_CELL))
     rungs = sorted({min(1 << b, cap)
@@ -915,45 +942,55 @@ def _exact_search(res, index: IvfFlatIndex, x, k: int):
 
 # ------------------------------------------------------------ search
 def _query_major_chunk(index: IvfFlatIndex, xs, st, ps, k: int,
-                       P: int, W: int):
+                       P: int, W: int, nested: bool = False):
     """One query-major chunk: the per-query probe-window gather scan
     (f32, or the certified int8 gather with its f32 rerun) — the PR-8
     path, now shared by the query-major schedule and the list-major
-    certificate-failure rerun."""
+    certificate-failure rerun. ``nested`` (that rerun, already inside
+    its ``ann.fine_scan_rerun`` span) opens no spans of its own, so no
+    scan is timed twice."""
+
+    def stage(name: str):
+        return contextlib.nullcontext() if nested else span(name)
+
     if index.db_dtype != "int8":
         # exact f32 scan over the probed rows — no certificate, hence
         # no margin to note (the scan IS the oracle for its pool)
-        return _fine_scan(xs, index.slab, index.ids, index.yy_slab,
-                          st, ps, k=k, P=P, W=W)
+        with stage("ann.fine_scan"):
+            return _fine_scan(xs, index.slab, index.ids, index.yy_slab,
+                              st, ps, k=k, P=P, W=W)
     C = min(k + _IVF_RESCORE_PAD, P * W)
-    vals, ids_c, ok, margin = _fine_scan_q8(
-        xs, index.slab, index.slab_q, index.row_scale, index.ids,
-        index.yy_q, st, ps, k=k, P=P, W=W, C=C,
-        eq_rows=index.eq_rows)
+    with stage("ann.fine_scan"):
+        vals, ids_c, ok, margin = _fine_scan_q8(
+            xs, index.slab, index.slab_q, index.row_scale, index.ids,
+            index.yy_q, st, ps, k=k, P=P, W=W, C=C,
+            eq_rows=index.eq_rows)
     explain.note_margin("ann.search_ivf_flat", margin)
-    n_fail = int(jnp.sum(~ok))
-    # quality telemetry: this path ALREADY syncs (the int() above
-    # decides the rerun), so the counters cost nothing extra —
-    # the IVF slice of the certificate/fixup evidence plane
-    record_certificate("ann.search_ivf_flat",
-                       n_queries=int(xs.shape[0]), n_fail=n_fail,
-                       pool_width=C, fixup_rows=n_fail or None,
-                       rerun=bool(n_fail), db_dtype="int8",
-                       n_probes=P)
+    with stage("ann.certificate_sync"):
+        n_fail = int(jnp.sum(~ok))
+        # quality telemetry: this path ALREADY syncs (the int() above
+        # decides the rerun), so the counters cost nothing extra —
+        # the IVF slice of the certificate/fixup evidence plane
+        record_certificate("ann.search_ivf_flat",
+                           n_queries=int(xs.shape[0]), n_fail=n_fail,
+                           pool_width=C, fixup_rows=n_fail or None,
+                           rerun=bool(n_fail), db_dtype="int8",
+                           n_probes=P)
     if n_fail:
         # quantization certificate failed for some queries: the
         # true top-k may extend past the rescored pool — rerun the
         # chunk through the exact f32 scan and keep certified rows
         # from the quantized pass (bytes saved stand; correctness
         # never rides on the margin)
-        emit_marker("ivf_q8_fallback", n_fail=n_fail,
-                    nq=int(xs.shape[0]))
-        explain.note(rerun="q8_exact", rerun_rows=n_fail)
-        fv, fi = _fine_scan(xs, index.slab, index.ids,
-                            index.yy_slab, st, ps, k=k, P=P, W=W)
-        okc = ok[:, None]
-        vals = jnp.where(okc, vals, fv)
-        ids_c = jnp.where(okc, ids_c, fi)
+        with stage("ann.fine_scan_rerun"):
+            emit_marker("ivf_q8_fallback", n_fail=n_fail,
+                        nq=int(xs.shape[0]))
+            explain.note(rerun="q8_exact", rerun_rows=n_fail)
+            fv, fi = _fine_scan(xs, index.slab, index.ids,
+                                index.yy_slab, st, ps, k=k, P=P, W=W)
+            okc = ok[:, None]
+            vals = jnp.where(okc, vals, fv)
+            ids_c = jnp.where(okc, ids_c, fi)
     return vals, ids_c
 
 
@@ -973,48 +1010,57 @@ def _search_list_major(res, index: IvfFlatIndex, x, probes,
     nq = x.shape[0]
 
     def run_chunk(s0: int, s1: int):
-        xs, pr = x[s0:s1], probes[s0:s1]
-        st, ps = starts[s0:s1], psizes[s0:s1]
-        sched = build_list_schedule(index, probes_host[s0:s1])
-        if s0 == 0:
-            emit_marker("ivf_fine_scan_schedule", schedule="list",
-                        lists_probed=sched.n_lists_probed,
-                        q_max=sched.q_max,
-                        cells=sched.sched.shape[1] // 8,
-                        stream_rows=sched.stream_rows,
-                        db_dtype=index.db_dtype)
-        if quant:
-            vals, ids_c, ok, margin = _fine_scan_list_q8(
-                xs, jnp.asarray(sched.sched),
-                jnp.asarray(sched.scale_l), pr, index.slab_q,
-                index.slab, index.ids, index.yy_slab,
-                host["yy_lmax"], host["eq_list"], st, ps,
-                k=k, P=P, W=W, Wk=Wk)
-        else:
-            vals, ids_c, ok, margin = _fine_scan_list(
-                xs, jnp.asarray(sched.sched), pr, index.slab,
-                index.ids, index.yy_slab, st, ps, host["yy_lmax"],
-                k=k, P=P, W=W, Wk=Wk)
+        with span("ann.fine_scan_plan"):
+            sched = build_list_schedule(index, probes_host[s0:s1])
+            if s0 == 0:
+                emit_marker("ivf_fine_scan_schedule", schedule="list",
+                            lists_probed=sched.n_lists_probed,
+                            q_max=sched.q_max,
+                            cells=sched.sched.shape[1] // 8,
+                            stream_rows=sched.stream_rows,
+                            db_dtype=index.db_dtype)
+            sched_d = jnp.asarray(sched.sched)
+            scale_d = jnp.asarray(sched.scale_l) if quant else None
+        with span("ann.fine_scan"):
+            xs, pr = x[s0:s1], probes[s0:s1]
+            st, ps = starts[s0:s1], psizes[s0:s1]
+            if quant:
+                vals, ids_c, ok, margin = _fine_scan_list_q8(
+                    xs, sched_d, scale_d, pr, index.slab_q,
+                    index.slab, index.ids, index.yy_slab,
+                    host["yy_lmax"], host["eq_list"], st, ps,
+                    k=k, P=P, W=W, Wk=Wk)
+            else:
+                vals, ids_c, ok, margin = _fine_scan_list(
+                    xs, sched_d, pr, index.slab,
+                    index.ids, index.yy_slab, st, ps, host["yy_lmax"],
+                    k=k, P=P, W=W, Wk=Wk)
         explain.note_margin("ann.search_ivf_flat", margin)
-        n_fail = int(jnp.sum(~ok))
-        # same host sync the q8 gather path already pays — the
-        # list-major slice of the certificate/fixup evidence plane
-        record_certificate("ann.search_ivf_flat",
-                           n_queries=int(xs.shape[0]), n_fail=n_fail,
-                           pool_width=256, fixup_rows=n_fail or None,
-                           rerun=bool(n_fail),
-                           db_dtype=index.db_dtype, fine_scan="list")
+        with span("ann.certificate_sync"):
+            n_fail = int(jnp.sum(~ok))
+            # same host sync the q8 gather path already pays — the
+            # list-major slice of the certificate/fixup evidence plane
+            record_certificate("ann.search_ivf_flat",
+                               n_queries=int(xs.shape[0]),
+                               n_fail=n_fail, pool_width=256,
+                               fixup_rows=n_fail or None,
+                               rerun=bool(n_fail),
+                               db_dtype=index.db_dtype,
+                               fine_scan="list")
         if n_fail:
             # pool-completeness certificate failed: the true top-k
             # (or one of its ties) may hide outside the 256-slot pool
             # — rerun the chunk query-major and keep certified rows
-            emit_marker("ivf_list_fallback", n_fail=n_fail,
-                        nq=int(xs.shape[0]))
-            explain.note(rerun="list_query_major", rerun_rows=n_fail)
-            fv, fi = _query_major_chunk(index, xs, st, ps, k, P, W)
-            okc = ok[:, None]
-            vals = jnp.where(okc, vals, fv)
-            ids_c = jnp.where(okc, ids_c, fi)
+            with span("ann.fine_scan_rerun"):
+                emit_marker("ivf_list_fallback", n_fail=n_fail,
+                            nq=int(xs.shape[0]))
+                explain.note(rerun="list_query_major",
+                             rerun_rows=n_fail)
+                fv, fi = _query_major_chunk(index, xs, st, ps, k, P, W,
+                                            nested=True)
+                okc = ok[:, None]
+                vals = jnp.where(okc, vals, fv)
+                ids_c = jnp.where(okc, ids_c, fi)
         return vals, ids_c
 
     if nq <= chunk:
@@ -1105,13 +1151,30 @@ def search_ivf_flat(res, index, queries, k: int,
                      n_probes=P, n_lists=L, k=k)
         return _exact_search(res, base, x, k)
 
-    probes = _coarse_probe(res, base.centroids, x, P)       # [nq, P]
+    with span("ann.coarse_probe"):
+        probes = _coarse_probe(res, base.centroids, x, P)   # [nq, P]
+
+    # the probe table comes to the host once, for the schedule
+    # (resolve_fine_scan's crossover, build_list_schedule) and the
+    # probed-rows count; an explicitly query-major or sharded call
+    # keeps it on the device
+    req = fine_scan if fine_scan is not None \
+        else env.get("RAFT_TPU_IVF_FINE_SCAN")
+    probes_host = probed_rows = None
+    if req != "query" and not sharded:
+        with span("ann.probe_fetch"):
+            probes_host = np.asarray(probes)
+        probed_rows = int(base._np_sizes[probes_host].sum())
+        res.metrics.counter(
+            PROBED_ROWS, help="Database rows named by the IVF fine "
+                              "scan's probe tables").inc(probed_rows)
 
     if explain.active() is not None:
         # explain capture: probed list ids (first query's probe set —
         # the record is per-request-batch) + the probed-size histogram
         # and pool width; the host transfer only happens under capture
-        pr_np = np.asarray(probes)
+        pr_np = (probes_host if probes_host is not None
+                 else np.asarray(probes))
         sz = np.asarray(base.sizes)[pr_np]
         explain.note(plane="ivf_flat", n_probes=P, n_lists=L, k=k,
                      db_dtype=base.db_dtype,
@@ -1125,40 +1188,29 @@ def search_ivf_flat(res, index, queries, k: int,
                                      P * index.probe_window)
                                  if base.db_dtype == "int8" else k))
 
-    rec = get_flight_recorder()
-    if rec.enabled:
-        probed_rows = float(jnp.sum(jnp.take(base.sizes, probes)))
+    if get_flight_recorder().enabled:
+        # the probed fraction rides the marker only where the host
+        # already holds the probe table: no sync of its own
+        frac = ({} if probed_rows is None else
+                {"probed_frac": round(
+                    probed_rows / max(1, nq * base.n_rows), 6)})
         emit_marker("ivf_search", nq=nq, k=k, n_probes=P, n_lists=L,
-                    probed_frac=round(
-                        probed_rows / max(1, nq * base.n_rows), 6),
-                    sharded=bool(sharded))
+                    sharded=bool(sharded), **frac)
 
     if sharded:
         return _search_sharded(res, index, x, probes, k, P, W, merge)
-
-    starts = jnp.take(index.offsets[:-1], probes)
-    psizes = jnp.take(index.padded_sizes, probes)
-    d = x.shape[1]
-    chunk = max(8, _FINE_TILE // max(1, P * W * max(d, 1)))
-    try:
-        res.profiler.capture_fn(
-            "ann.ivf_fine_scan", _fine_scan,
-            x[:min(nq, chunk)], index.slab, index.ids, index.yy_slab,
-            starts[:min(nq, chunk)], psizes[:min(nq, chunk)],
-            k=k, P=P, W=W)
-    except Exception:
-        pass
 
     # fine-scan schedule: env/arg request resolved against the
     # list-major envelope + the cost-model crossover on the ACTUAL
     # probe table (resolve_fine_scan). A list-major failure — real or
     # injected at the fine_scan_list site — degrades back to the
     # query-major scan for this call, with identical ids.
-    req = fine_scan if fine_scan is not None \
-        else env.get("RAFT_TPU_IVF_FINE_SCAN")
-    probes_host = np.asarray(probes) if req != "query" else None
-    schedule = resolve_fine_scan(index, nq, k, P, W, req,
-                                 probes_np=probes_host, chunk=chunk)
+    with span("ann.fine_scan_plan"):
+        starts = jnp.take(index.offsets[:-1], probes)
+        psizes = jnp.take(index.padded_sizes, probes)
+        chunk = max(8, _FINE_TILE // max(1, P * W * max(x.shape[1], 1)))
+        schedule = resolve_fine_scan(index, nq, k, P, W, req,
+                                     probes_np=probes_host, chunk=chunk)
     explain.note(fine_scan=schedule)
     if schedule == "list":
         try:

@@ -14,11 +14,15 @@ capture for one request) and keeps the records in a bounded ring
 Design contract (the NULL_FLIGHT idiom, applied to capture):
 
 - **Zero allocation when disabled.** Capture state lives in a
-  ``threading.local``; every hook (:func:`note`, :func:`note_margin`,
-  :func:`stage`) is one attribute fetch + None check when no capture
-  is active — no dict, no context-manager object (``stage`` returns a
-  shared null context), no device sync. With ``RAFT_TPU_EXPLAIN_FRAC``
-  unset the dispatch path is byte-for-byte the pre-explain one.
+  ``threading.local``; every hook (:func:`note`, :func:`note_margin`)
+  is one attribute fetch + None check when no capture is active — no
+  dict, no device sync. With ``RAFT_TPU_EXPLAIN_FRAC`` unset the
+  dispatch path is byte-for-byte the pre-explain one.
+- **Spans are the stage timer.** Every
+  :func:`~raft_tpu.observability.span` (and ``@instrument``) that
+  closes on the capturing thread adds its time to the record's
+  ``stages`` under the span's name — ``ann.coarse_probe``,
+  ``ann.fine_scan``, ``serving.device_wait`` and the rest.
 - **Margins stay on device until finalize.** The certificate margin
   (``bound − (θ + err)``, the scalar the core computes anyway — see
   ``_knn_fused_core``'s ``with_stats`` path) is noted as an ARRAY
@@ -87,45 +91,11 @@ def want(rid: int, frac: float) -> bool:
     return frac >= 1.0 or _sample_hash(rid) < frac
 
 
-class _NullCtx:
-    """Shared no-op context manager — what :func:`stage` returns when
-    no capture is active (one object for the whole process: the
-    disabled path allocates nothing)."""
-
-    __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-
-_NULL_CTX = _NullCtx()
-
-
-class _StageTimer:
-    __slots__ = ("_cap", "_name", "_t0")
-
-    def __init__(self, cap: "ExplainCapture", name: str):
-        self._cap = cap
-        self._name = name
-
-    def __enter__(self):
-        self._t0 = time.perf_counter()
-        return self
-
-    def __exit__(self, *exc):
-        dt = time.perf_counter() - self._t0
-        st = self._cap.stages
-        st[self._name] = st.get(self._name, 0.0) + dt
-        return False
-
-
 class ExplainCapture:
     """One in-flight explain record: a scratch dict the search path
-    annotates through :func:`note`/:func:`note_margin`/:func:`stage`
-    while active, finalized into an immutable record dict afterwards.
+    annotates through :func:`note`/:func:`note_margin` (and every span
+    that closes, into ``stages``) while active, finalized into an
+    immutable record dict afterwards.
     Single-threaded by construction — it is installed in the capturing
     thread's ``threading.local`` and never shared."""
 
@@ -230,14 +200,6 @@ def note_margin(site: str, margin) -> None:
     if cap is None:
         return
     cap.margins.append((site, margin))
-
-
-def stage(name: str):
-    """Context manager timing one pipeline stage (coarse/fine/rescore/
-    merge/dispatch) into the active capture; the shared null context
-    when none is active."""
-    cap = getattr(_tls, "capture", None)
-    return _NULL_CTX if cap is None else _StageTimer(cap, name)
 
 
 def begin_capture(rids) -> Optional[ExplainCapture]:

@@ -15,7 +15,9 @@ therefore exported as ``raft_tpu_span_seconds`` (dispatch wall time,
 honest for eager callers, trace-time for jitted ones) while *execute*
 time flows through :meth:`raft_tpu.benchmark.Fixture.run`, which forces
 completion and subtracts the transport RTT via its probe, and emits
-``raft_tpu_benchmark_seconds`` through the same registry.
+``raft_tpu_benchmark_seconds`` through the same registry. A span that
+closes while an explain capture is active on its thread also adds its
+time to the capture's ``stages`` under its name.
 
 Disabled contract (``RAFT_TPU_DISABLE_TRACING``): ``instrument`` applied
 in a disabled process returns the function UNCHANGED — zero overhead, no
@@ -35,6 +37,7 @@ import jax
 import numpy as np
 
 from raft_tpu.core import nvtx
+from raft_tpu.observability import explain
 from raft_tpu.observability.metrics import ENV_DISABLED, get_registry
 from raft_tpu.observability.timeline import emit_span
 
@@ -61,6 +64,11 @@ def _record(name: str, parent: str, seconds: float, bytes_in: int,
             bytes_out: int, error: bool) -> None:
     emit_span(name, parent, seconds, bytes_in, bytes_out, error,
               stack=nvtx.range_stack())
+    # spans are the explain plane's stage timer: a capture active on
+    # this thread sums each span's time under its name
+    cap = explain.active()
+    if cap is not None:
+        cap.stages[name] = cap.stages.get(name, 0.0) + seconds
     reg = get_registry()
     labels = {"span": name, "range": parent}
     reg.counter(SPAN_CALLS, labels,

@@ -243,6 +243,8 @@ def _pool_out_shape(nqp: int):
 def _fine_scan_pallas_call(kernel, n_prefetch: int, n_cells: int,
                            nqp: int, Wk: int, d: int, q8: bool,
                            operands):
+    """The list-major pallas_call; its op name in a device trace is
+    ``fine_scan_list_major`` (``_q8`` for the int8 slab)."""
     out_spec = pl.BlockSpec((nqp, POOL_SLOTS), lambda s, *_: (0, 0),
                             memory_space=pltpu.VMEM)
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -274,6 +276,7 @@ def _fine_scan_pallas_call(kernel, n_prefetch: int, n_cells: int,
             dimension_semantics=("arbitrary",)),
         cost_estimate=cost,
         interpret=interpret_mode(),
+        name="fine_scan_list_major_q8" if q8 else "fine_scan_list_major",
     )(*operands)
 
 

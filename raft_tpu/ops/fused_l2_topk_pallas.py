@@ -465,6 +465,7 @@ def fused_l2_slot_topk(x, y_hi, y_lo, xx, yy, m_real,
         ),
         cost_estimate=_slot_cost(Q, M, d, S, passes),
         interpret=interpret_mode(),
+        name="fused_l2_slot_topk",
     )(m_real, *operands)
     return m1, i1, m2min
 
@@ -523,6 +524,7 @@ def fused_l2_slot_topk_dchunk(x, y_hi, y_lo, xx, yy, m_real,
         ),
         cost_estimate=_slot_cost(Q, M, d, S, passes),
         interpret=interpret_mode(),
+        name="fused_l2_slot_topk_dchunk",
     )(m_real, *operands)
     return m1, i1, m2min
 
@@ -1154,11 +1156,12 @@ def _packed_out_shape(Q: int, Sg: int):
 
 def _group_pallas_call(kernel_base, packed: bool,
                        x, y_hi, y_lo, yy_half, m_real,
-                       *, T: int, Qb: int, passes: int, tpg: int,
-                       dc=None, xxh=None, **fold_kw):
+                       *, name: str, T: int, Qb: int, passes: int,
+                       tpg: int, dc=None, xxh=None, **fold_kw):
     """Shared scaffolding for the four group-fold entry points
     ((un)packed × (single-shot | d-chunked)) — specs, operands, grid and
-    pallas_call in ONE place so the variants cannot drift."""
+    pallas_call in ONE place so the variants cannot drift. ``name`` is
+    the kernel's op name in a device trace (the entry point's own)."""
     _check_tiling(T, Qb)
     Q, d = x.shape
     M = y_hi.shape[0]
@@ -1222,6 +1225,7 @@ def _group_pallas_call(kernel_base, packed: bool,
         ),
         cost_estimate=_slot_cost(Q, M, d, G * _LANES, passes),
         interpret=interpret_mode(),
+        name=name,
     )(m_real, *operands)
 
 
@@ -1245,8 +1249,8 @@ def fused_l2_group_topk(x, y_hi, y_lo, yy_half, m_real,
     top-2 is ≥ that group's a3). Padded-only groups keep a=+inf,
     id=-1."""
     return _group_pallas_call(_group_kernel, False, x, y_hi, y_lo,
-                              yy_half, m_real, T=T, Qb=Qb, passes=passes,
-                              tpg=tpg)
+                              yy_half, m_real, name="fused_l2_group_topk",
+                              T=T, Qb=Qb, passes=passes, tpg=tpg)
 
 
 @functools.partial(jax.jit,
@@ -1259,8 +1263,9 @@ def fused_l2_group_topk_dchunk(x, y_hi, y_lo, yy_half, m_real,
     group fold runs on the last d-chunk only. Same (half-score)
     outputs."""
     return _group_pallas_call(_group_kernel_dchunk, False, x, y_hi, y_lo,
-                              yy_half, m_real, T=T, Qb=Qb, passes=passes,
-                              tpg=tpg, dc=dc)
+                              yy_half, m_real,
+                              name="fused_l2_group_topk_dchunk",
+                              T=T, Qb=Qb, passes=passes, tpg=tpg, dc=dc)
 
 
 @functools.partial(jax.jit,
@@ -1284,8 +1289,10 @@ def fused_l2_group_topk_packed(x, y_hi, y_lo, yy_half, m_real,
     _check_pack_envelope(T, tpg, pbits)
     base = _group_kernel_packed_stream if stream else _group_kernel_packed
     return _group_pallas_call(base, True, x, y_hi, y_lo,
-                              yy_half, m_real, T=T, Qb=Qb, passes=passes,
-                              tpg=tpg, pair=pair, pbits=pbits, xxh=xxh)
+                              yy_half, m_real,
+                              name="fused_l2_group_topk_packed",
+                              T=T, Qb=Qb, passes=passes, tpg=tpg,
+                              pair=pair, pbits=pbits, xxh=xxh)
 
 
 @functools.partial(jax.jit,
@@ -1300,7 +1307,9 @@ def fused_l2_group_topk_packed_dchunk(x, y_hi, y_lo, yy_half, m_real,
     :func:`fused_l2_group_topk_packed`."""
     _check_pack_envelope(T, tpg, pbits)
     return _group_pallas_call(_group_kernel_packed_dchunk, True, x, y_hi,
-                              y_lo, yy_half, m_real, T=T, Qb=Qb,
+                              y_lo, yy_half, m_real,
+                              name="fused_l2_group_topk_packed_dchunk",
+                              T=T, Qb=Qb,
                               passes=passes, tpg=tpg, dc=dc, pair=pair,
                               pbits=pbits, xxh=xxh)
 
@@ -1416,6 +1425,8 @@ def _group_pallas_call_db(dbuf: bool, x, y_hi, y_lo, yy_half, m_real,
         ),
         cost_estimate=cost,
         interpret=interpret_mode(),
+        name=("fused_l2_group_topk_packed_" + ("dbuf" if dbuf else "db")
+              + ("_q8" if q8_mode else "")),
     )(m_real, *operands)
 
 

@@ -34,7 +34,12 @@ timeline.emit_serving`); queue depth is a live gauge, request latency a
 p50/p99-capable histogram, and every batch/bucket/shed transition a
 labeled counter through the MetricsRegistry — the evidence surface
 ``benchmarks/bench_serving.py`` turns into the ``BENCH_SERVING.json``
-SLO artifact.
+SLO artifact. Each dispatched request's queue wait (enqueue → batch
+pop) is a histogram sample (``raft_tpu_serving_queue_wait_seconds``);
+on the batcher thread the ``serving.flush_wait`` span times the wait
+for co-riders, and ``serving.device_wait`` (inside
+``serving.execute_batch``) the wait for the device and the copy of the
+answers to the host.
 
 Quality plane (ISSUE 10):
 
@@ -89,7 +94,7 @@ from raft_tpu.core.error import (DeadlineExceededError, LogicError,
                                  RaftException, expects)
 from raft_tpu.core.logger import log_warn
 from raft_tpu.core.resources import ensure_resources
-from raft_tpu.observability import instrument
+from raft_tpu.observability import instrument, span
 from raft_tpu.observability.metrics import percentile
 from raft_tpu.observability.quality import (ShadowSampler,
                                             shadow_floor_default,
@@ -106,6 +111,7 @@ QUEUE_DEPTH = "raft_tpu_serving_queue_rows"
 BATCHES = "raft_tpu_serving_batches_total"
 BATCH_PAD_ROWS = "raft_tpu_serving_batch_pad_rows_total"
 SHED = "raft_tpu_serving_shed_total"
+QUEUE_WAIT = "raft_tpu_serving_queue_wait_seconds"
 
 FLUSH_MS_ENV = "RAFT_TPU_SERVING_FLUSH_MS"
 QUEUE_CAP_ENV = "RAFT_TPU_SERVING_QUEUE_CAP"
@@ -215,15 +221,14 @@ def execute_batch(plane, snap: IndexSnapshot, x: np.ndarray, bucket: int,
         # dispatch (the scope converts it within one poll interval)
         fault_point("serving_flush")
         vals, ids = plane(snap, xp)
-        interruptible.synchronize(vals, ids)
-        return vals, ids
+        with span("serving.device_wait"):
+            interruptible.synchronize(vals, ids)
+            return np.asarray(vals)[:n_valid], np.asarray(ids)[:n_valid]
 
     if budget_s is not None:
         with deadline(budget_s, label="serving_flush"):
-            vals, ids = _dispatch()
-    else:
-        vals, ids = _dispatch()
-    return np.asarray(vals)[:n_valid], np.asarray(ids)[:n_valid]
+            return _dispatch()
+    return _dispatch()
 
 
 class ServingEngine:
@@ -987,6 +992,18 @@ class ServingEngine:
         except Exception:
             pass
 
+    def _observe_queue_wait(self, reqs, now: float) -> None:
+        if not reqs:
+            return
+        try:
+            hist = self.res.metrics.histogram(
+                QUEUE_WAIT, help="Time a dispatched request waited in "
+                                 "the queue (enqueue → batch pop)")
+            for req in reqs:
+                hist.observe(max(0.0, now - req.enqueued_at))
+        except Exception:
+            pass
+
     def stats(self) -> dict:
         """Live counters + latency percentiles (engine-side; the
         BENCH_SERVING artifact measures client-side). Percentiles use
@@ -1118,7 +1135,18 @@ class ServingEngine:
             batch.append(req)
             total += req.n
         self._gauge_depth()
+        self._observe_queue_wait(
+            batch + ([mutation] if mutation is not None else []), now)
         return batch, total, expired, mutation
+
+    def _flush_due_locked(self) -> bool:
+        """The queue holds a batch to dispatch now: a forced flush, a
+        full top bucket, or an oldest request that has waited the flush
+        interval."""
+        return (self._force_flush
+                or sum(r.n for r in self._queue) >= self._ladder[-1]
+                or self._clock() - self._queue[0].enqueued_at
+                >= self._flush_interval_s)
 
     def _fail_expired(self, expired) -> None:
         for req in expired:
@@ -1137,15 +1165,15 @@ class ServingEngine:
                     if self._stop:
                         break
                     if self._queue:
-                        now = self._clock()
-                        total = sum(r.n for r in self._queue)
-                        oldest = self._queue[0].enqueued_at
-                        if (self._force_flush
-                                or total >= self._ladder[-1]
-                                or now - oldest
-                                >= self._flush_interval_s):
+                        if self._flush_due_locked():
                             break
-                        self._cond.wait(self._flush_interval_s / 2)
+                        # the oldest request waits for co-riders: timed
+                        # from the first look that finds it not yet due
+                        # until the batch is due
+                        with span("serving.flush_wait"):
+                            while (not self._stop and self._queue
+                                   and not self._flush_due_locked()):
+                                self._cond.wait(self._flush_interval_s / 2)
                     else:
                         # empty-queue flush timer tick: nothing to
                         # dispatch — the timer is a no-op, not a batch
@@ -1238,9 +1266,8 @@ class ServingEngine:
         cap = (explain_mod.begin_capture([r.rid for r in batch])
                if any(r.explain for r in batch) else None)
         try:
-            with explain_mod.stage("execute_batch"):
-                vals, ids = execute_batch(self._plane, snap, x, bucket,
-                                          total, budget)
+            vals, ids = execute_batch(self._plane, snap, x, bucket,
+                                      total, budget)
         except DeadlineExceededError as e:
             explain_mod.end_capture(cap, outcome="deadline",
                                     bucket=bucket, riders=len(batch))

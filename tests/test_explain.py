@@ -10,7 +10,7 @@ histogram)."""
 import numpy as np
 import pytest
 
-from raft_tpu.observability import explain
+from raft_tpu.observability import explain, span
 from raft_tpu.observability.explain import (MARGIN_HISTOGRAM,
                                             RING_CAPACITY, capture,
                                             clear_records,
@@ -54,12 +54,10 @@ def test_disabled_hooks_are_noops():
     assert explain.active() is None
     explain.note(plane="brute")          # no capture: swallowed
     explain.note_margin("site", np.ones(4))
-    ctx = explain.stage("fine")
-    # the disabled stage() returns THE shared null context — no
-    # allocation per call
-    assert ctx is explain.stage("other")
-    with ctx:
+    # a span closing with no capture active times into nothing
+    with span("ann.fine_scan"):
         pass
+    assert explain.active() is None
     assert explain.end_capture(None) is None
     assert explain_records() == []
 
@@ -85,7 +83,9 @@ def test_note_collects_repeats_and_finalize_builds_record():
         explain.note(fine_scan="list_major")      # differing repeats
         explain.note(fine_scan="query_major")     # collect into a list
         explain.note(n_probes=4)                  # equal repeat: kept
-        with explain.stage("coarse"):
+        with span("ann.coarse_probe"):       # spans time the stages
+            pass
+        with span("ann.coarse_probe"):       # repeats sum
             pass
         explain.note_margin("ann.search_ivf_flat",
                             np.array([0.5, -0.25, np.inf]))
@@ -94,7 +94,8 @@ def test_note_collects_repeats_and_finalize_builds_record():
     assert rec["rids"] == [7, 8] and rec["outcome"] == "ok"
     assert rec["plane"] == "ivf_flat" and rec["n_probes"] == 4
     assert rec["fine_scan"] == ["list_major", "query_major"]
-    assert "coarse" in rec["stages"]
+    assert list(rec["stages"]) == ["ann.coarse_probe"]
+    assert rec["stages"]["ann.coarse_probe"] >= 0.0
     m = rec["margins"]["ann.search_ivf_flat"]
     # the inf is filtered, the negative counted
     assert m["n"] == 2 and m["n_negative"] == 1
@@ -239,6 +240,11 @@ def test_engine_explain_flag_produces_record():
     rec = recs[0]
     assert rec["outcome"] == "ok" and rec["plane"] == "brute"
     assert rec["margins"]["runtime.knn_query"]["n"] >= 4
-    assert "execute_batch" in rec["stages"]
+    # the batch's spans are its stages: the dispatch and, inside it,
+    # the wait for the device
+    assert {"serving.execute_batch", "serving.device_wait"} \
+        <= set(rec["stages"])
+    assert rec["stages"]["serving.device_wait"] \
+        <= rec["stages"]["serving.execute_batch"]
     st = eng.stats()
     assert st["explain"] == {"frac": 0.0, "records": 1}

@@ -480,6 +480,29 @@ def test_capture_fn_records_prediction_side():
     assert entry["measured_seconds"] is None  # prediction-only
 
 
+def test_ivf_search_marker_probed_frac_is_the_host_count():
+    """The ``ivf_search`` marker's probed fraction is the probed-rows
+    counter's host count over the batch's rows — the probe table the
+    plan already fetched, no device reduction of its own."""
+    from raft_tpu.ann import build_ivf_flat, search_ivf_flat
+    from raft_tpu.ann.ivf_flat import PROBED_ROWS, _coarse_probe
+    from raft_tpu.core.resources import DeviceResources
+
+    res = DeviceResources(seed=0)
+    rng = np.random.default_rng(5)
+    X = rng.normal(size=(1500, 16)).astype(np.float32)
+    Q = rng.normal(size=(16, 16)).astype(np.float32)
+    idx = build_ivf_flat(res, X, n_lists=8, max_iter=3, seed=0)
+    search_ivf_flat(res, idx, Q, 5, n_probes=2, fine_scan="list")
+    probes = np.asarray(_coarse_probe(res, idx.centroids, Q, 2))
+    host_rows = int(np.asarray(idx.sizes)[probes].sum())
+    assert obs.get_registry().counter(PROBED_ROWS).value == host_rows
+    marker = [e for e in get_flight_recorder().events()
+              if e["kind"] == "marker" and e["name"] == "ivf_search"]
+    assert len(marker) == 1
+    assert marker[0]["probed_frac"] == round(host_rows / (16 * 1500), 6)
+
+
 # ------------------------------------------------------- static pinning
 def test_event_sites_pinned_to_known_kinds():
     ci = _tools_import("check_instrumented")

@@ -351,3 +351,121 @@ def test_ivf_build_validation(fixture):
         build_ivf_flat(res, X[:8], n_lists=9)
     with pytest.raises(Exception):
         build_ivf_flat(res, X[:8], n_lists=0)
+
+
+# ------------------------------------------------ spans and counters
+#: the host boundaries of a search, each a span under the search's own
+_CHILD_SPANS = {
+    "list": {"ann.coarse_probe", "ann.probe_fetch", "ann.fine_scan_plan",
+             "ann.fine_scan", "ann.certificate_sync"},
+    "query": {"ann.coarse_probe", "ann.fine_scan_plan", "ann.fine_scan"},
+}
+
+
+@pytest.fixture()
+def flight():
+    from raft_tpu.observability import FlightRecorder, set_flight_recorder
+
+    rec = FlightRecorder(capacity=8192)
+    prev = set_flight_recorder(rec)
+    yield rec
+    set_flight_recorder(prev)
+
+
+def _ann_spans(rec):
+    return [e for e in rec.events()
+            if e["kind"] == "span" and e["name"].startswith("ann.")]
+
+
+def _probed_rows():
+    from raft_tpu.ann.ivf_flat import PROBED_ROWS
+    from raft_tpu.observability import get_registry
+
+    return get_registry().counter(PROBED_ROWS).value
+
+
+@pytest.mark.parametrize("schedule", ["list", "query"])
+def test_search_spans_cover_each_host_boundary(fixture, flight,
+                                               monkeypatch, schedule):
+    """One span per host boundary, each under ``ann.search_ivf_flat``,
+    one ``ann.fine_scan`` per chunk; the probed-rows counter comes from
+    the host probe table, which an explicitly query-major call never
+    fetches."""
+    from raft_tpu.ann import ivf_flat
+    from raft_tpu.ann.ivf_flat import _coarse_probe
+
+    res, _, Q, _, idx = fixture
+    monkeypatch.setattr(ivf_flat, "_FINE_TILE", 1)    # 8-query chunks
+    x = Q[:32]
+    rows0 = _probed_rows()
+    flight.clear()
+    search_ivf_flat(res, idx, x, 10, n_probes=3, fine_scan=schedule)
+    spans = _ann_spans(flight)
+    children = [e for e in spans if e["name"] != "ann.search_ivf_flat"]
+    assert [e["name"] for e in spans].count("ann.search_ivf_flat") == 1
+    assert {e["name"] for e in children} == _CHILD_SPANS[schedule]
+    assert all(e["range"] == "ann.search_ivf_flat" for e in children)
+    names = [e["name"] for e in children]
+    assert names.count("ann.fine_scan") == 4           # 32 / 8 chunks
+    probes = np.asarray(_coarse_probe(res, idx.centroids, x, 3))
+    host_rows = int(np.asarray(idx.sizes)[probes].sum())
+    if schedule == "list":
+        assert names.count("ann.certificate_sync") == 4
+        assert names.count("ann.fine_scan_plan") == 1 + 4
+        assert _probed_rows() - rows0 == host_rows
+    else:
+        assert _probed_rows() == rows0
+
+
+def test_list_major_rerun_is_one_span_per_chunk(fixture, flight,
+                                                monkeypatch):
+    """A chunk whose certificate fails reruns inside one
+    ``ann.fine_scan_rerun`` span, with no scan span of its own nested
+    in it, and answers as the query-major scan does."""
+    import jax.numpy as jnp
+
+    from raft_tpu.ann import ivf_flat
+
+    res, _, Q, _, idx = fixture
+    monkeypatch.setattr(ivf_flat, "_FINE_TILE", 1)
+    scan = ivf_flat._fine_scan_list
+
+    def uncertified(*a, **kw):
+        vals, ids, ok, margin = scan(*a, **kw)
+        return vals, ids, jnp.zeros_like(ok), margin
+
+    monkeypatch.setattr(ivf_flat, "_fine_scan_list", uncertified)
+    flight.clear()
+    vl, il = search_ivf_flat(res, idx, Q[:32], 10, n_probes=3,
+                             fine_scan="list")
+    names = [e["name"] for e in _ann_spans(flight)]
+    assert names.count("ann.fine_scan_rerun") == 4
+    assert names.count("ann.fine_scan") == 4
+    vq, iq = search_ivf_flat(res, idx, Q[:32], 10, n_probes=3,
+                             fine_scan="query")
+    assert np.array_equal(np.asarray(il), np.asarray(iq))
+
+
+def test_warm_search_pays_no_observability_sync(fixture, flight,
+                                                monkeypatch):
+    """The fine scan's cost capture happens at warm-up, and a warm
+    query-major search moves nothing to the host: neither a cost
+    capture nor a device reduction for the flight marker."""
+    from raft_tpu.ann.ivf_flat import warm_fine_scan
+
+    res, _, Q, _, idx = fixture
+    captured = []
+    monkeypatch.setattr(type(res.profiler), "capture_fn",
+                        lambda self, entry, *a, **kw: captured.append(
+                            entry))
+    warm_fine_scan(res, idx, 32, 10, 3)
+    assert "ann.ivf_fine_scan" in captured
+    captured.clear()
+    flight.clear()
+    with jax.transfer_guard_device_to_host("disallow"):
+        out = search_ivf_flat(res, idx, Q[:32], 10, n_probes=3,
+                              fine_scan="query")
+    jax.block_until_ready(out)
+    assert captured == []
+    marker = [e for e in flight.events() if e["name"] == "ivf_search"]
+    assert len(marker) == 1 and "probed_frac" not in marker[0]

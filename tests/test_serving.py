@@ -474,6 +474,54 @@ def test_closed_loop_poisson_soak(data):
 
 
 # ------------------------------------------------------------------
+# batcher spans and the queue-wait histogram
+# ------------------------------------------------------------------
+
+def test_served_request_spans_and_queue_wait(data):
+    """One request served through the flush window: one
+    ``serving.flush_wait`` (the wait for co-riders) and one
+    ``serving.device_wait`` (inside ``serving.execute_batch``) on the
+    batcher thread, and one queue-wait sample no longer than the
+    request's latency. The request is queued before the batcher starts
+    and comes due only when the test clock moves past the window."""
+    from raft_tpu.observability import FlightRecorder, set_flight_recorder
+    from raft_tpu.serving.engine import LATENCY, QUEUE_WAIT
+
+    _, idx = data
+    reg = get_registry()
+    wait_h, lat_h = reg.histogram(QUEUE_WAIT), reg.histogram(LATENCY)
+    waits0, wait_sum0 = wait_h.count, wait_h.sum
+    lats0, lat_sum0 = lat_h.count, lat_h.sum
+    now = [0.0]
+    eng = ServingEngine(idx, k=K, buckets=(8,), flush_interval_s=0.2,
+                        clock=lambda: now[0])
+    rec = FlightRecorder(capacity=4096)
+    prev = set_flight_recorder(rec)
+    try:
+        fut = eng.submit(rng.normal(size=(3, D)).astype(np.float32))
+        eng.start()
+        time.sleep(0.5)          # the batcher finds it not yet due
+        now[0] = 0.25
+        fut.result(timeout=60)
+    finally:
+        set_flight_recorder(prev)
+        eng.stop()
+    spans = [e for e in rec.events() if e["kind"] == "span"
+             and e["name"] in ("serving.flush_wait", "serving.device_wait")]
+    assert sorted(e["name"] for e in spans) == ["serving.device_wait",
+                                                "serving.flush_wait"]
+    assert all(e["lane"] == "serving-batcher" for e in spans)
+    by_name = {e["name"]: e for e in spans}
+    assert by_name["serving.device_wait"]["range"] \
+        == "serving.execute_batch"
+    # the span lasted until the clock moved
+    assert by_name["serving.flush_wait"]["dur"] > 0.3
+    assert wait_h.count == waits0 + 1 and lat_h.count == lats0 + 1
+    wait = wait_h.sum - wait_sum0
+    assert 0.0 <= wait <= lat_h.sum - lat_sum0
+
+
+# ------------------------------------------------------------------
 # the ANN tier behind the same bucket ladder (ISSUE 8)
 # ------------------------------------------------------------------
 
