@@ -110,6 +110,12 @@ def _env_int(name: str, default: int, lo: int = 1) -> int:
 #: tile stays under ~256 MB f32
 _FINE_TILE = 1 << 26
 
+
+def _query_major_rows(P: int, W: int, d: int) -> int:
+    """Query rows of one query-major gather tile (:data:`_FINE_TILE`)."""
+    return max(8, _FINE_TILE // max(1, P * W * max(d, 1)))
+
+
 #: IVF storage dtypes for the fine-scan slab: "f32" gathers full rows;
 #: "int8" gathers the per-list symmetric-scale quantized slab (~¼ the
 #: probed bytes), prunes to a certified candidate pool and exact-
@@ -493,7 +499,6 @@ def build_list_schedule(index: IvfFlatIndex, probes_np) -> _ListSchedule:
                                                pad_window)
 
     probes_np = np.asarray(probes_np)
-    nq, P = probes_np.shape
     plist = np.unique(probes_np.ravel())
     plist = plist[plist >= 0].astype(np.int64)
     Lp = int(plist.size)
@@ -516,20 +521,20 @@ def build_list_schedule(index: IvfFlatIndex, probes_np) -> _ListSchedule:
     if index.db_dtype == "int8":
         scale_l[:n_ent] = _list_host(index)["scale"][lid]
     # the transposed [L_probed, q_max] query-group table: group g holds
-    # the query indices probing plist[g], padded to the 8-row quantum
-    # with the never-wins mask marking real entries
-    inv = {int(l): g for g, l in enumerate(plist)}
-    members: list = [[] for _ in range(Lp)]
-    for q in range(nq):
-        for l in probes_np[q]:
-            if l >= 0:
-                members[inv[int(l)]].append(q)
-    q_max = -(-max((len(m) for m in members), default=1) // 8) * 8
+    # the query indices probing plist[g] in query order, padded to the
+    # 8-row quantum with the never-wins mask marking real entries
+    qi, pj = np.nonzero(probes_np >= 0)          # (query, slot) order
+    grp = np.searchsorted(plist, probes_np[qi, pj])
+    order = np.argsort(grp, kind="stable")
+    grp, qi = grp[order], qi[order]
+    counts = np.bincount(grp, minlength=Lp)
+    q_max = -(-int(counts.max(initial=1)) // 8) * 8
     group = np.zeros((max(Lp, 1), q_max), np.int32)
     gmask = np.zeros((max(Lp, 1), q_max), bool)
-    for g, m in enumerate(members):
-        group[g, :len(m)] = m
-        gmask[g, :len(m)] = True
+    col = np.arange(grp.size) - np.repeat(np.cumsum(counts) - counts,
+                                          counts)
+    group[grp, col] = qi
+    gmask[grp, col] = True
     stream_rows = int(index._np_padded[plist].sum())
     return _ListSchedule(sched, scale_l, Lp, int(q_max), group, gmask,
                          stream_rows)
@@ -566,6 +571,32 @@ def _list_host(index: IvfFlatIndex) -> dict:
         host["eq_list"] = jnp.asarray(eq_list)
     index._list_host = host
     return host
+
+
+def _schedule_len(index: IvfFlatIndex) -> int:
+    """The index's longest list-major schedule, in whole cells: the
+    length every kernel call's schedule is padded to."""
+    from raft_tpu.ops.fine_scan_pallas import LISTS_PER_CELL
+
+    return -(-_max_entries(index) // LISTS_PER_CELL) * LISTS_PER_CELL
+
+
+def _kernel_schedule(index: IvfFlatIndex, sched: _ListSchedule):
+    """One chunk's list-major kernel operands: the schedule (and the
+    int8 scales) padded to :func:`_schedule_len`, so every chunk of one
+    row count runs one compiled program, and the grid cells that hold
+    the chunk's entries — all the kernel streams."""
+    from raft_tpu.ops.fine_scan_pallas import LISTS_PER_CELL
+
+    n = _schedule_len(index)
+    Lp = sched.sched.shape[1]
+    full = np.zeros((4, n), np.int32)
+    full[3] = -1
+    full[:, :Lp] = sched.sched
+    scale = np.ones(n, np.float32)
+    scale[:Lp] = sched.scale_l
+    n_ent = int(np.count_nonzero(sched.sched[3] >= 0))
+    return full, scale, max(1, -(-n_ent // LISTS_PER_CELL))
 
 
 def _pool_finish(x, xx, rows, slab, ids, yy_slab, starts_qm, psizes,
@@ -626,23 +657,32 @@ def _kernel_envelope(bound, theta, widen):
     return bound >= theta + widen
 
 
+def _probe_windows(offsets, padded_sizes, probes):
+    """Query-major slab windows of a probe table: each probed list's
+    start row and padded length, [nq, P] each."""
+    return jnp.take(offsets, probes), jnp.take(padded_sizes, probes)
+
+
 @partial(jax.jit, static_argnames=("k", "P", "W", "Wk"))
-def _fine_scan_list(x, sched, probes, slab, ids, yy_slab, starts_qm,
-                    psizes, yy_lmax, k: int, P: int, W: int, Wk: int):
+def _fine_scan_list(x, sched, n_cells, probes, slab, ids, yy_slab,
+                    offsets, padded_sizes, yy_lmax, k: int, P: int,
+                    W: int, Wk: int):
     """List-major fine scan over the f32 slab (see the block comment):
     kernel pools → exact rescore + canonical reorder → certificate.
     Returns (vals, ids, certified, margin) like :func:`_fine_scan_q8`
     — the caller reruns failed queries query-major, so ids never
-    drift."""
+    drift. The query-major windows the reorder needs come from the
+    index's ``offsets`` / ``padded_sizes`` here, on the device."""
     from raft_tpu.ops.fine_scan_pallas import fine_scan_list_major
 
     nq, d = x.shape
+    starts_qm, psizes = _probe_windows(offsets, padded_sizes, probes)
     xx = jnp.sum(x * x, axis=1, keepdims=True)
     xp, pp, nqp = _pad_kernel_operands(x, probes)
     xxp = jnp.concatenate(
         [xx, jnp.zeros((nqp - nq, 1), jnp.float32)]) if nqp > nq else xx
-    a1, i1, a2, i2, a3 = fine_scan_list_major(sched, xp, xxp, pp, slab,
-                                              Wk=Wk)
+    a1, i1, a2, i2, a3 = fine_scan_list_major(sched, n_cells, xp, xxp,
+                                              pp, slab, Wk=Wk)
     rows = jnp.concatenate([i1[:nq], i2[:nq]], axis=1)   # [nq, 256]
     vals, out_ids = _pool_finish(x, xx, rows, slab, ids, yy_slab,
                                  starts_qm, psizes, k, P, W)
@@ -658,9 +698,9 @@ def _fine_scan_list(x, sched, probes, slab, ids, yy_slab, starts_qm,
 
 
 @partial(jax.jit, static_argnames=("k", "P", "W", "Wk"))
-def _fine_scan_list_q8(x, sched, scale_l, probes, slab_q, slab, ids,
-                       yy_slab, yy_lmax, eq_list, starts_qm, psizes,
-                       k: int, P: int, W: int, Wk: int):
+def _fine_scan_list_q8(x, sched, scale_l, n_cells, probes, slab_q, slab,
+                       ids, yy_slab, yy_lmax, eq_list, offsets,
+                       padded_sizes, k: int, P: int, W: int, Wk: int):
     """INT8 list-major fine scan: streams the quantized slab (~¼ the
     probed bytes) through :func:`ops.fine_scan_pallas.
     fine_scan_list_major_q8` with per-list dequant-in-register scales,
@@ -670,12 +710,13 @@ def _fine_scan_list_q8(x, sched, scale_l, probes, slab_q, slab, ids,
     from raft_tpu.ops.fine_scan_pallas import fine_scan_list_major_q8
 
     nq, d = x.shape
+    starts_qm, psizes = _probe_windows(offsets, padded_sizes, probes)
     xx = jnp.sum(x * x, axis=1, keepdims=True)
     xp, pp, nqp = _pad_kernel_operands(x, probes)
     xxp = jnp.concatenate(
         [xx, jnp.zeros((nqp - nq, 1), jnp.float32)]) if nqp > nq else xx
     a1, i1, a2, i2, a3 = fine_scan_list_major_q8(
-        sched, scale_l, xp, xxp, pp, slab_q, Wk=Wk)
+        sched, scale_l, n_cells, xp, xxp, pp, slab_q, Wk=Wk)
     rows = jnp.concatenate([i1[:nq], i2[:nq]], axis=1)
     vals, out_ids = _pool_finish(x, xx, rows, slab, ids, yy_slab,
                                  starts_qm, psizes, k, P, W)
@@ -691,6 +732,29 @@ def _fine_scan_list_q8(x, sched, scale_l, probes, slab_q, slab, ids,
     return vals, out_ids, certified, bound - (theta + widen)
 
 
+def _list_major_chunk(index: IvfFlatIndex, nq: int) -> int:
+    """Query rows of one list-major kernel call for an ``nq``-row
+    search: the largest power-of-two multiple of 8 rows whose cell fits
+    the scoped-VMEM budget at the index's kernel window, width and slab
+    dtype, capped at ``nq`` rounded up to 8. The kernel holds the query
+    block and its pools resident and streams each probed list once per
+    call, so a search inside that envelope runs as one chunk: one
+    schedule, one dispatch, one certificate sync."""
+    from raft_tpu.ops.fine_scan_pallas import (fine_scan_vmem_footprint,
+                                               pad_window)
+    from raft_tpu.ops.fused_l2_topk_pallas import vmem_budget
+
+    Wk = pad_window(index.probe_window)
+    q8 = index.db_dtype == "int8"
+    cap = -(-max(1, nq) // 8) * 8
+    budget = vmem_budget()
+    rows = 8
+    while rows < cap and fine_scan_vmem_footprint(
+            Wk, 2 * rows, index.d_orig, q8) <= budget:
+        rows *= 2
+    return min(rows, cap)
+
+
 def resolve_fine_scan(index: IvfFlatIndex, nq: int, k: int, P: int,
                       W: int, requested: Optional[str] = None,
                       probes_np=None, chunk: Optional[int] = None
@@ -703,7 +767,9 @@ def resolve_fine_scan(index: IvfFlatIndex, nq: int, k: int, P: int,
     logged downgrade for an explicit ``list``): the slab must cover
     one kernel window, k the candidate pool, the probe count the
     128-lane probe table, the cell fit the scoped-VMEM budget, and on
-    real TPUs the feature width must be lane-aligned.
+    real TPUs the feature width must be lane-aligned and an int8 slab's
+    row quantum a multiple of 8. ``chunk`` is the query rows of one
+    kernel call (:func:`_list_major_chunk` for a search).
 
     ``auto`` consults the schema-5 ``fine_scan`` tune-table column
     (:func:`raft_tpu.tune.ivf.fine_scan_config`) first, then falls to
@@ -742,6 +808,9 @@ def resolve_fine_scan(index: IvfFlatIndex, nq: int, k: int, P: int,
         reason = "cell footprint over the scoped-VMEM budget"
     elif not interpret_mode() and d % 128:
         reason = f"d={d} is not lane-aligned on a real TPU"
+    elif not interpret_mode() and quant and index.row_quantum % 8:
+        reason = (f"row quantum {index.row_quantum} leaves int8 windows "
+                  f"off the 8-row tiling on a real TPU")
     if reason is not None:
         if req == "list":
             from raft_tpu.core.logger import log_warn
@@ -789,19 +858,21 @@ def warm_fine_scan(res, index: IvfFlatIndex, nq: int, k: int,
                    n_probes: int) -> int:
     """Pre-compile BOTH fine-scan schedules a serving bucket of ``nq``
     queries can reach: the query-major gather programs (through the
-    public wrapper, so its chunking/rerun programs warm too) and one
-    list-major program per power-of-two schedule-cell rung — the only
-    geometry axis that varies with batch content; everything else is
-    frozen by the index. Called from the snapshot warmup so a live
-    request can never pay a compile whichever way the
-    :func:`resolve_fine_scan` crossover lands. Returns the list-major
-    rung count (0 = the bucket is outside the list-major envelope).
+    public wrapper, so its chunking/rerun programs warm too) and, at
+    each list-major chunk size the bucket runs (:func:`_list_major_chunk`),
+    the one list-major program every schedule of that chunk runs
+    (:func:`_kernel_schedule` pads them all to one length and streams
+    only their own cells), plus the chunk's certificate-failure rerun
+    (its tile operands and row merge). Called from the snapshot warmup
+    so a live request can never pay a compile whichever way the
+    :func:`resolve_fine_scan` crossover lands. Returns the count of
+    list-major scan programs warmed (0 = the bucket is outside the
+    list-major envelope).
 
     The query-major chunk's XLA cost is captured here, once per bucket
     shape, through ``res.profiler.capture_fn`` — never on a live
     search."""
-    from raft_tpu.ops.fine_scan_pallas import (LISTS_PER_CELL,
-                                               pad_window)
+    from raft_tpu.ops.fine_scan_pallas import pad_window
 
     P = min(max(1, int(n_probes)), index.n_lists)
     if P >= index.n_lists or nq < 1:
@@ -814,8 +885,7 @@ def warm_fine_scan(res, index: IvfFlatIndex, nq: int, k: int,
     out = search_ivf_flat(res, index, x0, k, n_probes=P,
                           fine_scan="query")
     jax.block_until_ready(out)
-    chunk = max(8, _FINE_TILE // max(1, P * W * max(d, 1)))
-    nq_c = min(nq, chunk)
+    nq_c = min(nq, _query_major_rows(P, W, d))
     try:
         zeros = jnp.zeros((nq_c, P), jnp.int32)
         res.profiler.capture_fn(
@@ -824,35 +894,37 @@ def warm_fine_scan(res, index: IvfFlatIndex, nq: int, k: int,
             index.yy_slab, zeros, zeros, k=k, P=P, W=W)
     except Exception:
         pass
-    if resolve_fine_scan(index, nq, k, P, W, "list") != "list":
+    chunk = _list_major_chunk(index, nq)
+    if resolve_fine_scan(index, nq, k, P, W, "list", chunk=chunk) \
+            != "list":
         return 0
-    sizes = sorted({min(nq, chunk), nq % chunk or min(nq, chunk)})
-    cap = max(1, -(-_max_entries(index) // LISTS_PER_CELL))
-    rungs = sorted({min(1 << b, cap)
-                    for b in range(cap.bit_length() + 1)})
     host = _list_host(index)
-    for nq_c in sizes:
-        xc = jnp.zeros((nq_c, d), jnp.float32)
-        probes0 = jnp.zeros((nq_c, P), jnp.int32)
-        starts0 = jnp.zeros((nq_c, P), jnp.int32)
-        psz0 = jnp.zeros((nq_c, P), jnp.int32)
-        for cells in rungs:
-            Lp = cells * LISTS_PER_CELL
-            sched = np.zeros((4, Lp), np.int32)
-            sched[3, :] = -1
-            if index.db_dtype == "int8":
-                out = _fine_scan_list_q8(
-                    xc, jnp.asarray(sched), jnp.ones(Lp, jnp.float32),
-                    probes0, index.slab_q, index.slab, index.ids,
-                    index.yy_slab, host["yy_lmax"], host["eq_list"],
-                    starts0, psz0, k=k, P=P, W=W, Wk=Wk)
-            else:
-                out = _fine_scan_list(
-                    xc, jnp.asarray(sched), probes0, index.slab,
-                    index.ids, index.yy_slab, starts0, psz0,
-                    host["yy_lmax"], k=k, P=P, W=W, Wk=Wk)
-            jax.block_until_ready(out)
-    return len(rungs)
+    n = _schedule_len(index)
+    sched = np.zeros((4, n), np.int32)
+    sched[3] = -1
+    sizes = sorted({min(nq, chunk), nq % chunk or min(nq, chunk)})
+    for rows in sizes:
+        xc = jnp.zeros((rows, d), jnp.float32)
+        probes0 = jnp.zeros((rows, P), jnp.int32)
+        if index.db_dtype == "int8":
+            out = _fine_scan_list_q8(
+                xc, jnp.asarray(sched), jnp.ones(n, jnp.float32), 1,
+                probes0, index.slab_q, index.slab, index.ids,
+                index.yy_slab, host["yy_lmax"], host["eq_list"],
+                index.offsets, index.padded_sizes, k=k, P=P, W=W, Wk=Wk)
+        else:
+            out = _fine_scan_list(
+                xc, jnp.asarray(sched), 1, probes0, index.slab,
+                index.ids, index.yy_slab, index.offsets,
+                index.padded_sizes, host["yy_lmax"], k=k, P=P, W=W, Wk=Wk)
+        # the rerun of a chunk whose first and last rows fail: both
+        # tile shapes it can cut, and the merge at each
+        vals, ids_c, ok, _ = out
+        ok_h = np.ones(rows, bool)
+        ok_h[[0, -1]] = False
+        jax.block_until_ready(_rerun_failed_tiles(
+            index, xc, probes0, ok, ok_h, vals, ids_c, k, P, W))
+    return len(sizes)
 
 
 def _coarse_probe(res, centroids, x, n_probes: int):
@@ -994,14 +1066,57 @@ def _query_major_chunk(index: IvfFlatIndex, xs, st, ps, k: int,
     return vals, ids_c
 
 
+@partial(jax.jit, static_argnames=("rows",))
+def _tile_operands(x, probes, offsets, padded_sizes, r0, rows: int):
+    """The query block and probe windows of the ``rows``-row tile of a
+    chunk starting at row ``r0`` (traced: one program per tile shape)."""
+    xs = jax.lax.dynamic_slice_in_dim(x, r0, rows)
+    pr = jax.lax.dynamic_slice_in_dim(probes, r0, rows)
+    st, ps = _probe_windows(offsets, padded_sizes, pr)
+    return xs, st, ps
+
+
+@jax.jit
+def _merge_rows(vals, ids, ok, fv, fi, r0):
+    """Rows ``r0 …`` of (vals, ids) whose certificate failed (``ok``
+    false) take the rerun's (fv, fi); certified rows keep their own."""
+    n = fv.shape[0]
+    okc = jax.lax.dynamic_slice_in_dim(ok, r0, n)[:, None]
+    v = jnp.where(okc, jax.lax.dynamic_slice_in_dim(vals, r0, n), fv)
+    i = jnp.where(okc, jax.lax.dynamic_slice_in_dim(ids, r0, n), fi)
+    return (jax.lax.dynamic_update_slice_in_dim(vals, v, r0, 0),
+            jax.lax.dynamic_update_slice_in_dim(ids, i, r0, 0))
+
+
+def _rerun_failed_tiles(index: IvfFlatIndex, x, probes, ok, ok_h, vals,
+                        ids_c, k: int, P: int, W: int):
+    """Rerun query-major only the query-major tiles
+    (:func:`_query_major_rows`) of a list-major chunk that hold a row
+    whose certificate failed (``ok_h`` false, on the host), and merge
+    the failed rows back — the tile shapes, and their gather memory,
+    are the query-major schedule's own."""
+    nq = x.shape[0]
+    tile = _query_major_rows(P, W, x.shape[1])
+    for r0 in range(0, nq, tile):
+        rows = min(tile, nq - r0)
+        if ok_h[r0:r0 + rows].all():
+            continue
+        xs, st, ps = _tile_operands(x, probes, index.offsets,
+                                    index.padded_sizes, r0, rows=rows)
+        fv, fi = _query_major_chunk(index, xs, st, ps, k, P, W,
+                                    nested=True)
+        vals, ids_c = _merge_rows(vals, ids_c, ok, fv, fi, r0)
+    return vals, ids_c
+
+
 def _search_list_major(res, index: IvfFlatIndex, x, probes,
-                       probes_host, starts, psizes, k: int, P: int,
-                       W: int, chunk: int):
-    """The list-major driver: per chunk, invert the probe table into
-    the list schedule, run the stream-once kernel, and rerun any
-    certificate-failing chunk rows through the query-major scan — the
-    returned ids are bit-identical to the query-major oracle either
-    way."""
+                       probes_host, k: int, P: int, W: int, chunk: int):
+    """The list-major driver: per chunk of ``chunk`` rows
+    (:func:`_list_major_chunk`), invert the probe table into the list
+    schedule, run the stream-once kernel, and rerun the query-major
+    tiles that hold a certificate-failing row through the query-major
+    scan — the returned ids are bit-identical to the query-major oracle
+    either way. A search the chunk covers passes its operands whole."""
     from raft_tpu.ops.fine_scan_pallas import pad_window
 
     Wk = pad_window(W)
@@ -1012,36 +1127,40 @@ def _search_list_major(res, index: IvfFlatIndex, x, probes,
     def run_chunk(s0: int, s1: int):
         with span("ann.fine_scan_plan"):
             sched = build_list_schedule(index, probes_host[s0:s1])
+            full, scale, n_cells = _kernel_schedule(index, sched)
             if s0 == 0:
                 emit_marker("ivf_fine_scan_schedule", schedule="list",
                             lists_probed=sched.n_lists_probed,
-                            q_max=sched.q_max,
-                            cells=sched.sched.shape[1] // 8,
+                            q_max=sched.q_max, cells=n_cells,
                             stream_rows=sched.stream_rows,
+                            chunk_rows=s1 - s0,
                             db_dtype=index.db_dtype)
-            sched_d = jnp.asarray(sched.sched)
-            scale_d = jnp.asarray(sched.scale_l) if quant else None
+            sched_d = jnp.asarray(full)
+            scale_d = jnp.asarray(scale) if quant else None
         with span("ann.fine_scan"):
-            xs, pr = x[s0:s1], probes[s0:s1]
-            st, ps = starts[s0:s1], psizes[s0:s1]
+            if s1 - s0 == nq:
+                xs, pr = x, probes
+            else:
+                xs, pr = x[s0:s1], probes[s0:s1]
             if quant:
                 vals, ids_c, ok, margin = _fine_scan_list_q8(
-                    xs, sched_d, scale_d, pr, index.slab_q,
+                    xs, sched_d, scale_d, n_cells, pr, index.slab_q,
                     index.slab, index.ids, index.yy_slab,
-                    host["yy_lmax"], host["eq_list"], st, ps,
-                    k=k, P=P, W=W, Wk=Wk)
+                    host["yy_lmax"], host["eq_list"], index.offsets,
+                    index.padded_sizes, k=k, P=P, W=W, Wk=Wk)
             else:
                 vals, ids_c, ok, margin = _fine_scan_list(
-                    xs, sched_d, pr, index.slab,
-                    index.ids, index.yy_slab, st, ps, host["yy_lmax"],
-                    k=k, P=P, W=W, Wk=Wk)
+                    xs, sched_d, n_cells, pr, index.slab, index.ids,
+                    index.yy_slab, index.offsets, index.padded_sizes,
+                    host["yy_lmax"], k=k, P=P, W=W, Wk=Wk)
         explain.note_margin("ann.search_ivf_flat", margin)
         with span("ann.certificate_sync"):
-            n_fail = int(jnp.sum(~ok))
+            ok_h = np.asarray(ok)
+            n_fail = int(ok_h.size - np.count_nonzero(ok_h))
             # same host sync the q8 gather path already pays — the
             # list-major slice of the certificate/fixup evidence plane
             record_certificate("ann.search_ivf_flat",
-                               n_queries=int(xs.shape[0]),
+                               n_queries=int(ok_h.size),
                                n_fail=n_fail, pool_width=256,
                                fixup_rows=n_fail or None,
                                rerun=bool(n_fail),
@@ -1050,17 +1169,14 @@ def _search_list_major(res, index: IvfFlatIndex, x, probes,
         if n_fail:
             # pool-completeness certificate failed: the true top-k
             # (or one of its ties) may hide outside the 256-slot pool
-            # — rerun the chunk query-major and keep certified rows
+            # — rerun those rows' tiles query-major, keep certified rows
             with span("ann.fine_scan_rerun"):
                 emit_marker("ivf_list_fallback", n_fail=n_fail,
-                            nq=int(xs.shape[0]))
+                            nq=int(ok_h.size))
                 explain.note(rerun="list_query_major",
                              rerun_rows=n_fail)
-                fv, fi = _query_major_chunk(index, xs, st, ps, k, P, W,
-                                            nested=True)
-                okc = ok[:, None]
-                vals = jnp.where(okc, vals, fv)
-                ids_c = jnp.where(okc, ids_c, fi)
+                vals, ids_c = _rerun_failed_tiles(
+                    index, xs, pr, ok, ok_h, vals, ids_c, k, P, W)
         return vals, ids_c
 
     if nq <= chunk:
@@ -1204,20 +1320,22 @@ def search_ivf_flat(res, index, queries, k: int,
     # list-major envelope + the cost-model crossover on the ACTUAL
     # probe table (resolve_fine_scan). A list-major failure — real or
     # injected at the fine_scan_list site — degrades back to the
-    # query-major scan for this call, with identical ids.
+    # query-major scan for this call, with identical ids. The
+    # list-major chunk follows its own kernel's VMEM envelope; the
+    # query-major one, the gather tile.
     with span("ann.fine_scan_plan"):
-        starts = jnp.take(index.offsets[:-1], probes)
-        psizes = jnp.take(index.padded_sizes, probes)
-        chunk = max(8, _FINE_TILE // max(1, P * W * max(x.shape[1], 1)))
+        chunk = _list_major_chunk(index, nq)
         schedule = resolve_fine_scan(index, nq, k, P, W, req,
                                      probes_np=probes_host, chunk=chunk)
+        if schedule != "list":
+            starts, psizes = _probe_windows(index.offsets,
+                                            index.padded_sizes, probes)
     explain.note(fine_scan=schedule)
     if schedule == "list":
         try:
             fault_point("fine_scan_list")
             return _search_list_major(res, index, x, probes,
-                                      probes_host, starts, psizes,
-                                      k, P, W, chunk)
+                                      probes_host, k, P, W, chunk)
         except DeviceError as e:
             # injected faults and classified device failures only: a
             # kernel that fails to compile or lower propagates
@@ -1230,7 +1348,10 @@ def search_ivf_flat(res, index, queries, k: int,
             log_warn("list-major fine scan failed (%s: %s) — "
                      "degrading to the query-major scan for this "
                      "call", type(e).__name__, e)
+        starts, psizes = _probe_windows(index.offsets,
+                                        index.padded_sizes, probes)
 
+    chunk = _query_major_rows(P, W, x.shape[1])
     if nq <= chunk:
         return _query_major_chunk(index, x, starts, psizes, k, P, W)
     outs = [_query_major_chunk(index, x[s:s + chunk],
@@ -1401,7 +1522,7 @@ def _search_sharded(res, index: ShardedIvfIndex, x, probes, k: int,
     else:
         fault_point("merge_allgather")
     d = x.shape[1]
-    chunk = max(8, _FINE_TILE // max(1, P * W * max(d, 1)))
+    chunk = _query_major_rows(P, W, d)
     if nq > chunk:
         outs = [_search_sharded(res, index, x[s:s + chunk],
                                 probes[s:s + chunk], k, P, W, merge)

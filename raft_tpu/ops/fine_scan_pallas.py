@@ -10,13 +10,14 @@ per frontier point as ``gather_overread`` by
 
 These kernels invert the schedule: the grid walks the PROBED LISTS
 (8 lists per cell — the schedule builder buckets the probed-list table
-to the 8-row quantum and rounds the cell count to a power of two so one
-compiled program serves a sweep), each cell streams its lists' slab
-windows from HBM ONCE through a manual 2-slot double-buffered DMA
-pipeline (the ``_group_kernel_packed_dbuf`` idiom) while the WHOLE
-query block stays VMEM-resident, and a per-(query, list) membership
-test against the resident probe table masks queries that did not probe
-the list to the never-wins +inf. Every scored row folds into a
+to the 8-row quantum; the grid's cell count is a traced operand, so one
+compiled program serves every schedule of one query block), each cell
+streams its lists' slab windows from HBM ONCE through a manual 2-slot
+double-buffered DMA pipeline (the ``_group_kernel_packed_dbuf`` idiom)
+while the WHOLE query block stays VMEM-resident, and a per-(query,
+list) membership test against the resident probe table masks queries
+that did not probe the list to the never-wins +inf. Every scored row
+folds into a
 per-query 128-slot candidate pool (per lane-class top-2 values + global
 slab-row ids, plus the running 3rd-min — the same certificate shape the
 fused brute kernels carry): outputs are revisited [nqp, 128] blocks, so
@@ -70,10 +71,10 @@ def fine_scan_vmem_footprint(Wk: int, nqp: int, d: int,
     2 DMA window slots (f32 or int8), the resident query block (f32 +
     the bf16 hi/lo split), the resident probe table, ~3 live [nqp, Wk]
     f32 score temporaries (d2 + mask/select intermediates), and the
-    5-buffer fold state. Conservative: the SIFT-1M cells (Wk=2048,
-    nqp 16/64) compile for a described v5e (tests/test_tpu_aot.py), and
-    the served list-major path ran on the chip (PR 21); no reject has
-    calibrated it."""
+    5-buffer fold state. Conservative: at the SIFT-1M window (Wk=2048,
+    d=128) it admits nqp ≤ 256, and nqp 16–256 compile for a described
+    v5e, f32 and int8 (tests/test_tpu_aot.py); no reject has calibrated
+    it."""
     bytes_ = 2 * Wk * d * (1 if q8 else 4)        # 2 DMA window slots
     bytes_ += nqp * d * (4 + 2 + 2)               # x f32 + hi/lo bf16
     bytes_ += nqp * _LANES * 4                    # probe table (Pp=128)
@@ -180,8 +181,15 @@ def _list_kernel_body(sched_ref, scale_ref, x_ref, xx_ref, probes_ref,
 
     def body(scratch, sem):
         def dma(slot, j):
+            start = sched_ref[0, j]
+            if q8:
+                # the int8 slab's HBM tiling is 8 rows: Mosaic slices it
+                # only at a start it can prove 8-aligned, which every
+                # window start is at a row quantum of 8 (the caller's
+                # envelope)
+                start = pl.multiple_of(start, 8)
             return pltpu.make_async_copy(
-                slab_ref.at[pl.ds(sched_ref[0, j], Wk), :],
+                slab_ref.at[pl.ds(start, Wk), :],
                 scratch.at[slot], sem.at[slot])
 
         j0 = s * LISTS_PER_CELL
@@ -240,11 +248,13 @@ def _pool_out_shape(nqp: int):
     ]
 
 
-def _fine_scan_pallas_call(kernel, n_prefetch: int, n_cells: int,
+def _fine_scan_pallas_call(kernel, n_prefetch: int, n_cells, Lp: int,
                            nqp: int, Wk: int, d: int, q8: bool,
                            operands):
-    """The list-major pallas_call; its op name in a device trace is
-    ``fine_scan_list_major`` (``_q8`` for the int8 slab)."""
+    """The list-major pallas_call over ``n_cells`` grid cells (a traced
+    bound: one compiled kernel serves every schedule length up to
+    ``Lp``); its op name in a device trace is ``fine_scan_list_major``
+    (``_q8`` for the int8 slab)."""
     out_spec = pl.BlockSpec((nqp, POOL_SLOTS), lambda s, *_: (0, 0),
                             memory_space=pltpu.VMEM)
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -261,7 +271,7 @@ def _fine_scan_pallas_call(kernel, n_prefetch: int, n_cells: int,
         ],
         out_specs=[out_spec] * 5,
     )
-    L = n_cells * LISTS_PER_CELL
+    L = Lp            # the most the grid can stream
     cost = pl.CostEstimate(
         # 3 bf16 cross passes + 2 norm passes (q8: ≤ 2 + 2)
         flops=2 * nqp * L * Wk * d * (4 if q8 else 5),
@@ -281,7 +291,7 @@ def _fine_scan_pallas_call(kernel, n_prefetch: int, n_cells: int,
 
 
 @functools.partial(jax.jit, static_argnames=("Wk",))
-def fine_scan_list_major(sched, x, xx, probes, slab, Wk: int
+def fine_scan_list_major(sched, n_cells, x, xx, probes, slab, Wk: int
                          ) -> Tuple[jax.Array, jax.Array, jax.Array,
                                     jax.Array, jax.Array]:
     """List-major fine scan over the f32 slab.
@@ -293,6 +303,9 @@ def fine_scan_list_major(sched, x, xx, probes, slab, Wk: int
         ``(0, 0, 0, -1)``. Window starts are clamp-adjusted by the
         schedule builder so every [start, start+Wk) window stays inside
         the slab.
+      n_cells: int32 scalar, the grid cells to stream (the first
+        ``n_cells·LISTS_PER_CELL`` entries, ≤ Lp); traced, so one
+        compiled program serves every schedule a query block can have.
       x: [nqp, d] f32 resident query block (nqp a multiple of 8; pad
         rows zero).
       xx: [nqp, 1] exact f32 query squared norms.
@@ -325,13 +338,13 @@ def fine_scan_list_major(sched, x, xx, probes, slab, Wk: int
                           passes=3)
 
     return _fine_scan_pallas_call(
-        kernel_nq8, 1, Lp // LISTS_PER_CELL, nqp, Wk, d, False,
+        kernel_nq8, 1, n_cells, Lp, nqp, Wk, d, False,
         (sched, x, xx, probes, slab))
 
 
 @functools.partial(jax.jit, static_argnames=("Wk", "passes"))
-def fine_scan_list_major_q8(sched, scale_l, x, xx, probes, slab_q,
-                            Wk: int, passes: int = 3
+def fine_scan_list_major_q8(sched, scale_l, n_cells, x, xx, probes,
+                            slab_q, Wk: int, passes: int = 3
                             ) -> Tuple[jax.Array, jax.Array, jax.Array,
                                        jax.Array, jax.Array]:
     """INT8 list-major fine scan: same schedule/pool contract as
@@ -359,7 +372,7 @@ def fine_scan_list_major_q8(sched, scale_l, x, xx, probes, slab_q,
                           q8=True, passes=passes)
 
     return _fine_scan_pallas_call(
-        kernel_q8, 2, Lp // LISTS_PER_CELL, nqp, Wk, d, True,
+        kernel_q8, 2, n_cells, Lp, nqp, Wk, d, True,
         (sched, scale_l, x, xx, probes, slab_q))
 
 

@@ -395,7 +395,9 @@ def test_search_spans_cover_each_host_boundary(fixture, flight,
     from raft_tpu.ann.ivf_flat import _coarse_probe
 
     res, _, Q, _, idx = fixture
-    monkeypatch.setattr(ivf_flat, "_FINE_TILE", 1)    # 8-query chunks
+    monkeypatch.setattr(ivf_flat, "_FINE_TILE", 1)    # 8-query tiles
+    monkeypatch.setattr(ivf_flat, "_list_major_chunk",
+                        lambda index, nq: 8)          # 8-query chunks
     x = Q[:32]
     rows0 = _probed_rows()
     flight.clear()
@@ -427,7 +429,8 @@ def test_list_major_rerun_is_one_span_per_chunk(fixture, flight,
     from raft_tpu.ann import ivf_flat
 
     res, _, Q, _, idx = fixture
-    monkeypatch.setattr(ivf_flat, "_FINE_TILE", 1)
+    monkeypatch.setattr(ivf_flat, "_list_major_chunk",
+                        lambda index, nq: 8)
     scan = ivf_flat._fine_scan_list
 
     def uncertified(*a, **kw):
@@ -469,3 +472,193 @@ def test_warm_search_pays_no_observability_sync(fixture, flight,
     assert captured == []
     marker = [e for e in flight.events() if e["name"] == "ivf_search"]
     assert len(marker) == 1 and "probed_frac" not in marker[0]
+
+
+# ------------------------------------------------ list-major chunking
+@pytest.fixture(scope="module")
+def idx8(fixture):
+    res, X, _, _, _ = fixture
+    return build_ivf_flat(res, X, n_lists=24, max_iter=6, seed=1,
+                          db_dtype="int8")
+
+
+def test_list_major_chunk_follows_the_vmem_envelope(fixture):
+    """The list-major chunk is the search's rows rounded up to 8, up to
+    the largest power-of-two multiple of 8 whose kernel cell fits the
+    scoped-VMEM budget."""
+    from raft_tpu.ann.ivf_flat import _list_major_chunk
+    from raft_tpu.ops.fine_scan_pallas import (fine_scan_vmem_footprint,
+                                               pad_window)
+    from raft_tpu.ops.fused_l2_topk_pallas import vmem_budget
+
+    _, _, _, _, idx = fixture
+    assert _list_major_chunk(idx, 1) == 8
+    assert _list_major_chunk(idx, 20) == 24
+    assert _list_major_chunk(idx, 32) == 32
+    big = _list_major_chunk(idx, 1 << 16)
+    Wk = pad_window(idx.probe_window)
+    assert big % 8 == 0 and (big // 8) & (big // 8 - 1) == 0
+    assert fine_scan_vmem_footprint(Wk, big, idx.d_orig) <= vmem_budget()
+    assert fine_scan_vmem_footprint(Wk, 2 * big, idx.d_orig) \
+        > vmem_budget()
+
+
+def test_list_major_search_is_one_chunk(fixture, flight):
+    """An unforced 32-row list-major search fits one kernel call: one
+    scan dispatch, one certificate sync, the search's plan and the
+    chunk's, and the schedule marker names the chunk's rows."""
+    res, _, Q, _, idx = fixture
+    flight.clear()
+    search_ivf_flat(res, idx, Q[:32], 10, n_probes=3, fine_scan="list")
+    names = [e["name"] for e in _ann_spans(flight)]
+    assert names.count("ann.fine_scan") == 1
+    assert names.count("ann.certificate_sync") == 1
+    assert names.count("ann.fine_scan_plan") == 1 + 1
+    marker = [e for e in flight.events()
+              if e["name"] == "ivf_fine_scan_schedule"]
+    assert len(marker) == 1 and marker[0]["chunk_rows"] == 32
+
+
+@pytest.mark.parametrize("dtype", ["f32", "int8"])
+def test_list_major_ids_do_not_depend_on_the_chunk(fixture, idx8,
+                                                   monkeypatch, dtype):
+    """A query's pool does not depend on which queries share its
+    chunk: ids at the whole-search chunk equal those at 8-row chunks,
+    and the query-major oracle's (f32 bit for bit; int8 as id sets,
+    the quantized contract)."""
+    from raft_tpu.ann import ivf_flat
+
+    res, _, Q, _, idx = fixture
+    index = idx if dtype == "f32" else idx8
+    _, whole = search_ivf_flat(res, index, Q[:32], 10, n_probes=3,
+                               fine_scan="list")
+    _, oracle = search_ivf_flat(res, index, Q[:32], 10, n_probes=3,
+                                fine_scan="query")
+    monkeypatch.setattr(ivf_flat, "_list_major_chunk",
+                        lambda index, nq: 8)
+    _, eight = search_ivf_flat(res, index, Q[:32], 10, n_probes=3,
+                               fine_scan="list")
+    whole, eight = np.asarray(whole), np.asarray(eight)
+    assert np.array_equal(whole, eight)
+    if dtype == "f32":
+        assert np.array_equal(whole, np.asarray(oracle))
+    else:
+        assert _id_sets(whole) == _id_sets(oracle)
+
+
+def test_list_major_rerun_is_only_the_failed_tiles(fixture, flight,
+                                                   monkeypatch):
+    """Rows whose certificate fails rerun query-major in the 8-row
+    query-major tiles that hold them, and only those; the answer is the
+    query-major oracle's."""
+    from raft_tpu.ann import ivf_flat
+
+    res, _, Q, _, idx = fixture
+    monkeypatch.setattr(ivf_flat, "_FINE_TILE", 1)    # 8-query tiles
+    scan = ivf_flat._fine_scan_list
+    failed = np.zeros(32, bool)
+    failed[[3, 20, 22]] = True
+
+    def uncertified(*a, **kw):
+        vals, ids, ok, margin = scan(*a, **kw)
+        return vals, ids, jax.device_put(~failed), margin
+
+    tiles = []
+    operands = ivf_flat._tile_operands
+
+    def spy(*a, **kw):
+        tiles.append((int(a[4]), kw["rows"]))
+        return operands(*a, **kw)
+
+    monkeypatch.setattr(ivf_flat, "_fine_scan_list", uncertified)
+    monkeypatch.setattr(ivf_flat, "_tile_operands", spy)
+    flight.clear()
+    _, il = search_ivf_flat(res, idx, Q[:32], 10, n_probes=3,
+                            fine_scan="list")
+    names = [e["name"] for e in _ann_spans(flight)]
+    assert names.count("ann.fine_scan") == 1
+    assert names.count("ann.fine_scan_rerun") == 1
+    assert tiles == [(0, 8), (16, 8)]
+    fallback = [e for e in flight.events()
+                if e["name"] == "ivf_list_fallback"]
+    assert len(fallback) == 1 and fallback[0]["n_fail"] == 3
+    _, iq = search_ivf_flat(res, idx, Q[:32], 10, n_probes=3,
+                            fine_scan="query")
+    assert np.array_equal(np.asarray(il), np.asarray(iq))
+
+
+@pytest.mark.parametrize("forced_rerun", [False, True])
+def test_warm_list_major_search_compiles_nothing(fixture, monkeypatch,
+                                                 forced_rerun):
+    """After ``warm_fine_scan`` a live 32-row list-major search lowers
+    and compiles no program, a rerun of failed rows included."""
+    from raft_tpu.ann import ivf_flat
+    from raft_tpu.ann.ivf_flat import warm_fine_scan
+
+    res, _, Q, _, idx = fixture
+    monkeypatch.setattr(ivf_flat, "_FINE_TILE", 1)    # 8-query tiles
+    assert warm_fine_scan(res, idx, 32, 10, 3) >= 1
+    if forced_rerun:
+        scan = ivf_flat._fine_scan_list
+        ok = np.ones(32, bool)
+        ok[[5, 30]] = False
+
+        def uncertified(*a, **kw):
+            vals, ids, _, margin = scan(*a, **kw)
+            return vals, ids, jax.device_put(ok), margin
+
+        monkeypatch.setattr(ivf_flat, "_fine_scan_list", uncertified)
+    events = []
+
+    def on_event(event, duration, **_):
+        if event in ("/jax/core/compile/jaxpr_to_mlir_module_duration",
+                     "/jax/core/compile/backend_compile_duration"):
+            events.append(event)
+
+    misses = res.compile_cache.misses
+    jax.monitoring.register_event_duration_secs_listener(on_event)
+    try:
+        out = search_ivf_flat(res, idx, Q[32:64], 10, n_probes=3,
+                              fine_scan="list")
+        jax.block_until_ready(out)
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on_event)
+    assert events == []
+    assert res.compile_cache.misses == misses
+
+
+def test_kernel_schedule_streams_only_its_cells(fixture):
+    """Every chunk's schedule is padded to the index's longest, and the
+    kernel, given the chunk's own cell count, pools exactly what the
+    unpadded schedule pools."""
+    import jax.numpy as jnp
+
+    from raft_tpu.ann.ivf_flat import (_coarse_probe, _kernel_schedule,
+                                       _max_entries, _pad_kernel_operands,
+                                       build_list_schedule)
+    from raft_tpu.ops.fine_scan_pallas import (LISTS_PER_CELL,
+                                               fine_scan_list_major,
+                                               pad_window)
+
+    res, _, Q, _, idx = fixture
+    x = jnp.asarray(Q[:16])
+    probes = _coarse_probe(res, idx.centroids, x, 3)
+    sched = build_list_schedule(idx, np.asarray(probes))
+    full, scale, n_cells = _kernel_schedule(idx, sched)
+    n = full.shape[1]
+    assert full.shape == (4, n) and scale.shape == (n,)
+    assert n == -(-_max_entries(idx) // LISTS_PER_CELL) * LISTS_PER_CELL
+    n_ent = int((sched.sched[3] >= 0).sum())
+    assert n_cells == -(-n_ent // LISTS_PER_CELL)
+    assert (full[3, n_ent:] == -1).all()
+    assert np.array_equal(full[:, :n_ent], sched.sched[:, :n_ent])
+    xp, pp, _ = _pad_kernel_operands(x, probes)
+    xx = jnp.sum(xp * xp, axis=1, keepdims=True)
+    Wk = pad_window(idx.probe_window)
+    short = sched.sched.shape[1] // LISTS_PER_CELL
+    a = fine_scan_list_major(jnp.asarray(sched.sched), short, xp, xx, pp,
+                             idx.slab, Wk=Wk)
+    b = fine_scan_list_major(jnp.asarray(full), n_cells, xp, xx, pp,
+                             idx.slab, Wk=Wk)
+    for u, v in zip(a, b):
+        assert np.array_equal(np.asarray(u), np.asarray(v))

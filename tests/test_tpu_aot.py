@@ -23,6 +23,9 @@ from jax.sharding import SingleDeviceSharding
 N_ROWS, DIM, K_EXACT, N_QUERIES = 1 << 20, 128, 64, 2048
 # served IVF-Flat / IVF-PQ: 1024 lists; a list-major chunk of queries
 N_LISTS, WK, NQP, LP = 1024, 2048, 16, 512
+# the IVF-Flat list-major schedule length: the SIFT-1M index's most
+# entries (≈ 1,100 windows), in whole 8-entry cells
+SCHED = 138 * 8
 # IVF-PQ codes: pq_dim=32 subspaces of 8 bits
 PQ_DIM, PQ_BITS = 32, 8
 
@@ -111,18 +114,31 @@ def test_knn_fused_selected_pipeline_compiles(chip):
              with_stats=True)
 
 
-@pytest.mark.parametrize("nqp", [NQP, 64])
-def test_fine_scan_list_major_compiles(chip, nqp):
+@pytest.mark.parametrize("nqp", [NQP, 32, 64, 128, 256])
+@pytest.mark.parametrize("q8", [False, True])
+def test_fine_scan_list_major_compiles(chip, nqp, q8):
+    """The list-major kernel at each chunk a served search reaches: a
+    1-row request padded to the 32-row bucket, the 128-row bucket, and
+    the 256-row chunks of the 512-row bucket (the largest the footprint
+    estimate admits at this window), over a schedule padded to the
+    index's longest with a traced cell count."""
     from raft_tpu.ops.fine_scan_pallas import (fine_scan_list_major,
+                                               fine_scan_list_major_q8,
                                                fine_scan_vmem_footprint)
     from raft_tpu.ops.fused_l2_topk_pallas import vmem_budget
 
-    assert fine_scan_vmem_footprint(WK, nqp, DIM) <= vmem_budget()
+    assert fine_scan_vmem_footprint(WK, nqp, DIM, q8) <= vmem_budget()
     s = functools.partial(_spec, chip)
     rows = N_ROWS + N_LISTS * 8
-    _compile(fine_scan_list_major, s((4, LP), jnp.int32),
-             s((nqp, DIM), jnp.float32), s((nqp, 1), jnp.float32),
-             s((nqp, 128), jnp.int32), s((rows, DIM), jnp.float32), Wk=WK)
+    query = (s((nqp, DIM), jnp.float32), s((nqp, 1), jnp.float32),
+             s((nqp, 128), jnp.int32))
+    sched, n_cells = s((4, SCHED), jnp.int32), s((), jnp.int32)
+    if q8:
+        _compile(fine_scan_list_major_q8, sched, s((SCHED,), jnp.float32),
+                 n_cells, *query, s((rows, DIM), jnp.int8), Wk=WK)
+    else:
+        _compile(fine_scan_list_major, sched, n_cells, *query,
+                 s((rows, DIM), jnp.float32), Wk=WK)
 
 
 def test_pq_scan_list_major_compiles(chip):
@@ -164,7 +180,8 @@ def test_kernel_op_names_are_stable(chip, kernel):
         def some_renamed_entry(*a):
             return fs.fine_scan_list_major.__wrapped__(*a, Wk=256)
 
-        args = (s((4, 16), jnp.int32), s((16, DIM), jnp.float32),
+        args = (s((4, 16), jnp.int32), s((), jnp.int32),
+                s((16, DIM), jnp.float32),
                 s((16, 1), jnp.float32), s((16, 128), jnp.int32),
                 s((4096, DIM), jnp.float32))
     else:
