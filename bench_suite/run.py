@@ -4,7 +4,10 @@
 
 One process, one run:
 
-1. the cell's data, made on the device from ``--seed``;
+1. the cell's data, made from ``--seed`` on the device: the whole base
+   on one device, or, where the configuration's ``data`` says
+   ``"placement": "row_sharded"``, its rows split over the cell's chips,
+   each chip drawing its own (``reference.make_data``);
 2. the system built and warmed by the cell's own traffic (``setup_s``);
 3. the window: the traffic offered for ``--seconds``, the profiler on
    with ``--trace 1``;
@@ -59,6 +62,7 @@ class Run:
     stats_delta: dict
     check_values: dict
     trace: Optional[trace_mod.Summary]
+    chips: int              # devices the cell ran on
 
     def rows_traced(self) -> int:
         """Query rows answered while the trace ran: every request of the
@@ -110,7 +114,7 @@ class Measured:
     """A run up to the close of its window, the system freed."""
     cell: spec.Cell
     seed: int
-    base: object            # the data, on the device
+    base: object            # the data, on the cell's device(s)
     pool: object
     setup_s: float
     window: load.Window
@@ -133,7 +137,7 @@ def measure(cell: spec.Cell, seed: int, seconds: float, traced: bool,
     cfg, traffic = cell.config, cell.traffic
     counter = CompileCounter()
     t0 = time.perf_counter()
-    base, pool = reference.make_data(seed, cfg["data"])
+    base, pool = reference.make_data(seed, cfg["data"], devices)
     jax.block_until_ready((base, pool))
     t1 = time.perf_counter()
     factory = (system_factory
@@ -165,9 +169,12 @@ def measure(cell: spec.Cell, seed: int, seconds: float, traced: bool,
     window = load.drive(system, traffic, load.Plan(traffic, n_pool, seed),
                         seconds, keeper, on_open=open_window, root=cell.root)
     counter.armed = False
+    after = {}
+    t3 = time.perf_counter()
     if traced:
         box["span"].__exit__(None, None, None)
         jax.profiler.stop_trace()
+        after["trace_stop"] = time.perf_counter() - t3
     stats1 = system.stats()
     stats_delta = {k: stats1[k] - stats0.get(k, 0) for k in stats1
                    if isinstance(stats1[k], (int, float))}
@@ -178,10 +185,15 @@ def measure(cell: spec.Cell, seed: int, seconds: float, traced: bool,
     gc.collect()
     summary = None
     if traced:
+        t4 = time.perf_counter()
         summary = trace_mod.reduce(trace_dir)
         shutil.rmtree(trace_dir, ignore_errors=True)
+        after["trace_reduce"] = time.perf_counter() - t4
+    after["after_window"] = time.perf_counter() - t3
     print("bench: set-up by step, s: "
           + ", ".join(f"{k} {v:.3f}" for k, v in split.items()), flush=True)
+    print("bench: after the window, s: "
+          + ", ".join(f"{k} {v:.3f}" for k, v in after.items()), flush=True)
     return Measured(cell=cell, seed=seed, base=base, pool=pool,
                     setup_s=box["setup_s"], window=window,
                     stats_delta=stats_delta, memory_peak_bytes=int(peak),
@@ -193,11 +205,13 @@ def report(m: Measured, devices, peaks: dict):
     build the result object. Returns (result, checked answers, the
     reference's ids for them)."""
     cfg, window = m.cell.config, m.window
+    t0 = time.perf_counter()
     values, limits, correct, ans, ref_ids = check.evaluate(
         cfg, m.base, m.pool, window.requests)
+    check_s = time.perf_counter() - t0
     run = Run(config=cfg, peaks=peaks,
               setup_s=m.setup_s, window=window, stats_delta=m.stats_delta,
-              check_values=values, trace=m.trace)
+              check_values=values, trace=m.trace, chips=len(devices))
     entries = m.cell.per_layer if m.trace is not None else m.cell.end_to_end
     metrics = spec.read_metrics(entries, run, m.cell.root)
     failed = sum(r.error is not None for r in window.requests)
@@ -210,7 +224,8 @@ def report(m: Measured, devices, peaks: dict):
               f"max {1e3 * late[-1]:.3f} ms", flush=True)
     print(f"bench: engine counters over the window: {m.stats_delta}",
           flush=True)
-    print(f"bench: {len(ans.rows)} answered rows checked", flush=True)
+    print(f"bench: {len(ans.rows)} answered rows checked in "
+          f"{check_s:.3f} s", flush=True)
     device = {"platform": devices[0].platform,
               "kind": devices[0].device_kind, "count": len(devices),
               "memory_peak_bytes": m.memory_peak_bytes}
