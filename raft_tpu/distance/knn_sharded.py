@@ -29,6 +29,13 @@ candidates first), so every shard computes the bit-identical merged
 result — the output is truly replicated, and ties break the same way
 on every device.
 
+**Build** (:func:`prepare_knn_index_sharded`): each device prepares its
+own rows inside ``shard_map``. A device array already row-sharded over
+the axis is taken as it is, with no host round trip. Shard ``r`` holds
+input rows ``[r·share, (r+1)·share)`` and pads (on its device) only
+where they fall short of whole certificate groups; the search maps its
+local ids and masks its pads by ``share``.
+
 **Overlapped merge**: queries split into ``micro_batches`` blocks inside
 ONE traced program. Block i's local fused kernel has no data dependence
 on block i−1's merge collectives, so XLA's latency-hiding scheduler is
@@ -63,7 +70,7 @@ from raft_tpu.comms import MeshComms
 from raft_tpu.core.error import (DeviceError, OutOfMemoryError,
                                  device_errors, expects)
 from raft_tpu.core.resources import ensure_resources
-from raft_tpu.observability import instrument
+from raft_tpu.observability import instrument, span
 from raft_tpu.observability.costmodel import (MERGE_STRATEGIES,
                                               choose_merge_strategy)
 from raft_tpu.resilience import (PoisonedOutputError, degrade_merge,
@@ -131,18 +138,24 @@ class ShardedFusedIndex:
     shard padded to whole certificate groups. Build once with
     :func:`prepare_knn_index_sharded`; query with
     :func:`knn_fused_sharded`. The tiling config, metric and mesh are
-    frozen at build time (the per-shard row padding bakes them in)."""
+    frozen at build time (the per-shard row padding bakes them in).
+
+    Shard ``r`` holds ``rows_per`` rows, of which the first
+    ``clip(n_rows - r·share, 0, share)`` are real: global rows
+    ``r·share`` onward. The rest are its own pads, which carry the
+    never-wins sentinel."""
 
     def __init__(self, yp_s, y_hi_s, y_lo_s, yyh_s, yy_s, n_rows: int,
-                 rows_per: int, mesh, axis: str, T: int, Qb: int, g: int,
-                 passes: int, metric: str, d_orig: int, pbits: int,
-                 grid_order: str, db_dtype: str = "bf16",
+                 rows_per: int, share: int, mesh, axis: str, T: int,
+                 Qb: int, g: int, passes: int, metric: str, d_orig: int,
+                 pbits: int, grid_order: str, db_dtype: str = "bf16",
                  y_q_s=None, scale_s=None, eq_s=None):
         self.yp_s = yp_s                  # [p·rows_per, d_eff] or None
         self.y_hi_s, self.y_lo_s = y_hi_s, y_lo_s
         self.yyh_s, self.yy_s = yyh_s, yy_s
         self.n_rows = n_rows              # true (unpadded) global rows
         self.rows_per = rows_per          # rows per shard (padded)
+        self.share = share                # input rows per shard
         self.mesh, self.axis = mesh, axis
         self.T, self.Qb, self.g = T, Qb, g
         self.passes, self.metric = passes, metric
@@ -167,7 +180,50 @@ class ShardedFusedIndex:
     def n_shards(self) -> int:
         return int(self.mesh.shape[self.axis])
 
+    def row_positions(self) -> np.ndarray:
+        """Positions of the real rows in the row-sharded operands, in
+        global id order."""
+        r, j = np.divmod(np.arange(self.n_rows, dtype=np.int64),
+                         self.share)
+        return r * self.rows_per + j
 
+
+def _rows_sharded(y, mesh, axis: str):
+    """``(ys, m)``: ``y`` as an f32 ``[p·share, d]`` array with rows split
+    over ``mesh[axis]`` (shard ``r`` holds rows ``[r·share,
+    (r+1)·share)``), and its real-row count ``m``; the last shards'
+    missing rows are zeros.
+
+    An f32 device array already split so is taken as it is. Any other
+    device array is converted, padded and resharded on the devices; a
+    host input is placed shard by shard."""
+    p = int(mesh.shape[axis])
+    rows = NamedSharding(mesh, P(axis))
+    if not isinstance(y, jax.Array):
+        y = np.asarray(y, np.float32)
+    m, d = y.shape
+    share = -(-m // p)
+    if isinstance(y, jax.Array):
+        ys = y.astype(jnp.float32)
+        if m != p * share:
+            ys = jnp.pad(ys, ((0, p * share - m), (0, 0)))
+        if not ys.sharding.is_equivalent_to(rows, 2):
+            ys = jax.device_put(ys, rows)
+        return ys, m
+
+    def block(index):
+        lo = index[0].start or 0
+        hi = p * share if index[0].stop is None else index[0].stop
+        part = y[lo:min(hi, m)]
+        if part.shape[0] == hi - lo:
+            return part
+        return np.concatenate(
+            [part, np.zeros((hi - lo - part.shape[0], d), np.float32)])
+
+    return jax.make_array_from_callback((p * share, d), rows, block), m
+
+
+@instrument("distance.prepare_knn_index_sharded")
 def prepare_knn_index_sharded(y, mesh=None, axis: str = "x",
                               passes: int = 3, metric: str = "l2",
                               T: Optional[int] = None,
@@ -177,15 +233,21 @@ def prepare_knn_index_sharded(y, mesh=None, axis: str = "x",
                               grid_order: Optional[str] = None,
                               db_dtype: str = "bf16",
                               res=None) -> ShardedFusedIndex:
-    """Build a :class:`ShardedFusedIndex`: rows pad to ``p`` equal
-    shards of whole certificate groups (``g·T`` rows for the
-    database-major orders, ``T`` otherwise) ON HOST, land row-sharded
-    via one ``device_put`` (the full f32 matrix never materializes on
-    one device — the point of the bigger-than-HBM mode), and the
-    index-side operand prep (bf16 hi/lo split, norms, sentinel carrier)
-    runs per shard inside ``shard_map``, with each shard's real-row
-    count threaded as a traced value so global pad rows carry the
-    never-wins sentinel.
+    """Build a :class:`ShardedFusedIndex` over the rows of ``y`` split
+    evenly over ``mesh[axis]``, each shard prepared on its own device.
+
+    ``y`` may be a ``jax.Array`` whose rows are already split over the
+    axis (a ``NamedSharding`` with ``P(axis)``): it is taken as it is,
+    and nothing of it moves to the host or to another device. Any other
+    input is first placed so (:func:`_rows_sharded`). Each shard pads on
+    its device to ``rows_per``, a whole number of certificate groups
+    (``g·T`` rows for the database-major orders, ``T`` otherwise), only
+    where its rows fall short of it; the index-side operand prep (bf16
+    hi/lo split, norms, sentinel carrier) then runs per shard inside
+    ``shard_map``. Each device holds its share of the input and of the
+    index, never the whole of either. The index records the rows per
+    shard of the input (``share``), from which each shard's pad rows get
+    the never-wins sentinel and its local ids map to global ones.
 
     The tiling config resolves against the PER-SHARD shape (pack width
     from the shard's tile count — a 10M-row index split 8 ways packs
@@ -205,9 +267,10 @@ def prepare_knn_index_sharded(y, mesh=None, axis: str = "x",
     if db_dtype not in DB_DTYPES:
         raise ValueError(f"prepare_knn_index_sharded: db_dtype must be "
                          f"one of {DB_DTYPES}, got {db_dtype!r}")
-    y = np.asarray(y, np.float32)
-    m, d = y.shape
+    ys, m = _rows_sharded(y, mesh, axis)
+    d = ys.shape[1]
     p = int(mesh.shape[axis])
+    share = ys.shape[0] // p
     dcfg = fused_config(passes, db_dtype)
     T = dcfg.T if T is None else T
     Qb = dcfg.Qb if Qb is None else Qb
@@ -219,8 +282,7 @@ def prepare_knn_index_sharded(y, mesh=None, axis: str = "x",
         grid_order = "db"      # quantized kernels are database-major
     T, Qb = fit_config(T, Qb, d, passes, g or dcfg.g, grid_order,
                        db_dtype)
-    m_shard = -(-m // p)
-    n_tiles_est = max(1, -(-m_shard // T))
+    n_tiles_est = max(1, -(-share // T))
     if g is None:
         g = max(dcfg.g, (1 << auto_pack_bits(n_tiles_est, T))
                 // (T // _LANES))
@@ -231,58 +293,64 @@ def prepare_knn_index_sharded(y, mesh=None, axis: str = "x",
     db_dtype = resolve_db_dtype(db_dtype, d, packed, grid_order,
                                 store_yp)
     row_mult = g * T if grid_order in ("db", "dbuf") else T
-    rows_per = max(1, -(-m_shard // row_mult)) * row_mult
+    rows_per = max(1, -(-share // row_mult)) * row_mult
     dpad = (-d) % (_DC if d > _D_SINGLE_SHOT else _LANES)
-    d_eff = d + dpad
-    # host-side global pad: [p·rows_per, d_eff]; pads all trail the real
-    # rows, so shard i owns global rows [i·rows_per, (i+1)·rows_per)
-    yg = np.zeros((p * rows_per, d_eff), np.float32)
-    yg[:m, :d] = y
-    ys = jax.device_put(yg, NamedSharding(mesh, P(axis)))
+    row_spec = (P(axis),)
+    padded = rows_per != share or dpad
+    if padded:
+        with span("distance.shard_pad"):
+            ys = jax.jit(jax.shard_map(
+                lambda y_loc: jnp.pad(
+                    y_loc, ((0, rows_per - share), (0, dpad))),
+                mesh=mesh, in_specs=row_spec, out_specs=P(axis)))(ys)
 
+    def _real_rows():
+        # a traced value: one program serves every shard
+        r = jax.lax.axis_index(axis).astype(jnp.int32)
+        return jnp.clip(jnp.int32(m) - r * share, 0, share)
+
+    # a padded copy is the build's own: its buffer may become yp
+    donate = (0,) if padded else ()
     if db_dtype == "int8":
         fault_point("quantize_index")
 
         def _prep_q8(y_loc):
-            r = jax.lax.axis_index(axis)
-            m_loc = jnp.clip(
-                jnp.int32(m) - r.astype(jnp.int32) * rows_per,
-                0, rows_per)
             return _prepare_ops_q8(y_loc, T, g, metric, pbits=pbits,
-                                   grid_order=grid_order, n_valid=m_loc)
+                                   grid_order=grid_order,
+                                   n_valid=_real_rows())
 
         fn = jax.jit(jax.shard_map(
-            _prep_q8, mesh=mesh, in_specs=(P(axis),),
+            _prep_q8, mesh=mesh, in_specs=row_spec,
             out_specs=(P(axis), P(axis), P(axis), P(None, axis),
                        P(None, axis), P(axis)),
-            check_vma=False))
-        yp_s, y_q_s, scale_s, yyh_s, yy_s, eq_s = fn(ys)
+            check_vma=False), donate_argnums=donate)
+        with span("distance.shard_prep"):
+            yp_s, y_q_s, scale_s, yyh_s, yy_s, eq_s = fn(ys)
         return ShardedFusedIndex(yp_s, None, None, yyh_s, yy_s, m,
-                                 rows_per, mesh, axis, T, Qb, g, passes,
-                                 metric, d, pbits, grid_order,
-                                 db_dtype="int8", y_q_s=y_q_s,
-                                 scale_s=scale_s, eq_s=eq_s)
+                                 rows_per, share, mesh,
+                                 axis, T, Qb, g, passes, metric, d, pbits,
+                                 grid_order, db_dtype="int8",
+                                 y_q_s=y_q_s, scale_s=scale_s, eq_s=eq_s)
 
     def _prep(y_loc):
-        r = jax.lax.axis_index(axis)
-        m_loc = jnp.clip(jnp.int32(m) - r.astype(jnp.int32) * rows_per,
-                         0, rows_per)
         return _prepare_ops(y_loc, T, g, metric, pbits=pbits,
-                            grid_order=grid_order, n_valid=m_loc)
+                            grid_order=grid_order, n_valid=_real_rows())
 
     fn = jax.jit(jax.shard_map(
-        _prep, mesh=mesh, in_specs=(P(axis),),
+        _prep, mesh=mesh, in_specs=row_spec,
         out_specs=(P(axis), P(axis), P(axis), P(None, axis),
                    P(None, axis)),
-        check_vma=False))
-    yp_s, y_hi_s, y_lo_s, yyh_s, yy_s = fn(ys)
+        check_vma=False), donate_argnums=donate)
+    with span("distance.shard_prep"):
+        yp_s, y_hi_s, y_lo_s, yyh_s, yy_s = fn(ys)
     if not store_yp:
         yp_s = None
         if passes == 1:
             y_lo_s = None   # the 1-pass kernel and lite fixup never read it
     return ShardedFusedIndex(yp_s, y_hi_s, y_lo_s, yyh_s, yy_s, m,
-                             rows_per, mesh, axis, T, Qb, g, passes,
-                             metric, d, pbits, grid_order)
+                             rows_per, share, mesh,
+                             axis, T, Qb, g, passes, metric, d, pbits,
+                             grid_order)
 
 
 def _merge_allgather(comms: MeshComms, p: int, k: int, v, i):
@@ -482,7 +550,8 @@ def knn_fused_sharded(x, y, k: int, mesh=None, axis: str = "x",
             xq = jnp.concatenate(
                 [x, jnp.zeros((nq_pad - nq, d_eff), jnp.float32)])
         key = ("db", mesh, axis, k, idx.T, Qb_eff, idx.g, idx.passes,
-               idx.metric, idx.rows_per, m, nb, qb_len, merge_eff,
+               idx.metric, idx.rows_per, idx.share, m, nb, qb_len,
+               merge_eff,
                bool(rescore), idx.pbits, certify, pool_algo,
                idx.grid_order, idx.db_dtype, has_yp, has_ylo)
         fn = _SHARDED_FUSED_CACHE.get(key)
@@ -492,6 +561,7 @@ def knn_fused_sharded(x, y, k: int, mesh=None, axis: str = "x",
                         "tournament": _merge_tournament,
                         "host": None}[merge_eff]
             rows_per, T_, g_ = idx.rows_per, idx.T, idx.g
+            share = idx.share
             passes_, metric_, pbits_ = idx.passes, idx.metric, idx.pbits
             order_, dtype_ = idx.grid_order, idx.db_dtype
 
@@ -509,10 +579,8 @@ def knn_fused_sharded(x, y, k: int, mesh=None, axis: str = "x",
                 yyh_l = next(it)
                 yy_l = next(it)
                 r = jax.lax.axis_index(axis)
-                m_loc = jnp.clip(
-                    jnp.int32(m) - r.astype(jnp.int32) * rows_per,
-                    0, rows_per)
-                off = r.astype(jnp.int32) * rows_per
+                off = r.astype(jnp.int32) * share
+                m_loc = jnp.clip(jnp.int32(m) - off, 0, share)
                 out_v, out_i = [], []
                 nf = jnp.zeros((), jnp.int32)
                 # micro-batch pipeline: block b's kernel is independent
@@ -572,7 +640,8 @@ def knn_fused_sharded(x, y, k: int, mesh=None, axis: str = "x",
         else:
             operands = [o for o in (idx.yp_s, idx.y_hi_s, idx.y_lo_s)
                         if o is not None] + [idx.yyh_s, idx.yy_s]
-        vals, ids, nf = fn(*operands, xq)
+        with span("distance.sharded_dispatch"):
+            vals, ids, nf = fn(*operands, xq)
         if merge_eff == "host":
             vals, ids = _merge_host_pool(vals, ids, k)
         if nq_pad != nq:

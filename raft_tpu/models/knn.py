@@ -184,8 +184,9 @@ class NearestNeighbors:
 
         if isinstance(self._index, ShardedFusedIndex):
             # sharded fused fit: the true rows of the row-sharded yp
-            return self._index.yp_s[:self._index.n_rows,
-                                    :self._index.d_orig]
+            idx = self._index
+            return jnp.take(idx.yp_s, jnp.asarray(idx.row_positions()),
+                            axis=0)[:, :idx.d_orig]
         if self.mesh is not None:
             # sharded fit: slice the true rows of the global array
             return self._index.idx_s[:self._index.n]

@@ -434,3 +434,229 @@ def test_check_instrumented_covers_sharded_sites():
     errs = ci.check_sharded_merge(
         sites={"raft_tpu/parallel/mesh.py": ("collective_permute",)})
     assert errs and "collective_permute" in errs[0]
+
+
+# ------------------------------------------------ device-resident build
+def _no_host_copies(mp):
+    """Make the build's device-to-host copies raise. On an accelerator
+    ``jax.transfer_guard_device_to_host("disallow")`` does so; on the CPU,
+    where device memory is host memory and the guard never fires, the
+    sharded module's numpy refuses device arrays and their host views
+    raise instead."""
+    from jax._src import array as jarray
+
+    from raft_tpu.distance import knn_sharded
+
+    def refuse(*_, **__):
+        raise AssertionError("device-to-host copy in the sharded build")
+
+    class _Numpy:
+        def __getattr__(self, name):
+            fn = getattr(np, name)
+            if name not in ("array", "asarray", "ascontiguousarray"):
+                return fn
+
+            def guarded(a, *args, **kwargs):
+                if isinstance(a, jax.Array):
+                    refuse()
+                return fn(a, *args, **kwargs)
+
+            return guarded
+
+    mp.setattr(knn_sharded, "np", _Numpy())
+    mp.setattr(jarray.ArrayImpl, "_value", property(refuse))
+    mp.setattr(jarray.ArrayImpl, "copy_to_host_async", refuse)
+
+
+def test_host_views_refused_inside_the_guard(monkeypatch):
+    """The build tests' guard bites: a build that pulls its input to
+    numpy (as the host round trip did), or reads a host view of it,
+    raises."""
+    from raft_tpu.distance import knn_sharded
+
+    y = jax.device_put(np.ones((8, 4), np.float32))
+    with monkeypatch.context() as mp:
+        _no_host_copies(mp)
+        for host_copy in (lambda: knn_sharded.np.asarray(y, np.float32),
+                          y.tolist, lambda: float(y[0, 0])):
+            with pytest.raises(AssertionError):
+                host_copy()
+        assert knn_sharded.np.asarray([1.0]).sum() == 1.0
+    assert np.asarray(y).sum() == 32
+
+
+# rows per shard: a whole number of the config's row multiple (T=256 at
+# the query-major order), or not, so that each shard pads on its device
+SHARES = {"whole": 512, "padded": 300}
+
+
+@pytest.mark.parametrize("share", sorted(SHARES))
+@pytest.mark.parametrize("p", [2, 4, 8])
+def test_build_from_row_sharded_array(p, share, monkeypatch):
+    """A row-sharded device array builds with no copy to the host, and
+    answers bit for bit as the same rows given as numpy, over both
+    merges, and as the single-device oracle."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    n = p * SHARES[share]
+    r = np.random.default_rng(100 * p + SHARES[share])
+    y = r.normal(size=(n, D)).astype(np.float32)
+    x = r.normal(size=(NQ, D)).astype(np.float32)
+    mesh = _mesh(p)
+    y_dev = jax.device_put(y, NamedSharding(mesh, P("x")))
+    with monkeypatch.context() as mp:
+        _no_host_copies(mp)
+        with jax.transfer_guard_device_to_host("disallow"):
+            idx = prepare_knn_index_sharded(y_dev, mesh=mesh, passes=3,
+                                            **CFG)
+    host = prepare_knn_index_sharded(y, mesh=mesh, passes=3, **CFG)
+    assert idx.rows_per == host.rows_per == 512
+    assert idx.share == host.share == SHARES[share]
+    assert np.array_equal(
+        idx.row_positions(),
+        np.add.outer(512 * np.arange(p), np.arange(SHARES[share])).ravel())
+    ov, oi = knn_fused(x, y, k=K, passes=3, **CFG)
+    for merge in ("allgather", "tournament"):
+        dv, di = knn_fused_sharded(x, idx, K, mesh=mesh, merge=merge)
+        hv, hi = knn_fused_sharded(x, host, K, mesh=mesh, merge=merge)
+        assert np.array_equal(np.asarray(dv), np.asarray(hv))
+        assert np.array_equal(np.asarray(di), np.asarray(hi))
+        assert np.array_equal(np.asarray(dv), np.asarray(ov))
+        assert np.array_equal(np.sort(np.asarray(di), 1),
+                              np.sort(np.asarray(oi), 1))
+
+
+@pytest.mark.parametrize("m", [17, 1001])
+def test_sharded_host_tail_shards(m):
+    """A host input whose rows the shard count does not divide: the last
+    shards are part pads, some (m=17 over 8: shares of 3) none but pads.
+    No pad id leaks and the answer is the float64 top-k."""
+    k, nq = 5, 11
+    r = np.random.default_rng(m)
+    y = r.normal(size=(m, 16)).astype(np.float32)
+    x = r.normal(size=(nq, 16)).astype(np.float32)
+    mesh = _mesh(8)
+    idx = prepare_knn_index_sharded(y, mesh=mesh, passes=3, **CFG)
+    share = -(-m // 8)
+    assert idx.share == share and len(idx.row_positions()) == m
+    assert np.array_equal(
+        np.asarray(idx.yp_s)[idx.row_positions(), :16], y)
+    sv, si = knn_fused_sharded(x, idx, k, mesh=mesh, merge="allgather")
+    d2 = ((x[:, None, :].astype(np.float64)
+           - y[None, :, :].astype(np.float64)) ** 2).sum(-1)
+    ref_ids = np.argsort(d2, axis=1, kind="stable")[:, :k]
+    assert np.array_equal(np.sort(np.asarray(si), 1), np.sort(ref_ids, 1))
+    np.testing.assert_allclose(np.asarray(sv),
+                               np.take_along_axis(d2, ref_ids, axis=1),
+                               rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("m", [17, 1001])
+def test_uneven_device_array_pads_on_its_devices(m, monkeypatch):
+    """A device array whose rows the shard count does not divide is
+    padded and resharded on the devices, with no copy to the host, and
+    builds the index the same rows as numpy build."""
+    k, nq = 5, 11
+    r = np.random.default_rng(m + 1)
+    y = r.normal(size=(m, 16)).astype(np.float32)
+    x = r.normal(size=(nq, 16)).astype(np.float32)
+    mesh = _mesh(8)
+    y_dev = jax.device_put(y, jax.devices()[3])
+    with monkeypatch.context() as mp:
+        _no_host_copies(mp)
+        with jax.transfer_guard_device_to_host("disallow"):
+            idx = prepare_knn_index_sharded(y_dev, mesh=mesh, passes=3,
+                                            **CFG)
+    host = prepare_knn_index_sharded(y, mesh=mesh, passes=3, **CFG)
+    assert (idx.share, idx.rows_per, idx.n_rows) == \
+        (host.share, host.rows_per, host.n_rows)
+    for a, b in ((idx.yp_s, host.yp_s), (idx.yy_s, host.yy_s)):
+        assert a.sharding.is_equivalent_to(b.sharding, a.ndim)
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+    dv, di = knn_fused_sharded(x, idx, k, mesh=mesh, merge="allgather")
+    hv, hi = knn_fused_sharded(x, host, k, mesh=mesh, merge="allgather")
+    assert np.array_equal(np.asarray(dv), np.asarray(hv))
+    assert np.array_equal(np.asarray(di), np.asarray(hi))
+
+
+# ------------------------------------------------ against the benchmark's
+# plain reference, on the benchmark's own row-sharded data
+@pytest.fixture(scope="module")
+def bench_reference():
+    """``bench_suite.reference`` (it imports nothing of raft_tpu) with
+    row blocks of 256 rows, and the bigann32m-exact4 configuration."""
+    import os
+    import sys
+
+    root = os.path.join(os.path.dirname(__file__), "..")
+    sys.path.insert(0, root)
+    try:
+        from bench_suite import reference
+    finally:
+        sys.path.remove(root)
+    with open(os.path.join(root, "bench_suite", "configs",
+                           "bigann32m-exact4.json")) as f:
+        cfg = json.load(f)
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(reference, "ROW_BLOCK", 256)
+        yield reference, cfg
+
+
+def _compare(reference, q, base, dist, ids, k):
+    """(dist_err, rank_gap) of answers as the benchmark's check reads
+    them: each reported distance against its id's true distance, and the
+    served ids' sorted true distances against the reference's, both
+    relative to the row's exact k-th distance."""
+    _, ref_ids = reference.exact_topk(q, base, k)
+    t_srv = reference.true_distances(q, base, ids)
+    t_ref = reference.true_distances(q, base, ref_ids)
+    scale = t_ref[:, -1:]
+    dist_err = float(np.max(np.abs(np.asarray(dist, np.float64) - t_srv)
+                            / scale))
+    rank_gap = float(np.max((np.sort(t_srv, 1) - np.sort(t_ref, 1))
+                            / scale))
+    return dist_err, max(rank_gap, 0.0), ref_ids
+
+
+def test_sharded_search_against_the_plain_reference(bench_reference):
+    """``knn_fused_sharded`` at the configuration's k over the
+    benchmark's row-sharded data on four devices, against
+    ``bench_suite.reference`` at f32 ``highest``.
+
+    Tolerances, each the configuration's own limit:
+    - ``dist_err`` ≤ 1.1e-5: the program's distances are f32 in the
+      expanded form ‖q‖² + ‖x‖² − 2q·x, whose rounding is a few f32 ulps
+      of ‖q‖² + ‖x‖², about 1e-6 of the k-th distance here; a one-pass
+      bf16 cross term errs by ≈ 2⁻⁹ of q·x, thousands of times more;
+    - ``rank_gap`` ≤ 3e-6: ids may differ from the reference's only where
+      two true distances at the k-th place lie closer than the expanded
+      form's rounding of them (the ``dist_err`` above).
+    """
+    reference, cfg = bench_reference
+    data = dict(cfg["data"], n_rows=4096, n_pool=96, n_centers=32)
+    k = int(cfg["k"])
+    limits = {n: v["max"] for n, v in cfg["check"]["limits"].items()}
+    base, pool = reference.make_data(2 ** 31 + 5, data, jax.devices()[:4])
+    mesh, axis = base.sharding.mesh, base.sharding.spec[0]
+    idx = prepare_knn_index_sharded(base, mesh=mesh, axis=axis)
+    assert idx.share == 1024
+    q = np.asarray(pool)
+    dist, ids = knn_fused_sharded(q, idx, k, mesh=mesh, axis=axis)
+    ids = np.asarray(ids)
+    dist_err, rank_gap, ref_ids = _compare(reference, q, base, dist, ids, k)
+    assert dist_err <= limits["dist_err"]
+    assert rank_gap <= limits["rank_gap"]
+    # ids equal except exact ties at the k-th place: a row whose sets
+    # differ has its (k)th and (k+1)th true distances equal in f32
+    differ = [r for r in range(len(q))
+              if set(ids[r]) != set(ref_ids[r])]
+    if differ:
+        d_next, _ = reference.exact_topk(q[differ], base, k + 1)
+        assert np.all(np.isclose(d_next[:, k], d_next[:, k - 1],
+                                 rtol=1e-6, atol=0))
+    # a one-pass bf16 cross term, below the f32 the configuration
+    # states, fails the comparison
+    bd, bi = reference.exact_topk(q, base, k, precision="bf16")
+    b_err, b_gap, _ = _compare(reference, q, base, bd, bi, k)
+    assert b_err > limits["dist_err"] or b_gap > limits["rank_gap"]
+    assert b_err > 100 * dist_err

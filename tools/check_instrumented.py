@@ -65,7 +65,8 @@ HOT_PATHS: Dict[str, Sequence[str]] = {
     "raft_tpu/tune/sharded.py": ("autotune_sharded",),
     "raft_tpu/tune/ivf.py": ("autotune_fine_scan",
                              "autotune_pq_scan"),
-    "raft_tpu/distance/knn_sharded.py": ("knn_fused_sharded",),
+    "raft_tpu/distance/knn_sharded.py": ("knn_fused_sharded",
+                                         "prepare_knn_index_sharded"),
     "raft_tpu/serving/engine.py": ("execute_batch",),
     "raft_tpu/serving/snapshot.py": ("build_snapshot",),
     "raft_tpu/cluster/kmeans.py": ("kmeans_fit", "kmeans_predict"),
