@@ -991,7 +991,7 @@ def _exact_search(res, index: IvfFlatIndex, x, k: int):
     if qpad:
         x = jnp.concatenate(
             [x, jnp.zeros((qpad, x.shape[1]), jnp.float32)])
-    vals, pos, n_fail, margin = _knn_fused_core(
+    vals, pos, n_fail, margin, n_over = _knn_fused_core(
         x, yp, y_hi, y_lo, yyh_k, yy_raw, k=k, T=T, Qb=Qb_eff, g=g,
         passes=3, metric="l2", m=M, rescore=True, pbits=pbits,
         with_stats=True, rows_valid=rv)
@@ -1002,7 +1002,7 @@ def _exact_search(res, index: IvfFlatIndex, x, k: int):
 
     record_pending("ann.ivf_exact", n_fail, n_queries=x.shape[0],
                    pool_width=rescore_pool_width(k, S_pool, True),
-                   fix_tiers=fixup_tiers_for(M))
+                   fix_tiers=fixup_tiers_for(M), n_over=n_over)
     if explain.active() is not None:
         explain.note_margin("ann.ivf_exact",
                             margin[:nq] if qpad else margin)

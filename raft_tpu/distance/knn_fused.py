@@ -37,7 +37,10 @@ Pipeline (all one jit program):
    hundreds) are re-solved exactly and scattered back: tiered static
    batches (16/128/512/1024, each eligible only while its [F, M] tile
    fits the fixup budget) that materialize an [F, M] distance tile
-   and take one top_k; a full streamed fallback covers pathological
+   and select over it once — through a threshold block filter where
+   the tile is long (``fixup_select``: the rows' own candidates bound
+   their k-th distance, so only the few blocks that can hold an answer
+   pass through a TopK); a full streamed fallback covers pathological
    batches (cond) and the empty-ladder regime (M too large for any
    tile).
 
@@ -62,6 +65,7 @@ precision expanded scores (validated in tests against an f64 oracle).
 from __future__ import annotations
 
 import functools
+import math
 import os
 from typing import Optional, Tuple
 
@@ -130,6 +134,10 @@ _FIXUP_TIERS = (16, 128, 512, 1024)
 # 4.2 GB keeps the 1024 tier at the 1M driver shape (2·4.1 GB + ~2 GB
 # of index operands < 16 GB v5e HBM) and sheds it past ~1.05M rows
 _FIXUP_TILE_BUDGET = 4_200_000_000
+# columns per block of the fixup's threshold filter (fixup_select), and
+# the most blocks its exact fallback (blocked_top_k) merges at a time
+_FIXUP_BLOCK = 512
+_FIXUP_GROUP = 256
 # pool oversampling beyond k before exact rescoring
 _POOL_PAD = 32
 # query-chunk bound: the [Q, S] slot arrays + [Q, C, d] rescore gather are
@@ -260,6 +268,94 @@ def _pool_smallest(a, c: int, algo: str = "xla"):
     return -neg, pos
 
 
+def fixup_filter_geometry(M: int, k: int) -> Optional[Tuple[int, int]]:
+    """``(B, nb)`` of :func:`fixup_select` over an ``[F, M]`` tile: the
+    block width and the number of blocks kept (the power of two ≥ 2k),
+    or None where the filter cannot shrink the top_k (M ≤ nb·B), B does
+    not divide M or k > B — the flat top_k then stays."""
+    nb = 1 << max(0, 2 * k - 1).bit_length()
+    B = _FIXUP_BLOCK
+    if M % B or M <= nb * B or k > B:
+        return None
+    return B, nb
+
+
+def fixup_select(d2b, cand, k: int, nb: int):
+    """``(vals, ids, exact)``: the k smallest of each row of a masked
+    ``[F, M]`` distance tile, ascending, given as its blocked view
+    ``d2b [M/B, F, B]`` (``d2b[j, f, b]`` = column ``j·B + b`` of row
+    f). Where ``exact`` (a bool scalar) holds they are bit for bit
+    ``top_k(-d2, k)`` of the flat tile, ties to the lowest column.
+
+    XLA's TPU TopK costs time in proportion to its element count
+    (``SELECT_K_MATRIX.json``), and the fixup knows almost the whole
+    answer before it selects: ``cand [F, k]`` are each row's current
+    candidate columns (−1 for none), so ``t = max d2[f, cand[f]]`` bounds
+    the row's k-th value whenever they are k distinct real columns. The
+    block minima (a reduction the tile's producer fuses) mark the blocks
+    holding a value ≤ t; the first nb of them, in ascending block order,
+    are gathered and only those ``nb·B`` columns go through a TopK.
+    Every column left out is > t, so where each row kept all its marked
+    blocks and its k-th kept value is ≤ t the result IS the flat top_k.
+    A row that fails that (more than nb marked blocks; fewer than k
+    distinct real candidates, so t = +inf; a NaN) makes ``exact`` false,
+    and :func:`blocked_top_k` then selects over the whole tile.
+
+    Blocks lead the view so that the tile's producer writes it, and the
+    gathers read it, in one TPU layout: ``[F, M/B, B]`` costs a
+    relayout copy of the whole tile."""
+    nblk, F, B = d2b.shape
+    rows = jnp.arange(F, dtype=jnp.int32)[:, None]
+    c = jnp.clip(cand, 0, nblk * B - 1)
+    at = d2b[c // B, rows, c % B]                                  # [F, k]
+    t = jnp.max(jnp.where(cand >= 0, at, jnp.inf), axis=1)          # [F]
+    hit = jnp.min(d2b, axis=2).T <= t[:, None]                     # [F, nblk]
+    n_hit = jnp.sum(hit, axis=1, dtype=jnp.int32)
+    # marked blocks first, each group in ascending block order; the
+    # keys are distinct, so no tie is left to the TopK
+    bid = jnp.arange(nblk, dtype=jnp.int32)
+    _, sel = jax.lax.top_k(
+        jnp.where(hit, nblk - bid, -bid).astype(jnp.float32), nb)
+    sub = d2b[sel, rows].reshape(F, nb * B)                        # [F, nb·B]
+    cols = (sel[:, :, None] * B
+            + jnp.arange(B, dtype=jnp.int32)).reshape(F, nb * B)
+    nt, pos = jax.lax.top_k(-sub, k)
+    vals = -nt
+    ids = jnp.take_along_axis(cols, pos, axis=1)
+    # NaN-safe: a comparison with NaN fails
+    exact = jnp.all((n_hit <= nb) & (vals[:, k - 1] <= t))
+    return vals, ids, exact
+
+
+def blocked_top_k(d2b, k: int):
+    """``top_k(-d2, k)`` of the flat tile, negated back, from its
+    blocked view ``d2b [M/B, F, B]`` (:func:`fixup_select`, B ≥ k)
+    without relaying the whole tile out: a running top k merged with
+    one group of blocks at a time, in column order, so ties go to the
+    lowest column as the flat top_k breaks them. The group (≤
+    ``_FIXUP_GROUP`` blocks) bounds the relayout to one group's
+    columns."""
+    nblk, F, B = d2b.shape
+    G = math.gcd(nblk, _FIXUP_GROUP)
+
+    def group(j):
+        g = jax.lax.dynamic_slice_in_dim(d2b, j * G, G, axis=0)
+        cols = j * G * B + jnp.arange(G * B, dtype=jnp.int32)
+        return (jnp.transpose(g, (1, 0, 2)).reshape(F, G * B),
+                jnp.broadcast_to(cols, (F, G * B)))
+
+    def merge(v, c):
+        nt, pos = jax.lax.top_k(-v, k)
+        return -nt, jnp.take_along_axis(c, pos, axis=1)
+
+    def body(j, carry):
+        v, c = group(j)
+        return merge(jnp.concatenate([carry[0], v], axis=1),
+                     jnp.concatenate([carry[1], c], axis=1))
+
+    return jax.lax.fori_loop(1, nblk // G, body, merge(*group(0)))
+
+
 def decode_packed_pool(cand_p, pos, S_: int, T: int, g: int,
                        pbits: int = _PACK_BITS):
     """Candidate columns from (packed value, pool position) — THE
@@ -284,8 +380,6 @@ def auto_pack_bits(n_tiles: int, T: int) -> int:
     definition — prepare_knn_index and the north-star benchmark both
     call it, so the measured configuration cannot drift from
     production's."""
-    import math
-
     return min(_PBITS_MAX, max(_PACK_BITS, int(math.floor(
         math.log2(max(n_tiles * T / 2560.0, 256.0))))))
 
@@ -414,8 +508,6 @@ def q8_eq_bound(scales, d: int):
     the twin-pool certificate is widened by (see _knn_fused_core), and
     the bound the property test attacks with adversarial
     scale-boundary values."""
-    import math
-
     return scales * (_Q8_ERR * math.sqrt(max(d, 1)))
 
 
@@ -838,8 +930,10 @@ def _knn_fused_core(x, yp, y_hi, y_lo, yyh_k, yy_raw,
     fix_tiers = tuple(t for t in _FIXUP_TIERS
                       if t * M * 4 <= _FIXUP_TILE_BUDGET)
 
-    def exact_rows(xq):
-        """Exact top-k for a [F, d] query block.
+    def exact_rows(xq, cq):
+        """Exact top-k for a [F, d] query block whose current candidate
+        ids are ``cq`` [F, k], and whether its select overflowed
+        (:func:`fixup_select`).
 
         rescore mode: f32 HIGHEST against the stored yp — exact w.r.t.
         f32 scores. Lite mode (yp is None): the SAME bf16(x3)
@@ -847,8 +941,9 @@ def _knn_fused_core(x, yp, y_hi, y_lo, yyh_k, yy_raw,
         the kernel score function, which is what lite results are
         certified against.
 
-        Small blocks materialize the whole [F, M] distance tile and take
-        ONE top_k: MEASURED (v5e, 2048×1M×128) the old per-tile
+        Small blocks materialize the whole [F, M] distance tile and
+        select over it once, through the threshold block filter that
+        ``cq`` bounds: MEASURED (v5e, 2048×1M×128) the old per-tile
         merge loop (489 sequential top_k's on [F, k+T]) cost ~90 ms —
         3× the entire rest of the pipeline — and ran on nearly every
         batch because the certificate fires for a handful of queries at
@@ -861,50 +956,74 @@ def _knn_fused_core(x, yp, y_hi, y_lo, yyh_k, yy_raw,
         nt_dims = (((1,), (1,)), ((), ()))
 
         def scores(yt_f32, yt_hi, yt_lo, yy_seg):
+            # rows [n, d] give [F, n]; rows blocked [n/B, B, d] give the
+            # blocked view [n/B, F, B] (fixup_select)
+            blocked = (yt_hi if yp is None else yt_f32).ndim == 3
+
+            def dot(a, b, **kw):
+                if blocked:
+                    return jnp.einsum("fd,jbd->jfb", a, b, **kw)
+                return jax.lax.dot_general(a, b, nt_dims, **kw)
+
             if yp is not None:
-                s = jax.lax.dot_general(
-                    xq, yt_f32, nt_dims,
-                    precision=jax.lax.Precision.HIGHEST,
-                    preferred_element_type=jnp.float32)
+                s = dot(xq, yt_f32, precision=jax.lax.Precision.HIGHEST,
+                        preferred_element_type=jnp.float32)
             else:
                 xhi = xq.astype(jnp.bfloat16)
-                s = jax.lax.dot_general(
-                    xhi, yt_hi, nt_dims,
-                    preferred_element_type=jnp.float32)
+                s = dot(xhi, yt_hi, preferred_element_type=jnp.float32)
                 if passes == 3:
                     # barrier: XLA:TPU's bf16 pass folds the split
                     # (see split_hi_lo) — lo would collapse to ~0
                     xhi_b = jax.lax.optimization_barrier(xhi)
                     xlo = (xq - xhi_b.astype(jnp.float32)
                            ).astype(jnp.bfloat16)
-                    s = s + jax.lax.dot_general(
-                        xhi, yt_lo, nt_dims,
-                        preferred_element_type=jnp.float32)
-                    s = s + jax.lax.dot_general(
-                        xlo, yt_hi, nt_dims,
-                        preferred_element_type=jnp.float32)
+                    s = s + dot(xhi, yt_lo,
+                                preferred_element_type=jnp.float32)
+                    s = s + dot(xlo, yt_hi,
+                                preferred_element_type=jnp.float32)
             if metric == "ip":
                 # lite operands are the hi/lo split of y/2 (the kernel
                 # feeds them to the same scorer) — recover -x·y with
                 # the ×2 the packed pipeline applies; the stored-yp
                 # path contracts the full-scale y
                 return -s if yp is not None else -2.0 * s
+            if blocked:
+                return jnp.maximum(
+                    xs[None, :, None] + yy_seg[:, None, :] - 2.0 * s, 0.0)
             return jnp.maximum(
                 xs[:, None] + yy_seg[None, :] - 2.0 * s, 0.0)
 
         if fix_tiers and F <= fix_tiers[-1]:
-            yy_all = (yy_raw[0] if yp is None
-                      else jnp.sum(yp * yp, axis=1))
-            d2 = scores(yp, y_hi, y_lo, yy_all)                 # [F, M]
-            col = jnp.arange(M, dtype=jnp.int32)
-            col_ok = (rows_valid[None, :] if rows_valid is not None
-                      else col[None, :] < m_eff)
-            d2 = jnp.where(col_ok, d2, jnp.inf)
-            # (A/B MEASURED: routing this top_k through the slotted
-            # select — 2.5 vs 3.0 ms standalone at [16, 1M] — showed
-            # no e2e win in-composite; the plain top_k stays)
-            nt, ni = jax.lax.top_k(-d2, k)
-            return -nt, ni
+            # the index's stored norms are the f32 rows' own, except
+            # int8's, which are the dequantized rows' (_prepare_ops_q8)
+            yy_all = (jnp.sum(yp * yp, axis=1) if quant
+                      else yy_raw[0])
+            col_ok = (rows_valid if rows_valid is not None
+                      else jnp.arange(M, dtype=jnp.int32) < m_eff)
+
+            def tile(B=None):
+                """The masked [F, M] tile, or with a block width B its
+                blocked view [M/B, F, B] (fixup_select)."""
+                def rows(a):
+                    return (a if a is None or B is None
+                            else a.reshape(M // B, B, *a.shape[1:]))
+
+                d2 = scores(rows(yp), rows(y_hi), rows(y_lo),
+                            rows(yy_all))
+                ok = rows(col_ok)
+                return jnp.where(ok[None, :] if B is None
+                                 else ok[:, None, :], d2, jnp.inf)
+
+            geometry = fixup_filter_geometry(M, k)
+            if geometry is None:
+                nt, ni = jax.lax.top_k(-tile(), k)
+                return -nt, ni, jnp.int32(0)
+            B, nb = geometry
+            d2b = tile(B)
+            sv, si, exact = fixup_select(d2b, cq, k, nb)
+            fv, fi = jax.lax.cond(exact, lambda _: (sv, si),
+                                  lambda d: blocked_top_k(d, k), d2b)
+            return fv, fi, (~exact).astype(jnp.int32)
 
         # full-batch fallback: streamed per-tile merge (the [Q, M] tile
         # would not fit HBM); rare — needs > fix_tiers[-1] failures
@@ -936,24 +1055,25 @@ def _knn_fused_core(x, yp, y_hi, y_lo, yyh_k, yy_raw,
 
         bv = jnp.full((F, k), jnp.inf, jnp.float32)
         bi = jnp.full((F, k), -1, jnp.int32)
-        return jax.lax.fori_loop(0, n_tiles, body, (bv, bi))
+        return (*jax.lax.fori_loop(0, n_tiles, body, (bv, bi)),
+                jnp.int32(0))
 
     def no_fixup(operand):
         vals, ids = operand
-        return vals, ids
+        return vals, ids, jnp.int32(0)
 
     def make_fixup(F):
         def fixup(operand):
             vals, ids = operand
             _, fidx = jax.lax.top_k(failed.astype(jnp.int32), F)
-            fv, fi = exact_rows(x[fidx])
+            fv, fi, over = exact_rows(x[fidx], ids[fidx])
             # padded rows of fidx are healthy queries — recomputing them
             # exactly and writing back is harmless (same answer)
-            return vals.at[fidx].set(fv), ids.at[fidx].set(fi)
+            return vals.at[fidx].set(fv), ids.at[fidx].set(fi), over
         return fixup
 
     def full_fallback(operand):
-        return exact_rows(x)
+        return exact_rows(x, operand[1])
 
     if _diag:
         # measurement-only: the certified pipeline WITHOUT the fixup
@@ -969,15 +1089,19 @@ def _knn_fused_core(x, yp, y_hi, y_lo, yyh_k, yy_raw,
     for t in [t for t in reversed(fix_tiers) if t < Q]:
         branch = (lambda op, t=t, nxt=branch: jax.lax.cond(
             n_fail <= t, make_fixup(t), nxt, op))
-    vals, ids = jax.lax.cond(n_fail == 0, no_fixup, branch, (vals, ids))
+    vals, ids, n_over = jax.lax.cond(n_fail == 0, no_fixup, branch,
+                                     (vals, ids))
     if with_stats:
         # ``with_stats``: the certificate-failure count rides out as a
         # third (scalar) output so the NON-jitted wrappers can report
-        # fixup-rate telemetry host-side (observability.quality), plus
-        # the PRE-FIXUP per-query margin as a fourth so the explain
-        # plane can histogram it — one int32 + one [Q] f32 per program,
-        # no extra compute, fixup semantics untouched
-        return vals, ids, n_fail, margin
+        # fixup-rate telemetry host-side (observability.quality), the
+        # PRE-FIXUP per-query margin as a fourth so the explain plane
+        # can histogram it, and as a fifth 1 where the fixup's select
+        # overflowed its block filter into the whole-tile select (the
+        # count raft_tpu_cert_fixup_overflow_total sums) — two int32
+        # scalars + one [Q] f32 per program, no extra compute, fixup
+        # semantics untouched
+        return vals, ids, n_fail, margin, n_over
     return vals, ids
 
 
@@ -1485,8 +1609,6 @@ def prepare_knn_index(y, passes: int = 3, metric: str = "l2",
     # certificate budgets for — such a g simply routes to the UNPACKED
     # kernel (g·n_ch > 2^pbits ⇒ packed=False, +inf sentinels), the
     # same fallback the core and _prepare_ops agree on
-    import math
-
     pbits = min(_PBITS_MAX, max(_PACK_BITS, int(math.ceil(math.log2(
         max(g * (T // _LANES), 2))))))
     if rows_valid is not None and g * (T // _LANES) > (1 << pbits):
@@ -1722,7 +1844,7 @@ def knn_fused(x, y, k: int, passes: int = 3,
     pool_len = S_pool if packed_env else 2 * S_pool
     pool_algo = resolve_pool_algo(pool_select_algo(), pool_len,
                                   min(k + _POOL_PAD, pool_len))
-    vals, ids, n_fail, margin = _knn_fused_core(
+    vals, ids, n_fail, margin, n_over = _knn_fused_core(
         x, idx.yp, idx.y_hi, idx.y_lo, idx.yyh_k, idx.yy_raw,
         k=k, T=T, Qb=Qb, g=g, passes=passes, metric=metric, m=m,
         rescore=rescore, pbits=idx.pbits, certify=certify,
@@ -1744,7 +1866,8 @@ def knn_fused(x, y, k: int, passes: int = 3,
             "distance.knn_fused", n_fail, n_queries=x.shape[0],
             pool_width=rescore_pool_width(k, S_pool, packed_env),
             fix_tiers=fixup_tiers_for(idx.yyh_k.shape[1]),
-            db_dtype=db_dtype, passes=passes, certify=certify)
+            n_over=n_over, db_dtype=db_dtype, passes=passes,
+            certify=certify)
         if explain.active() is not None:
             # pad rows carry vacuous margins — slice them off (the
             # slice dispatch only happens under an active capture)

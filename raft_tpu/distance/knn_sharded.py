@@ -582,7 +582,7 @@ def knn_fused_sharded(x, y, k: int, mesh=None, axis: str = "x",
                 off = r.astype(jnp.int32) * share
                 m_loc = jnp.clip(jnp.int32(m) - off, 0, share)
                 out_v, out_i = [], []
-                nf = jnp.zeros((), jnp.int32)
+                nf = no = jnp.zeros((), jnp.int32)
                 # micro-batch pipeline: block b's kernel is independent
                 # of block b−1's merge collectives — the scheduler may
                 # overlap
@@ -590,10 +590,10 @@ def knn_fused_sharded(x, y, k: int, mesh=None, axis: str = "x",
                     xb = jax.lax.slice_in_dim(xq_l, b * qb_len,
                                               (b + 1) * qb_len, axis=0)
                     # margin (4th with_stats output) is DCE'd here: the
-                    # sharded out_specs stay (vals, ids, n_fail) —
-                    # per-shard margins would need a gather the explain
-                    # plane doesn't ask for
-                    vals, ids, nfb, _ = _knn_fused_core(
+                    # sharded out_specs stay (vals, ids, n_fail, n_over)
+                    # — per-shard margins would need a gather the
+                    # explain plane doesn't ask for
+                    vals, ids, nfb, _, nob = _knn_fused_core(
                         xb, yp_l, yhi_l, ylo_l, yyh_l, yy_l,
                         k=k, T=T_, Qb=Qb_eff, g=g_, passes=passes_,
                         metric=metric_, m=rows_per, rescore=rescore,
@@ -601,7 +601,7 @@ def knn_fused_sharded(x, y, k: int, mesh=None, axis: str = "x",
                         pool_algo=pool_algo, grid_order=order_,
                         db_dtype=dtype_, with_stats=True, y_q=yq_l,
                         y_scale_k=scl_l, eq_groups=eq_l, m_valid=m_loc)
-                    nf = nf + nfb
+                    nf, no = nf + nfb, no + nob
                     # local → global ids; pad/sentinel candidates (id -1
                     # or non-finite value) must lose every merge
                     gid = jnp.where((ids >= 0) & jnp.isfinite(vals),
@@ -613,11 +613,13 @@ def knn_fused_sharded(x, y, k: int, mesh=None, axis: str = "x",
                     out_i.append(gid)
                 cat_v = jnp.concatenate(out_v, axis=0)
                 cat_i = jnp.concatenate(out_i, axis=0)
-                # per-shard certificate-failure count: rank-major [p]
-                # on the host side of the shard_map (quality telemetry)
+                # per-shard certificate-failure and select-overflow
+                # counts: rank-major [p] on the host side of the
+                # shard_map (quality telemetry)
                 if merge_fn is None:   # host merge: per-shard locals out
-                    return cat_v[None], cat_i[None], nf.reshape(1)
-                return cat_v, cat_i, nf.reshape(1)
+                    return (cat_v[None], cat_i[None], nf.reshape(1),
+                            no.reshape(1))
+                return cat_v, cat_i, nf.reshape(1), no.reshape(1)
 
             if quant:
                 # yp + (y_q, scale, eq) — all row/group-sharded
@@ -626,9 +628,9 @@ def knn_fused_sharded(x, y, k: int, mesh=None, axis: str = "x",
                 row_specs = [P(axis)] * (1 + int(has_yp) + int(has_ylo))
             in_specs = tuple(row_specs
                              + [P(None, axis), P(None, axis), P()])
-            out_specs = ((P(axis), P(axis), P(axis))
+            out_specs = ((P(axis), P(axis), P(axis), P(axis))
                          if merge_eff == "host"
-                         else (P(), P(), P(axis)))
+                         else (P(), P(), P(axis), P(axis)))
             fn = jax.jit(jax.shard_map(
                 shard_fn, mesh=mesh, in_specs=in_specs,
                 out_specs=out_specs, check_vma=False))
@@ -641,12 +643,12 @@ def knn_fused_sharded(x, y, k: int, mesh=None, axis: str = "x",
             operands = [o for o in (idx.yp_s, idx.y_hi_s, idx.y_lo_s)
                         if o is not None] + [idx.yyh_s, idx.yy_s]
         with span("distance.sharded_dispatch"):
-            vals, ids, nf = fn(*operands, xq)
+            vals, ids, nf, no = fn(*operands, xq)
         if merge_eff == "host":
             vals, ids = _merge_host_pool(vals, ids, k)
         if nq_pad != nq:
             vals, ids = vals[:nq], ids[:nq]
-        return vals, ids, nf
+        return vals, ids, nf, no
 
     # ---- resilience driver ------------------------------------------
     # The fast path is one trip through the loop body with zero extra
@@ -671,8 +673,8 @@ def knn_fused_sharded(x, y, k: int, mesh=None, axis: str = "x",
             elif merge_eff == "allgather":
                 fault_point("merge_allgather")
             with device_errors(site):
-                vals, ids, nf_shards = _dispatch(merge_eff, nb_cur,
-                                                 Qb_base)
+                vals, ids, nf_shards, no_shards = _dispatch(
+                    merge_eff, nb_cur, Qb_base)
             if poison == "nan":   # simulated kernel-output poisoning
                 vals = jnp.full_like(vals, jnp.nan)
             if validate and not bool(jnp.isfinite(vals).all()):
@@ -725,7 +727,7 @@ def knn_fused_sharded(x, y, k: int, mesh=None, axis: str = "x",
             site, nf_shards, n_queries=p * _geometry(nb_cur, Qb_base)[3],
             pool_width=rescore_pool_width(
                 k, -(-n_tiles_loc // idx.g) * _LANES, packed),
-            fix_tiers=fixup_tiers_for(idx.rows_per),
+            fix_tiers=fixup_tiers_for(idx.rows_per), n_over=no_shards,
             db_dtype=idx.db_dtype, merge=merge_eff, shards=p)
     except Exception:
         pass
@@ -817,21 +819,22 @@ def _knn_query_sharded(x, y, k, mesh, axis, passes, metric, T, Qb, g,
                 ylo_l = next(it) if has_ylo else None
             yyh_l = next(it)
             yy_l = next(it)
-            v, i, nf, _ = _knn_fused_core(
+            v, i, nf, _, no = _knn_fused_core(
                 xq, yp_l, yhi_l, ylo_l, yyh_l, yy_l,
                 k=k, T=T_, Qb=Qb_eff, g=g_, passes=passes_,
                 metric=metric_, m=m, rescore=rescore, pbits=pbits_,
                 certify=certify, pool_algo=pool_algo, grid_order=order_,
                 db_dtype=dtype_, with_stats=True, y_q=yq_l,
                 y_scale_k=scl_l, eq_groups=eq_l)
-            return v, i, nf.reshape(1)
+            return v, i, nf.reshape(1), no.reshape(1)
 
         n_repl = (1 + 3 if quant
                   else 1 + int(has_yp) + int(has_ylo)) + 2
         in_specs = tuple([P()] * n_repl + [P(axis)])
         fn = jax.jit(jax.shard_map(
             shard_fn, mesh=mesh, in_specs=in_specs,
-            out_specs=(P(axis), P(axis), P(axis)), check_vma=False))
+            out_specs=(P(axis), P(axis), P(axis), P(axis)),
+            check_vma=False))
         _SHARDED_FUSED_CACHE[key] = fn
 
     from raft_tpu.parallel import replicated
@@ -845,13 +848,14 @@ def _knn_query_sharded(x, y, k, mesh, axis, passes, metric, T, Qb, g,
     operands += [jax.device_put(idx.yyh_k, replicated(mesh)),
                  jax.device_put(idx.yy_raw, replicated(mesh))]
     xs = jax.device_put(x, NamedSharding(mesh, P(axis)))
-    vals, ids, nf_shards = fn(*operands, xs)
+    vals, ids, nf_shards, no_shards = fn(*operands, xs)
     try:
         record_pending(
             "distance.knn_fused_sharded", nf_shards, n_queries=nq_pad,
             pool_width=rescore_pool_width(k, S_pool, packed),
             fix_tiers=fixup_tiers_for(idx.yyh_k.shape[1]),
-            db_dtype=idx.db_dtype, merge="query_sharded", shards=p)
+            n_over=no_shards, db_dtype=idx.db_dtype,
+            merge="query_sharded", shards=p)
     except Exception:
         pass
     if nq_pad != nq:

@@ -378,17 +378,18 @@ def run_fused_ops(fops: FusedOps, x, k: int, rows_valid=None,
                   metric=fops.metric, m=M, rescore=True,
                   pbits=fops.pbits, with_stats=True, rows_valid=rv,
                   grid_order=fops.grid_order)
-    # margin (4th with_stats output) is discarded inside this jitted
-    # view — the mutable plane's explain story rides the base-search
-    # sites; XLA DCEs the unused output
+    # margin and the select-overflow flag (4th and 5th with_stats
+    # outputs) are discarded inside this jitted view — the mutable
+    # plane's explain and fixup stories ride the base-search sites;
+    # XLA DCEs the unused outputs
     if fops.db_dtype == "int8":
         yp, y_q, scale_k, yyh, yy_raw, eq = ops
-        vals, pos, n_fail, _ = _knn_fused_core(
+        vals, pos, n_fail, _, _ = _knn_fused_core(
             x, yp, None, None, yyh, yy_raw, db_dtype="int8", y_q=y_q,
             y_scale_k=scale_k, eq_groups=eq, **common)
     else:
         yp, y_hi, y_lo, yyh, yy_raw = ops
-        vals, pos, n_fail, _ = _knn_fused_core(
+        vals, pos, n_fail, _, _ = _knn_fused_core(
             x, yp, y_hi, y_lo, yyh, yy_raw, **common)
     vals, pos = vals[:nq], pos[:nq]
     # rows short of k come back (+inf, <raw column>) from the fixup's

@@ -62,6 +62,9 @@ CERT_FIXUPS = "raft_tpu_certificate_fixups_total"
 #: fixup batch sizes — the static tier (16/128/512/1024 or the full
 #: fallback) that absorbed each nonzero failure batch
 FIXUP_ROWS = "raft_tpu_certificate_fixup_rows"
+#: fixups whose select overflowed the threshold block filter into the
+#: exact select over the whole [F, M] tile (knn_fused.fixup_select)
+FIXUP_OVERFLOW = "raft_tpu_cert_fixup_overflow_total"
 #: exact-rescore candidate-pool widths (C = k + pad clamped to the pool)
 RESCORE_POOL = "raft_tpu_rescore_pool_width"
 #: IVF chunks whose q8 certificate failure forced an exact f32-scan rerun
@@ -137,9 +140,12 @@ def fixup_tier_for(n_fail: int, fix_tiers: Sequence[int],
 def record_certificate(site: str, n_queries: int, n_fail: int,
                        pool_width: Optional[int] = None,
                        fixup_rows: Optional[int] = None,
-                       rerun: bool = False, **meta) -> None:
-    """Host-side record of one certificate evaluation batch. Never
-    raises into the result path."""
+                       rerun: bool = False,
+                       n_over: Optional[int] = None, **meta) -> None:
+    """Host-side record of one certificate evaluation batch. ``n_over``
+    (where the site reports it) counts the batch's fixups whose select
+    overflowed into the whole-tile select. Never raises into the result
+    path."""
     if not quality_enabled():
         return
     try:
@@ -160,6 +166,12 @@ def record_certificate(site: str, n_queries: int, n_fail: int,
                           help="Static fixup-tier batch sizes "
                                "dispatched for failed queries",
                           buckets=COUNT_BUCKETS).observe(int(fixup_rows))
+        if n_over is not None:
+            reg.counter(FIXUP_OVERFLOW, labels,
+                        help="Certificate fixups whose select "
+                             "overflowed the threshold block filter "
+                             "into the whole-tile select").inc(
+                                 max(0, int(n_over)))
         if rerun:
             reg.counter(IVF_RERUNS, labels,
                         help="IVF q8 chunks rerun through the exact "
@@ -230,7 +242,8 @@ def measured_rerun_frac(site: str,
 
 
 # pending certificate stats whose failure count is still a device value:
-# (site, n_fail_device, n_queries, pool_width, fix_tiers, meta)
+# (site, n_fail_device, n_queries, pool_width, fix_tiers, n_over_device,
+#  meta)
 _PENDING_CAP = 4096
 _pending: collections.deque = collections.deque(maxlen=_PENDING_CAP)
 _pending_lock = threading.Lock()
@@ -238,11 +251,14 @@ _pending_lock = threading.Lock()
 
 def record_pending(site: str, n_fail, n_queries: int,
                    pool_width: Optional[int] = None,
-                   fix_tiers: Sequence[int] = (), **meta) -> None:
-    """Queue certificate stats whose ``n_fail`` is an UNRESOLVED device
-    scalar/array — no host sync here, so the dispatch path keeps its
-    async semantics; :func:`drain` converts later (the value is a tiny
-    output of a program whose results the caller consumes anyway)."""
+                   fix_tiers: Sequence[int] = (), n_over=None,
+                   **meta) -> None:
+    """Queue certificate stats whose ``n_fail`` (and ``n_over``, the
+    fixup select-overflow count, where the site has one) is an
+    UNRESOLVED device scalar/array — no host sync here, so the dispatch
+    path keeps its async semantics; :func:`drain` converts later (the
+    values are tiny outputs of a program whose results the caller
+    consumes anyway)."""
     if not quality_enabled():
         return
     try:
@@ -260,7 +276,8 @@ def record_pending(site: str, n_fail, n_queries: int,
         pass
     with _pending_lock:
         _pending.append((site, n_fail, int(n_queries),
-                         pool_width, tuple(fix_tiers), dict(meta)))
+                         pool_width, tuple(fix_tiers), n_over,
+                         dict(meta)))
 
 
 def _count_trace_skip(site: str) -> None:
@@ -284,14 +301,16 @@ def drain() -> int:
         with _pending_lock:
             if not _pending:
                 return n
-            site, nf, nq, pw, tiers, meta = _pending.popleft()
+            site, nf, nq, pw, tiers, no, meta = _pending.popleft()
         try:
             n_fail = int(np.sum(np.asarray(nf)))
+            n_over = None if no is None else int(np.sum(np.asarray(no)))
         except Exception:
             continue
         record_certificate(
             site, nq, n_fail, pool_width=pw,
-            fixup_rows=fixup_tier_for(n_fail, tiers, nq), **meta)
+            fixup_rows=fixup_tier_for(n_fail, tiers, nq), n_over=n_over,
+            **meta)
         n += 1
 
 
@@ -331,6 +350,9 @@ def quality_block(registry=None, drain_first: bool = True
             sites.setdefault(site, {})["fixups"] = int(metric.value)
         elif metric.name == IVF_RERUNS and site:
             sites.setdefault(site, {})["cert_reruns"] = int(metric.value)
+        elif metric.name == FIXUP_OVERFLOW and site:
+            sites.setdefault(site, {})["fixup_overflows"] = int(
+                metric.value)
         elif metric.name == PQ_RERUN_FRAC and site:
             sites.setdefault(site, {})["pq_rerun_frac"] = round(
                 float(metric.value), 6)

@@ -193,7 +193,7 @@ def knn_query(res, index, x, k: int, rescore: Optional[bool] = None,
         if qpad:
             xq = jnp.concatenate(
                 [xq, jnp.zeros((qpad, xq.shape[1]), jnp.float32)])
-        vals, ids, n_fail, margin = _knn_fused_core(
+        vals, ids, n_fail, margin, n_over = _knn_fused_core(
             xq, yp, y_hi, y_lo, yyh_k, yy_raw,
             k=k, T=T_, Qb=Qb_eff, g=g_, passes=passes_, metric=metric_,
             m=m_, rescore=rescore, pbits=pbits_, certify=certify,
@@ -203,7 +203,7 @@ def knn_query(res, index, x, k: int, rescore: Optional[bool] = None,
             vals, ids, margin = vals[:Q], ids[:Q], margin[:Q]
         if metric_ == "ip":
             vals = -vals        # internal −x·y ascending → IP descending
-        return vals, ids, n_fail, margin
+        return vals, ids, n_fail, margin, n_over
 
     statics = (k, T_, Qb_eff, g_, passes_, metric_, m_, bool(rescore),
                pbits_, certify, pool_algo, order_, dtype_, has_yp,
@@ -214,8 +214,8 @@ def knn_query(res, index, x, k: int, rescore: Optional[bool] = None,
     else:
         ops += [o for o in (idx.y_hi, idx.y_lo) if o is not None]
     ops += [idx.yyh_k, idx.yy_raw]
-    vals, ids, n_fail, margin = _aot_call(res, "knn_query", statics,
-                                          run, x, *ops)
+    vals, ids, n_fail, margin, n_over = _aot_call(
+        res, "knn_query", statics, run, x, *ops)
     # certificate/fixup telemetry for the AOT serving plane: the
     # failure count stays a device scalar here (quality.drain resolves
     # it later — the live request path never syncs for telemetry); the
@@ -232,7 +232,7 @@ def knn_query(res, index, x, k: int, rescore: Optional[bool] = None,
             n_queries=Q + ((-Q) % Qb_eff),
             pool_width=rescore_pool_width(k, S_pool, packed),
             fix_tiers=fixup_tiers_for(idx.yyh_k.shape[1]),
-            db_dtype=dtype_, passes=passes_)
+            n_over=n_over, db_dtype=dtype_, passes=passes_)
         if explain.active() is not None:
             explain.note_margin("runtime.knn_query", margin)
             explain.note(plane="brute", db_dtype=dtype_,

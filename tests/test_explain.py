@@ -163,7 +163,7 @@ def test_with_stats_margin_matches_diag_oracle():
     _, _, n_fail, bound, theta, err = _knn_fused_core(
         xp, idx.yp, idx.y_hi, idx.y_lo, idx.yyh_k, idx.yy_raw,
         _diag=True, **args)
-    _, _, n_fail_s, margin = _knn_fused_core(
+    _, _, n_fail_s, margin, _ = _knn_fused_core(
         xp, idx.yp, idx.y_hi, idx.y_lo, idx.yyh_k, idx.yy_raw,
         with_stats=True, **args)
     ref = np.asarray(bound) - (np.asarray(theta) + np.asarray(err))

@@ -669,3 +669,194 @@ def test_pool_select_routings_agree(monkeypatch):
     recall = np.mean([len(set(np.asarray(ids)[i]) & set(ref_ids[i])) / 8
                       for i in range(64)])
     assert recall >= 0.999
+
+
+# ------------------------------------------------------------------
+# certificate fixup: the threshold block filter (fixup_select)
+# ------------------------------------------------------------------
+def _tile_case(case, F=16, M=4096, k=8):
+    """(d2 [F, M], cand [F, k]) for one select case; cand are the rows'
+    candidate columns as the fixup holds them."""
+    import jax
+
+    r = np.random.default_rng(3)
+    d2 = r.random((F, M)).astype(np.float32)
+    if case == "ties":          # many equal values, across blocks
+        d2 = np.round(d2 * 40) / 40
+    elif case == "prefix_pads":
+        d2[:, 3000:] = np.inf
+    elif case == "ragged":
+        d2[:, r.random(M) < 0.2] = np.inf
+    elif case == "ip":          # negative scores (−x·y)
+        d2 = -d2
+    _, ids = jax.lax.top_k(-d2, 4 * k)
+    cand = np.asarray(ids[:, :k]).copy()
+    if case == "loose":         # t far past the k-th value
+        cand[:, -1] = np.asarray(ids)[:, -1]
+    elif case == "short":       # fewer than k real candidates: t = +inf
+        cand[0, 0] = -1
+    elif case == "duplicate":   # a candidate twice
+        cand[:, 1] = cand[:, 0]
+    return d2, cand
+
+
+@pytest.mark.parametrize("case,B,nb,exact", [
+    ("random", 128, 16, True),
+    ("random", 256, 16, True),
+    ("prefix_pads", 128, 16, True),
+    ("ragged", 128, 16, True),
+    ("ip", 128, 16, True),
+    ("duplicate", 128, 16, True),
+    ("ties", 128, 16, False),     # > nb marked blocks
+    ("loose", 128, 16, False),
+    ("short", 128, 16, False),
+    ("random", 128, 2, False),
+])
+def test_fixup_select_matches_flat_top_k(case, B, nb, exact):
+    """The filter's answer, where it claims exactness, and the blocked
+    fallback's always, equal the flat top_k over the tile bit for bit,
+    ties to the lowest column included."""
+    import jax
+    import jax.numpy as jnp
+
+    from raft_tpu.distance.knn_fused import blocked_top_k, fixup_select
+
+    k = 8
+    d2, cand = _tile_case(case, k=k)
+    F, M = d2.shape
+    nt, ni = jax.lax.top_k(-jnp.asarray(d2), k)
+    want_v, want_i = np.asarray(-nt), np.asarray(ni)
+    d2b = jnp.asarray(d2).reshape(F, M // B, B).transpose(1, 0, 2)
+    v, i, ok = jax.jit(fixup_select, static_argnums=(2, 3))(
+        d2b, jnp.asarray(cand), k, nb)
+    assert bool(ok) == exact
+    if exact:
+        assert np.array_equal(np.asarray(v).view(np.int32),
+                              want_v.view(np.int32))
+        assert np.array_equal(np.asarray(i), want_i)
+    fv, fi = jax.jit(blocked_top_k, static_argnums=1)(d2b, k)
+    assert np.array_equal(np.asarray(fv).view(np.int32),
+                          want_v.view(np.int32))
+    assert np.array_equal(np.asarray(fi), want_i)
+
+
+def test_fixup_filter_geometry():
+    """The filter engages by shape alone: the exact cells' per-chip
+    tiles take it, tiles it cannot shrink keep the flat top_k."""
+    from raft_tpu.distance.knn_fused import (_FIXUP_BLOCK,
+                                             fixup_filter_geometry)
+
+    assert fixup_filter_geometry(8388608, 100) == (_FIXUP_BLOCK, 256)
+    assert fixup_filter_geometry(1048576, 64) == (_FIXUP_BLOCK, 128)
+    assert fixup_filter_geometry(128 * _FIXUP_BLOCK, 64) is None
+    assert fixup_filter_geometry(1048576 + 1, 64) is None
+    assert fixup_filter_geometry(1048576, _FIXUP_BLOCK + 1) is None
+
+
+@pytest.fixture
+def fixup_filter(monkeypatch):
+    """Filter blocks of 128 columns, so that tiles of a few thousand
+    rows take the threshold filter; ``force_fallback()`` makes every
+    select report inexact, so the fixup takes its exact fallback. The
+    core's compiled programs are dropped around each change."""
+    import jax.numpy as jnp
+
+    from raft_tpu.distance import knn_fused as kf
+    from raft_tpu.observability.metrics import (MetricsRegistry,
+                                                set_registry)
+    from raft_tpu.observability import quality
+
+    class Paths:
+        def force_fallback(self):
+            select = kf.fixup_select
+            monkeypatch.setattr(
+                kf, "fixup_select",
+                lambda *a: (*select(*a)[:2], jnp.bool_(False)))
+            kf._knn_fused_core.clear_cache()
+
+        def counters(self):
+            from raft_tpu.observability import get_registry
+
+            quality.drain()
+            reg, site = get_registry(), {"site": "distance.knn_fused"}
+            return (reg.counter(quality.CERT_FIXUPS, site).value,
+                    reg.counter(quality.FIXUP_OVERFLOW, site).value)
+
+    monkeypatch.setattr(kf, "_FIXUP_BLOCK", 128)
+    prev = set_registry(MetricsRegistry())
+    quality.clear()
+    kf._knn_fused_core.clear_cache()
+    yield Paths()
+    kf._knn_fused_core.clear_cache()
+    quality.clear()
+    set_registry(prev)
+
+
+def _fixup_data(data, metric="l2", m=3000, d=32, Q=32):
+    """(x, y, rows_valid or None) whose certificate fails for some
+    queries while their candidates stay near the answer: overlapping
+    clusters (tighter for ip, whose scores spread less), optionally
+    with exact duplicate rows."""
+    r = np.random.default_rng(11)
+    noise = 3e-3 if metric == "ip" else 3e-2
+    base = r.normal(size=(40, d)).astype(np.float32)
+    y = base[r.integers(0, 40, m)] + noise * r.normal(
+        size=(m, d)).astype(np.float32)
+    x = base[r.integers(0, 40, Q)] + noise * r.normal(
+        size=(Q, d)).astype(np.float32)
+    if data == "ties":
+        # every row of the first third twice more, in other blocks, so
+        # equal distances straddle the k-th place
+        t = m // 3
+        y[t:2 * t] = y[:t]
+        y[2 * t:3 * t] = y[:t]
+    elif data == "overflow":
+        # one row 40 times, spread over more blocks than the filter
+        # keeps (nb = 16 at k = 8); a query at it ties them all at t
+        y[::m // 40][:40] = y[7]
+        x[0] = y[7]
+    rv = None
+    if data == "ragged":
+        rv = r.random(m) > 0.15
+    return x, y, rv
+
+
+@pytest.mark.parametrize("metric,lite,data", [
+    ("l2", False, "prefix"),
+    ("ip", False, "prefix"),
+    ("l2", True, "prefix"),
+    ("ip", True, "prefix"),
+    ("l2", False, "ragged"),
+    ("l2", False, "ties"),
+    ("l2", False, "overflow"),
+])
+def test_fixup_filter_matches_flat_select(fixup_filter, metric, lite,
+                                          data):
+    """Through the whole certified pipeline, on data whose certificate
+    fails: the fixup's filtered select returns the same (vals, ids),
+    bit for bit, as its exact fallback (the flat top_k over the same
+    tile, test above), in rescore and lite modes, for l2 and ip, with
+    prefix pads (m=3000 of M=3072 prepared rows) and a ragged mask, on
+    exact duplicates tying at the threshold, and where a row has more
+    marked blocks than the filter keeps (it overflows into the
+    fallback, counted once)."""
+    from raft_tpu.distance.knn_fused import prepare_knn_index
+
+    k = 8
+    x, y, rv = _fixup_data(data, metric)
+    idx = prepare_knn_index(y, passes=3, metric=metric, T=512, Qb=16,
+                            g=8, store_yp=not lite, rows_valid=rv)
+    assert idx.yyh_k.shape[1] > 2048        # the filter engages
+    v1, i1 = knn_fused(x, idx, k)
+    fixups, over = fixup_filter.counters()
+    assert fixups > 0
+    assert over == (1 if data == "overflow" else 0)
+    fixup_filter.force_fallback()
+    v2, i2 = knn_fused(x, idx, k)
+    assert fixup_filter.counters()[1] > over
+    assert np.array_equal(np.asarray(v1).view(np.int32),
+                          np.asarray(v2).view(np.int32))
+    assert np.array_equal(np.asarray(i1), np.asarray(i2))
+    if not lite and metric == "l2" and rv is None:
+        ref_vals, _, tol = _oracle(x, y, k)
+        np.testing.assert_allclose(np.asarray(v1), ref_vals, atol=tol)

@@ -22,6 +22,7 @@ from raft_tpu.distance.knn_sharded import (default_micro_batches,
                                            knn_fused_sharded,
                                            prepare_knn_index_sharded,
                                            resolve_merge_strategy)
+from raft_tpu.observability import get_registry
 from raft_tpu.parallel import make_mesh
 
 rng = np.random.default_rng(7)
@@ -138,6 +139,60 @@ def test_sharded_ragged_shards_exact_values():
     np.testing.assert_allclose(np.asarray(sv), ref_vals, rtol=1e-4,
                                atol=1e-4)
     assert int(np.asarray(si).max()) < m          # no pad ids leak
+
+
+@pytest.mark.parametrize("p", [2, 4])
+def test_sharded_fixup_takes_the_threshold_filter(p, monkeypatch):
+    """Per-shard certificate fixups select through the threshold block
+    filter (blocks of 128 columns here, so each shard's 3,072 prepared
+    rows take it) and the merged answer matches the single-device
+    exact one: the same id sets, f32-exact values, no select
+    overflowing into the fallback."""
+    from raft_tpu.distance import knn_fused as kf
+    from raft_tpu.observability import quality
+    from raft_tpu.observability.metrics import (MetricsRegistry,
+                                                set_registry)
+
+    monkeypatch.setattr(kf, "_FIXUP_BLOCK", 128)
+    kf._knn_fused_core.clear_cache()
+    prev = set_registry(MetricsRegistry())
+    quality.clear()
+    try:
+        r = np.random.default_rng(11)
+        m, d, nq, k = 3000 * p, 32, 32, 8
+        base = r.normal(size=(40, d)).astype(np.float32)
+        y = base[r.integers(0, 40, m)] + 3e-2 * r.normal(
+            size=(m, d)).astype(np.float32)
+        x = base[r.integers(0, 40, nq)] + 3e-2 * r.normal(
+            size=(nq, d)).astype(np.float32)
+        cfg = dict(T=512, Qb=16, g=8)
+        ov, oi = knn_fused(x, y, k=k, passes=3, **cfg)
+        mesh = _mesh(p)
+        idx = prepare_knn_index_sharded(y, mesh=mesh, passes=3, **cfg)
+        assert idx.rows_per == 3072
+        sv, si = knn_fused_sharded(x, idx, k, mesh=mesh)
+        # both f32-exact, to the cancellation floor of the expanded f32
+        # score: the same values, and ids whose true distances are the
+        # k smallest (near-equal ones may trade places)
+        tol = 8 * float((x ** 2).sum(1).max()
+                        + (y ** 2).sum(1).max()) * 2.0 ** -24
+        np.testing.assert_allclose(np.asarray(sv), np.asarray(ov),
+                                   atol=tol)
+        d2 = ((x[:, None, :].astype(np.float64)
+               - y[None, :, :].astype(np.float64)) ** 2).sum(-1)
+        for ids in (si, oi):
+            np.testing.assert_allclose(
+                np.sort(np.take_along_axis(d2, np.asarray(ids), 1), 1),
+                np.sort(d2, 1)[:, :k], atol=tol)
+        quality.drain()
+        reg = get_registry()
+        site = {"site": "distance.knn_fused_sharded"}
+        assert reg.counter(quality.CERT_FIXUPS, site).value > 0
+        assert reg.counter(quality.FIXUP_OVERFLOW, site).value == 0
+    finally:
+        kf._knn_fused_core.clear_cache()
+        quality.clear()
+        set_registry(prev)
 
 
 def test_query_sharded_mode(data):

@@ -194,6 +194,43 @@ def test_forced_failure_fixup_counter_exact():
     assert ev and ev[-1]["n_fail"] == expected
 
 
+def test_fixup_overflow_counter(monkeypatch):
+    """raft_tpu_cert_fixup_overflow_total counts the fixups whose select
+    overflowed the threshold block filter: 0 on an ordinary forced
+    fixup, 1 where a query ties one row's 40 copies, spread over more
+    blocks than the filter keeps (blocks of 128 columns here, so the
+    3,072-row tile takes the filter)."""
+    from raft_tpu.distance import knn_fused as kf
+
+    monkeypatch.setattr(kf, "_FIXUP_BLOCK", 128)
+    kf._knn_fused_core.clear_cache()
+    try:
+        r = np.random.default_rng(11)
+        m, d, k = 3000, 32, 8
+        base = r.normal(size=(40, d)).astype(np.float32)
+        y = base[r.integers(0, 40, m)] + 3e-2 * r.normal(
+            size=(m, d)).astype(np.float32)
+        x = base[r.integers(0, 40, 32)] + 3e-2 * r.normal(
+            size=(32, d)).astype(np.float32)
+        idx = kf.prepare_knn_index(y, passes=3, T=512, Qb=16, g=8)
+        site = {"site": "distance.knn_fused"}
+        reg = obs.get_registry()
+        kf.knn_fused(x, idx, k)
+        quality.drain()
+        assert reg.counter(quality.CERT_FIXUPS, site).value > 0
+        assert reg.counter(quality.FIXUP_OVERFLOW, site).value == 0
+        y[::75][:40] = y[7]
+        x[0] = y[7]
+        kf.knn_fused(x, kf.prepare_knn_index(y, passes=3, T=512, Qb=16,
+                                             g=8), k)
+        quality.drain()
+        assert reg.counter(quality.FIXUP_OVERFLOW, site).value == 1
+        block = quality_block()
+        assert block["sites"]["distance.knn_fused"]["fixup_overflows"] == 1
+    finally:
+        kf._knn_fused_core.clear_cache()
+
+
 def test_clean_run_counts_checks_not_fixups():
     from raft_tpu.distance.knn_fused import knn_fused
 

@@ -151,6 +151,51 @@ def test_brute_parity_clustered_offset_data():
                                rtol=1e-5, atol=1e-5)
 
 
+def test_int8_fixup_scores_the_f32_rows(monkeypatch):
+    """The int8 index's stored norms are the DEQUANTIZED rows' (ŷ), not
+    the f32 rows' its rescore and fixup score: on data where they
+    differ widely (one row per group 60× the rest, so the others
+    quantize coarsely), the certificate fails, the fixup runs — through
+    the threshold filter (blocks of 128 columns) — and its answers are
+    the f32 oracle's: norms taken from yp, exact."""
+    from raft_tpu.observability import get_registry, quality
+    from raft_tpu.observability.metrics import (MetricsRegistry,
+                                                set_registry)
+
+    monkeypatch.setattr(kf, "_FIXUP_BLOCK", 128)
+    kf._knn_fused_core.clear_cache()
+    prev = set_registry(MetricsRegistry())
+    quality.clear()
+    try:
+        m, d, nq, k = 3000, 32, 32, 8
+        r = np.random.default_rng(5)
+        Y = r.normal(size=(m, d)).astype(np.float32)
+        Y[::1024] *= 60.0
+        X = Y[r.integers(1, 1000, nq)] + 0.05 * r.normal(
+            size=(nq, d)).astype(np.float32)
+        idx8 = prepare_knn_index(Y, passes=3, T=256, Qb=16, g=4,
+                                 grid_order="db", db_dtype="int8")
+        yp = np.asarray(idx8.yp, np.float64)
+        gap = np.abs(np.asarray(idx8.yy_raw[0]) - (yp ** 2).sum(1))[:m]
+        assert np.median(gap) > 1e-2          # ŷ norms are not y's
+        v8, id8 = knn_fused(X, idx8, k)
+        quality.drain()
+        assert get_registry().counter(
+            quality.CERT_FIXUPS, {"site": "distance.knn_fused"}).value > 0
+        d2 = ((X[:, None, :].astype(np.float64)
+               - Y[None, :, :].astype(np.float64)) ** 2).sum(-1)
+        ref = np.sort(d2, 1)[:, :k]
+        tol = 8 * float((X ** 2).sum(1).max()
+                        + (Y[1:1024] ** 2).sum(1).max()) * 2.0 ** -24
+        np.testing.assert_allclose(np.asarray(v8), ref, atol=tol)
+        np.testing.assert_allclose(
+            np.take_along_axis(d2, np.asarray(id8), 1), ref, atol=tol)
+    finally:
+        kf._knn_fused_core.clear_cache()
+        quality.clear()
+        set_registry(prev)
+
+
 def test_brute_parity_ip_metric():
     m, d, nq, k = 4096, 64, 32, 8
     Y = rng.normal(size=(m, d)).astype(np.float32)

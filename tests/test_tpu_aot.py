@@ -114,6 +114,39 @@ def test_knn_fused_selected_pipeline_compiles(chip):
              with_stats=True)
 
 
+def test_chip_share_fixup_holds_one_tile(chip):
+    """One chip's share of the four-chip exact cell (8 x 2^20 rows,
+    a 512-row micro-batch, k=100) compiles with its certificate fixup
+    selecting through the threshold block filter, and holds the 16-row
+    distance tile once: a relayout of the tile, or an exact fallback
+    that sorts it whole, shows as temporary memory past one tile."""
+    from raft_tpu.distance import knn_fused as kf
+
+    rows, q, k = 8 * N_ROWS, 512, 100
+    held = {}
+
+    def prepare(y):
+        idx = kf.prepare_knn_index(y)
+        held["idx"] = idx
+        return idx.yp, idx.y_hi, idx.y_lo, idx.yyh_k, idx.yy_raw
+
+    ops = jax.eval_shape(prepare, jax.ShapeDtypeStruct((rows, DIM),
+                                                       jnp.float32))
+    idx = held["idx"]
+    M = ops[3].shape[1]
+    assert kf.fixup_tiers_for(M) == (16,)
+    assert kf.fixup_filter_geometry(M, k) is not None
+    args = [_spec(chip, (q, DIM), jnp.float32)] + [
+        _spec(chip, o.shape, o.dtype) for o in ops]
+    compiled = jax.jit(functools.partial(
+        kf._knn_fused_core, k=k, T=idx.T, Qb=min(idx.Qb, q), g=idx.g,
+        passes=idx.passes, metric="l2", m=rows, rescore=True,
+        pbits=idx.pbits, grid_order=idx.grid_order,
+        with_stats=True)).lower(*args).compile()
+    tile = 16 * M * 4
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.1 * tile
+
+
 @pytest.mark.parametrize("nqp", [NQP, 32, 64, 128, 256])
 @pytest.mark.parametrize("q8", [False, True])
 def test_fine_scan_list_major_compiles(chip, nqp, q8):
