@@ -85,9 +85,8 @@ def run_passes(root: str, names: Optional[Sequence[str]] = None,
     pass name → findings, deterministically ordered."""
     _ensure_registered()
     if program is None:
-        from .registry import EXTRA_SCAN_FILES, SCAN_PACKAGES
-        program = load_program(root, packages=SCAN_PACKAGES,
-                               extra_files=EXTRA_SCAN_FILES)
+        from .registry import SCAN_PACKAGES
+        program = load_program(root, packages=SCAN_PACKAGES)
     if graph is None:
         graph = build_call_graph(program)
     out: Dict[str, List[Finding]] = {}

@@ -17,8 +17,7 @@ import dataclasses
 import os
 from typing import Dict, Iterator, List, Optional, Sequence, Tuple
 
-#: packages walked by default (repo-relative); single files may be
-#: added via ``extra_files`` (bench.py, tools/*.py for registry diffs)
+#: packages walked by default (repo-relative)
 DEFAULT_PACKAGES: Tuple[str, ...] = ("raft_tpu",)
 
 
@@ -93,12 +92,11 @@ def _symbol_table(tree: ast.Module, modname: str) -> Dict[str, str]:
 
 
 def load_program(root: str,
-                 packages: Sequence[str] = DEFAULT_PACKAGES,
-                 extra_files: Sequence[str] = ()) -> Program:
-    """Parse every ``*.py`` under ``packages`` (plus ``extra_files``)
-    into a :class:`Program`. Unparseable files raise — a syntax error
-    anywhere in the tree is itself a finding-worthy failure, surfaced
-    loudly rather than skipped."""
+                 packages: Sequence[str] = DEFAULT_PACKAGES) -> Program:
+    """Parse every ``*.py`` under ``packages`` into a :class:`Program`.
+    Unparseable files raise — a syntax error anywhere in the tree is
+    itself a finding-worthy failure, surfaced loudly rather than
+    skipped."""
     modules: Dict[str, ModuleInfo] = {}
     by_rel: Dict[str, ModuleInfo] = {}
 
@@ -128,9 +126,6 @@ def load_program(root: str,
                 rel = os.path.relpath(os.path.join(dirpath, fn),
                                       root).replace(os.sep, "/")
                 _add(rel)
-    for rel in extra_files:
-        if os.path.exists(os.path.join(root, rel)):
-            _add(rel.replace(os.sep, "/"))
     return Program(root=root, modules=modules, by_rel=by_rel)
 
 

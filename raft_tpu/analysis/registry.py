@@ -40,10 +40,9 @@ from .framework import AnalysisPass, Finding, WARNING, register_pass
 from .loader import (ModuleInfo, Program, dotted, load_program,
                      string_constants)
 
-#: what the program spans beyond raft_tpu/ — the tools and bench
-#: drivers carry registry tables and env knobs of their own
+#: what the program spans beyond raft_tpu/ — the tools and the
+#: attribution scripts carry registry tables and env knobs of their own
 SCAN_PACKAGES: Tuple[str, ...] = ("raft_tpu", "tools", "benchmarks")
-EXTRA_SCAN_FILES: Tuple[str, ...] = ("bench.py",)
 
 FAULTS_MODULE = "raft_tpu/resilience/faults.py"
 FLIGHT_MODULE = "raft_tpu/observability/flight.py"
@@ -299,8 +298,7 @@ class Registries:
 def derive_registries(root: str,
                       program: Optional[Program] = None) -> Registries:
     if program is None:
-        program = load_program(root, packages=SCAN_PACKAGES,
-                               extra_files=EXTRA_SCAN_FILES)
+        program = load_program(root, packages=SCAN_PACKAGES)
     emitters = derive_emitter_kinds(program)
     return Registries(
         fault_sites=derive_fault_sites(program),

@@ -1198,8 +1198,8 @@ def search_ivf_flat(res, index, queries, k: int,
     (ref: ivf_flat::search — coarse probe, gather the probed lists,
     list-local select, merge.) Returns (d2 [nq, k] ascending, global
     ids [nq, k]); entries beyond the probed candidates carry
-    (+inf, −1) — recall vs the exact oracle is the tracked artifact
-    (benchmarks/bench_ann.py → BENCH_ANN.json).
+    (+inf, −1) — recall vs the exact oracle is what the
+    ``sift1m-ivf`` benchmark cell (``bench_suite/``) reports.
 
     ``index`` is an :class:`IvfFlatIndex` or a :class:`ShardedIvfIndex`
     (:func:`shard_ivf_lists` — whole lists over the mesh, per-shard
@@ -1244,7 +1244,7 @@ def search_ivf_flat(res, index, queries, k: int,
     if n_probes is None:
         # fleet-wide recall knob: RAFT_TPU_ANN_NPROBES retunes every
         # default-probes caller (serving planes included) without a
-        # rebuild — read per call, like the pool-select env
+        # rebuild — read per call
         P = _env_int("RAFT_TPU_ANN_NPROBES", base.n_probes_default)
     else:
         P = int(n_probes)

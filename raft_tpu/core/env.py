@@ -61,9 +61,6 @@ _knob("RAFT_TPU_DISABLE_QUALITY", "bool", False,
       "turn off the quality-telemetry plane only")
 
 # -- kernels / routing --------------------------------------------------
-_knob("RAFT_TPU_POOL_SELECT", "enum", "xla",
-      "fused-KNN pool-selection algorithm",
-      choices=("xla", "two_stage", "slotted", "chunked"))
 _knob("RAFT_TPU_SELECTK_TABLE", "path", None,
       "override the committed SELECT_K_MATRIX.json AUTO table")
 _knob("RAFT_TPU_TUNE_FUSED", "path", None,
@@ -181,10 +178,6 @@ _knob("RAFT_TPU_WAL_SYNC", "enum", "batch",
       "WAL fsync policy", choices=("always", "batch", "none"))
 _knob("RAFT_TPU_WAL_SEGMENT_MB", "float", 64.0,
       "WAL segment rotation size (MB)")
-
-# -- bench harness ------------------------------------------------------
-_knob("RAFT_TPU_SOLVERS_BUDGET_S", "float", None,
-      "wall-clock budget for benchmarks/bench_solvers_scale.py")
 
 
 # ------------------------------------------------------------ accessors

@@ -306,7 +306,7 @@ def knn(res, index, queries, k: int, metric: str = "sqeuclidean",
     # d ≤ 512 takes the single-shot kernel; wider features take the
     # d-chunked kernel (VMEM scratch accumulator) up to a pragmatic cap;
     # fused_eligible is THE shared backend/shape gate (also used by
-    # models.NearestNeighbors.fit and bench.py's prepare decision)
+    # models.NearestNeighbors.fit's prepare decision)
     from raft_tpu.distance.knn_fused import fused_eligible
 
     auto_fused = (algo == "auto" and fused_eligible(n, queries.shape[1])

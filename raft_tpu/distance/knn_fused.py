@@ -50,7 +50,7 @@ Modes:
   so the result is certified exact w.r.t. f32 distances.
 - ``passes=1`` (fast): single bf16 contraction; E = 0, so the certificate
   guarantees exactness w.r.t. the bf16 score function; recall vs f32 is
-  empirical (≥0.99 typical — measured in benchmarks/).
+  empirical (≥0.99 typical).
 
 Precision contract: the score function is the EXPANDED squared L2,
 ``‖x‖² + ‖y‖² − 2x·y``, evaluated in f32 — the same functional form the
@@ -181,89 +181,14 @@ def _err_bound_coeff_p1(d: int) -> float:
     return 2.0 ** -5 + 2.0 ** -14 + d * 2.0 ** -22
 
 
-def pool_select_algo() -> str:
-    """The pool-selection routing for knn_fused, from
-    ``RAFT_TPU_POOL_SELECT`` (xla | two_stage | slotted | chunked).
-    Read by the NON-jitted entry points and threaded into the core as a
-    static argument — an env read inside the jitted core would be
-    frozen into the first-traced executable and silently ignore later
-    changes (A/B harnesses flip this between calls)."""
-    algo = os.environ.get("RAFT_TPU_POOL_SELECT", "xla")
-    if algo not in ("xla", "two_stage", "slotted", "chunked"):
-        from raft_tpu.core.logger import log_warn
-
-        log_warn("RAFT_TPU_POOL_SELECT=%r unknown — using 'xla'", algo)
-        algo = "xla"
-    return algo
-
-
-def resolve_pool_algo(algo: str, pool_len: int, c: int) -> str:
-    """Decide the EFFECTIVE pool-selection algorithm for a pool of width
-    ``pool_len`` selecting ``c`` — called from the NON-jitted wrapper
-    BEFORE the core, so the downgrade decision (and its warning) happens
-    per call. Deciding inside the jitted core was an observability-
-    truthfulness bug: the trace-time ``log_warn`` fired once, and every
-    later call served from the compiled cache ran the XLA fallback
-    silently — A/B runs flipping ``RAFT_TPU_POOL_SELECT`` after the
-    first trace were mislabeled. The envelope predicates mirror the
-    selectors' own NotImplementedError checks (pool values are always
-    f32, so only the shape envelopes apply)."""
-    if algo == "slotted":
-        from raft_tpu.matrix.select_k_slotted import slotted_envelope
-
-        _, _, pool_cap = slotted_envelope(pool_len, c)
-        if c <= pool_cap:
-            return algo
-        reason = f"k={c} exceeds slotted pool {pool_cap}"
-    elif algo in ("two_stage", "chunked"):
-        from raft_tpu.matrix.select_k_chunked import chunked_envelope
-
-        nc = 2 if algo == "two_stage" else 8
-        if chunked_envelope(pool_len, nc):
-            return algo
-        reason = f"len={pool_len} too short for nc={nc}"
-    else:
-        return "xla"
-    from raft_tpu.core.logger import log_warn
-
-    log_warn("pool select %r outside envelope on len=%d→%d (%s) — "
-             "using XLA top_k for this call", algo, pool_len, c, reason)
-    return "xla"
-
-
-def _pool_smallest(a, c: int, algo: str = "xla"):
+def _pool_smallest(a, c: int):
     """Exact c smallest per row of the candidate pool ``a`` →
-    (values ascending, positions). The driver profile attributes ~4.5
-    of 19.3 ms e2e to this selection (XLA's TopK measured ~2.5×
-    superlinear in width in-composite, round 3) — route it to any of
-    the repo's EXACT selection algorithms so an A/B
-    (``RAFT_TPU_POOL_SELECT``) can flip algorithms end-to-end without
-    code edits. Exactness is non-negotiable here: the twin-pool
-    certificate's bound_a1 / C-th-pruned terms assume exact selection
-    (an approximate selector leaves skipped bucket-top-2 entries with
-    no floor — the a3 term does not cover them). Values are re-gathered
-    from ``a`` so packed mantissa codes survive bit-exactly.
-
-    ``algo`` must already be the EFFECTIVE algorithm: the non-jitted
-    wrapper resolves the shape envelope via :func:`resolve_pool_algo`
-    per call (an out-of-envelope algo here raises at trace time instead
-    of silently mislabeling what ran — the old in-core fallback logged
-    once at trace time and lied for every cached call after)."""
-    B, S = a.shape
-    if algo in ("two_stage", "slotted", "chunked"):
-        from raft_tpu.matrix.select_k_chunked import select_k_chunked
-        from raft_tpu.matrix.select_k_slotted import select_k_slotted
-
-        idx = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None],
-                               (B, S))
-        if algo == "slotted":
-            vals, pos = select_k_slotted(a, idx, c, True)
-        else:
-            # two_stage IS the chunked merge with 2 chunks
-            vals, pos = select_k_chunked(
-                a, idx, c, True, nc=2 if algo == "two_stage" else 8)
-        # bit-exact packed codes: re-gather from the input
-        return jnp.take_along_axis(a, pos, axis=1), pos
+    (values ascending, positions), by XLA's TopK. Exactness is
+    non-negotiable here: the twin-pool certificate's bound_a1 /
+    C-th-pruned terms assume exact selection (an approximate selector
+    leaves skipped bucket-top-2 entries with no floor — the a3 term does
+    not cover them). Negation flips only the sign bit, so packed
+    mantissa codes survive bit-exactly."""
     neg, pos = jax.lax.top_k(-a, c)
     return -neg, pos
 
@@ -377,9 +302,7 @@ def auto_pack_bits(n_tiles: int, T: int) -> int:
     M/2^pbits wide, so pick the widest codes that keep ≥ ~2.5k buckets
     (fixup rate ∝ 1/buckets²), clamped to [8, 13] (value perturbation
     2^(pbits−23) must stay well under the error margins). ONE
-    definition — prepare_knn_index and the north-star benchmark both
-    call it, so the measured configuration cannot drift from
-    production's."""
+    definition, which prepare_knn_index calls."""
     return min(_PBITS_MAX, max(_PACK_BITS, int(math.floor(
         math.log2(max(n_tiles * T / 2560.0, 256.0))))))
 
@@ -582,14 +505,13 @@ def _prepare_ops_q8(y, T: int, g: int, metric: str,
 @functools.partial(jax.jit,
                    static_argnames=("k", "T", "Qb", "g", "passes", "metric",
                                     "m", "rescore", "pbits", "certify",
-                                    "pool_algo", "grid_order", "db_dtype",
+                                    "grid_order", "db_dtype",
                                     "_diag", "with_stats"))
 def _knn_fused_core(x, yp, y_hi, y_lo, yyh_k, yy_raw,
                     k: int, T: int, Qb: int, g: int, passes: int,
                     metric: str, m: int, rescore: bool = True,
                     pbits: int = _PACK_BITS, certify: str = "kernel",
-                    pool_algo: str = "xla", grid_order: str = "query",
-                    db_dtype: str = "bf16",
+                    grid_order: str = "query", db_dtype: str = "bf16",
                     _diag: bool = False, with_stats: bool = False,
                     m_valid=None, rows_valid=None,
                     y_q=None, y_scale_k=None,
@@ -758,7 +680,7 @@ def _knn_fused_core(x, yp, y_hi, y_lo, yyh_k, yy_raw,
         C = min(k + _POOL_PAD, 2 * Ca)
         # packed f32 order == value order (negation flips only the sign
         # bit, so codes survive the top_k round-trip)
-        a1_sel, pos1 = _pool_smallest(a1p, Ca, pool_algo)
+        a1_sel, pos1 = _pool_smallest(a1p, Ca)
         a2_sel = jnp.take_along_axis(a2p, pos1, axis=1)
         cands = jnp.concatenate([a1_sel, a2_sel], axis=1)       # [Q, 2Ca]
         cpos = jnp.concatenate([pos1, pos1], axis=1)
@@ -821,7 +743,7 @@ def _knn_fused_core(x, yp, y_hi, y_lo, yyh_k, yy_raw,
         pool_v = jnp.concatenate([a1, a2], axis=1)              # [Q, 2S']
         pool_id = jnp.concatenate([id1, id2], axis=1)
         C = min(k + _POOL_PAD, pool_v.shape[1])
-        cand_v_hat, pos = _pool_smallest(pool_v, C, pool_algo)  # ascending
+        cand_v_hat, pos = _pool_smallest(pool_v, C)  # ascending
         cand_pid = jnp.take_along_axis(pool_id, pos, axis=1)    # point ids
         cand_pid = jnp.where(jnp.isfinite(cand_v_hat), cand_pid, -1)
         a3_min = 2.0 * jnp.min(a3, axis=1) + xx_r[:, 0]
@@ -1078,9 +1000,8 @@ def _knn_fused_core(x, yp, y_hi, y_lo, yyh_k, yy_raw,
     if _diag:
         # measurement-only: the certified pipeline WITHOUT the fixup
         # cascade, plus the failure count and the certificate internals
-        # (bound, θ, err) — benchmarks/ use this to attribute time and
-        # to see WHY queries fail instead of guessing; NOT a valid
-        # exactness contract
+        # (bound, θ, err) — the tests' oracle for WHY queries fail;
+        # NOT a valid exactness contract
         return vals, ids, n_fail, bound, theta, err
 
     # tiered cascade: n_fail==0 → no-op; else the smallest tier that
@@ -1192,8 +1113,8 @@ def footprint_for(T: int, Qb: int, d: int, passes: int,
 
 def resolve_grid_order(grid_order: str, d: int, packed: bool) -> str:
     """EFFECTIVE grid order for a call — decided (and logged) in the
-    non-jitted wrapper like resolve_pool_algo, so a downgraded request
-    is visible per call instead of silently mislabeling what ran. The
+    non-jitted wrapper, so a downgraded request is visible per call
+    instead of silently mislabeling what ran. The
     database-major kernels are packed-only and single-shot-only
     (d ≤ _D_SINGLE_SHOT); anything outside that envelope runs the
     query-major pipeline."""
@@ -1471,9 +1392,8 @@ def fused_defaults(passes: int = 3) -> Tuple[int, int, int]:
 
 def fused_eligible(n_rows: int, d: int) -> bool:
     """THE fused-pipeline eligibility gate (backend + shape envelope),
-    shared by knn()'s auto-routing, models.NearestNeighbors.fit's
-    prepare decision, and bench.py — one predicate, no drifting
-    copies."""
+    shared by knn()'s auto-routing and models.NearestNeighbors.fit's
+    prepare decision — one predicate, no drifting copies."""
     return (jax.default_backend() == "tpu"
             and n_rows >= 4096 and d <= 4096)
 
@@ -1835,20 +1755,15 @@ def knn_fused(x, y, k: int, passes: int = 3,
         raise ValueError("knn_fused: an int8-streamed index is always "
                          "exact-rescored (rescore=False would return "
                          "top-k of the QUANTIZED score function)")
-    # effective pool-selection algorithm, decided (and logged) HERE in
-    # the non-jitted wrapper, per call — the core's static pool geometry
-    # reproduced exactly (S' = ceil(n_tiles/g)·128; packed pools are S'
-    # wide, unpacked 2·S')
+    # the core's static pool geometry reproduced exactly
+    # (S' = ceil(n_tiles/g)·128), for the rescore pool width below
     S_pool = -(-n_tiles // g) * _LANES
     packed_env = g * (T // _LANES) <= (1 << idx.pbits)
-    pool_len = S_pool if packed_env else 2 * S_pool
-    pool_algo = resolve_pool_algo(pool_select_algo(), pool_len,
-                                  min(k + _POOL_PAD, pool_len))
     vals, ids, n_fail, margin, n_over = _knn_fused_core(
         x, idx.yp, idx.y_hi, idx.y_lo, idx.yyh_k, idx.yy_raw,
         k=k, T=T, Qb=Qb, g=g, passes=passes, metric=metric, m=m,
         rescore=rescore, pbits=idx.pbits, certify=certify,
-        pool_algo=pool_algo, grid_order=grid_order,
+        grid_order=grid_order,
         db_dtype=db_dtype, with_stats=True, y_q=idx.y_q,
         y_scale_k=idx.y_scale_k, eq_groups=idx.eq_groups,
         rows_valid=idx.rows_valid)

@@ -78,12 +78,11 @@ from raft_tpu.resilience import (PoisonedOutputError, degrade_merge,
                                  record_degradation, record_exhausted,
                                  record_retry)
 from raft_tpu.distance.knn_fused import (
-    _D_SINGLE_SHOT, _DC, _LANES, _PACK_BITS, _PBITS_MAX, _POOL_PAD,
+    _D_SINGLE_SHOT, _DC, _LANES, _PACK_BITS, _PBITS_MAX,
     _Q_CHUNK, DB_DTYPES, GRID_ORDERS, KnnIndex, _knn_fused_core,
     _prepare_ops, _prepare_ops_q8, auto_pack_bits, fit_config,
-    fixup_tiers_for, fused_config, pool_select_algo, prepare_knn_index,
-    rescore_pool_width, resolve_db_dtype, resolve_grid_order,
-    resolve_pool_algo)
+    fixup_tiers_for, fused_config, prepare_knn_index, rescore_pool_width,
+    resolve_db_dtype, resolve_grid_order)
 from raft_tpu.observability.quality import record_pending
 
 SHARD_MODES = ("db", "query")
@@ -519,11 +518,7 @@ def knn_fused_sharded(x, y, k: int, mesh=None, axis: str = "x",
         x = jnp.concatenate(
             [x, jnp.zeros((nq, d_eff - x.shape[1]), jnp.float32)], axis=1)
 
-    S_pool = -(-n_tiles_loc // idx.g) * _LANES
     packed = idx.g * (idx.T // _LANES) <= (1 << idx.pbits)
-    pool_len = S_pool if packed else 2 * S_pool
-    pool_algo = resolve_pool_algo(pool_select_algo(), pool_len,
-                                  min(k + _POOL_PAD, pool_len))
 
     has_yp = idx.yp_s is not None
     has_ylo = idx.y_lo_s is not None
@@ -552,7 +547,7 @@ def knn_fused_sharded(x, y, k: int, mesh=None, axis: str = "x",
         key = ("db", mesh, axis, k, idx.T, Qb_eff, idx.g, idx.passes,
                idx.metric, idx.rows_per, idx.share, m, nb, qb_len,
                merge_eff,
-               bool(rescore), idx.pbits, certify, pool_algo,
+               bool(rescore), idx.pbits, certify,
                idx.grid_order, idx.db_dtype, has_yp, has_ylo)
         fn = _SHARDED_FUSED_CACHE.get(key)
         if fn is None:
@@ -598,7 +593,7 @@ def knn_fused_sharded(x, y, k: int, mesh=None, axis: str = "x",
                         k=k, T=T_, Qb=Qb_eff, g=g_, passes=passes_,
                         metric=metric_, m=rows_per, rescore=rescore,
                         pbits=pbits_, certify=certify,
-                        pool_algo=pool_algo, grid_order=order_,
+                        grid_order=order_,
                         db_dtype=dtype_, with_stats=True, y_q=yq_l,
                         y_scale_k=scl_l, eq_groups=eq_l, m_valid=m_loc)
                     nf, no = nf + nfb, no + nob
@@ -790,17 +785,14 @@ def _knn_query_sharded(x, y, k, mesh, axis, passes, metric, T, Qb, g,
     n_tiles = idx.yyh_k.shape[1] // idx.T
     S_pool = -(-n_tiles // idx.g) * _LANES
     packed = idx.g * (idx.T // _LANES) <= (1 << idx.pbits)
-    pool_len = S_pool if packed else 2 * S_pool
     if k > 2 * S_pool:
         raise NotImplementedError(
             f"knn_fused_sharded: k={k} too large for pool {2 * S_pool}")
-    pool_algo = resolve_pool_algo(pool_select_algo(), pool_len,
-                                  min(k + _POOL_PAD, pool_len))
     has_yp = idx.yp is not None
     has_ylo = idx.y_lo is not None
     key = ("query", mesh, axis, k, idx.T, Qb_eff, idx.g, idx.passes,
            idx.metric, m, qs_len, bool(rescore), idx.pbits, certify,
-           pool_algo, idx.grid_order, idx.db_dtype, has_yp, has_ylo)
+           idx.grid_order, idx.db_dtype, has_yp, has_ylo)
     fn = _SHARDED_FUSED_CACHE.get(key)
     if fn is None:
         T_, g_, passes_, metric_ = idx.T, idx.g, idx.passes, idx.metric
@@ -823,7 +815,7 @@ def _knn_query_sharded(x, y, k, mesh, axis, passes, metric, T, Qb, g,
                 xq, yp_l, yhi_l, ylo_l, yyh_l, yy_l,
                 k=k, T=T_, Qb=Qb_eff, g=g_, passes=passes_,
                 metric=metric_, m=m, rescore=rescore, pbits=pbits_,
-                certify=certify, pool_algo=pool_algo, grid_order=order_,
+                certify=certify, grid_order=order_,
                 db_dtype=dtype_, with_stats=True, y_q=yq_l,
                 y_scale_k=scl_l, eq_groups=eq_l)
             return v, i, nf.reshape(1), no.reshape(1)

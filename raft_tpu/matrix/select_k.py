@@ -40,8 +40,8 @@ from raft_tpu.resilience import fault_point
 
 
 def _load_select_k_table():
-    """Load the measured algorithm table (benchmarks/select_k_matrix.py →
-    SELECT_K_MATRIX.json), if one has been committed. Returns a list of
+    """Load the measured algorithm table (SELECT_K_MATRIX.json, a sweep
+    measured on the chip), if one has been committed. Returns a list of
     (log-coords, SelectAlgo) cells, or None."""
     import json
     import math
@@ -111,8 +111,8 @@ def choose_select_k_algorithm(n_rows: int, length: int, k: int,
     decision tree over (rows, cols, k), generated from benchmark sweeps.)
 
     The TPU analog is table-driven the same way: when a measured
-    ``SELECT_K_MATRIX.json`` exists (produced on real TPU by
-    benchmarks/select_k_matrix.py — never from CPU timings), AUTO picks
+    ``SELECT_K_MATRIX.json`` exists (measured on real TPU — never from
+    CPU timings), AUTO picks
     the measured-fastest algorithm of the nearest grid cell in
     (log batch, log len, log k), restricted to algorithms whose
     envelope admits (length, k, dtype) — AUTO never returns a choice

@@ -25,13 +25,6 @@ load + WAL tail replay). ``MutableIndex(durable_dir=...)`` /
 ``ServingEngine(durable=True)`` turn it on; acked writes then survive
 SIGKILL at any instruction boundary (the crash matrix in
 tests/test_durability.py).
-
-Evidence: ``benchmarks/bench_mutation.py`` drives a closed-loop mixed
-read/write load across a full compaction cycle and writes
-``BENCH_MUTATION.json``; ``benchmarks/bench_recovery.py`` measures the
-durable-write overhead + recovery time vs WAL tail length and writes
-``BENCH_RECOVERY.json`` — both gated by ``tools/bench_report.py
---check``.
 """
 
 from raft_tpu.mutable.checkpoint import (CheckpointStore,

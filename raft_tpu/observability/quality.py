@@ -398,8 +398,8 @@ def _sample_hash(rid: int) -> float:
 
 
 def recall_at_k(served_ids: np.ndarray, true_ids: np.ndarray) -> float:
-    """Mean per-row |served ∩ true| / k — the same recall the offline
-    ANN benchmark reports (``benchmarks/bench_ann.py``)."""
+    """Mean per-row |served ∩ true| / k — the same recall the
+    benchmark cells (``bench_suite/``) report."""
     served = np.asarray(served_ids)
     true = np.asarray(true_ids)
     if served.ndim == 1:

@@ -41,7 +41,7 @@ evidence, never calibration evidence). ``tools/bench_report.py
 ``measured: false`` tune tables) instead of just replacing them.
 Persistence is opt-in: in-memory always; written to
 ``RAFT_TPU_DRIFT_LEDGER`` (path) when set, or via :meth:`DriftLedger.
-save` (the benchmarks write ``DRIFT_LEDGER.json`` at the repo root).
+save`.
 """
 
 from __future__ import annotations

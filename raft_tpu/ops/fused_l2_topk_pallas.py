@@ -1,4 +1,4 @@
-"""Fused L2 distance + slotted top-k candidate kernel (Pallas/Mosaic).
+"""Fused L2 distance + grouped top-k candidate kernels (Pallas/Mosaic).
 
 The TPU rendering of the reference's fused distance→select pipeline:
 (ref: cpp/include/raft/matrix/detail/select_radix.cuh:639 radix_kernel,
@@ -14,21 +14,19 @@ Design (TPU-first, not a translation):
 - Each cell contracts ``X_block[Qb,d] @ Y_tile[T,d]ᵀ`` on the MXU in
   bfloat16 (1 pass, ``passes=1``) or with a hi/lo bf16 split
   (``passes=3``: hi·hi + hi·lo + lo·hi — f32-grade accuracy at 3× bf16
-  cost, the TPU replacement for fp32 SGEMM), then forms
-  ``d2 = xx + yy − 2S`` with exact f32 norm corrections.
-- The [Qb, T] distance tile NEVER leaves VMEM. It is folded lane-chunk by
-  lane-chunk into per-slot running (min, argmin, 2nd-min) — a "slot" is a
-  (tile, lane-class) bucket; the fold is pure VPU compare/selects, the
-  scan-free replacement for warp-shuffle insertion sorts.
-- Outputs: per-slot min ``m1 [Q, S]`` + its index ``i1 [Q, S]``, plus a
-  per-query running min over slots of the slot 2nd-min (``m2min [Q, LANES]``
-  — folded over tiles in-place). ``m2min`` powers the EXACTNESS
-  CERTIFICATE in raft_tpu.distance.knn_fused: every non-candidate point is
-  ≥ its slot's 2nd-min, so ``min_slots m2 ≥ θ`` proves the candidate top-k
+  cost, the TPU replacement for fp32 SGEMM), then forms the half-score
+  ``yy/2 − S`` against exact f32 norms.
+- The [Qb, T] score tile NEVER leaves VMEM. It is folded lane-chunk by
+  lane-chunk into per-(lane, tile-group) running top-2 plus the group
+  3rd-min; the fold is pure VPU compare/selects, the scan-free
+  replacement for warp-shuffle insertion sorts.
+- The 3rd-min powers the EXACTNESS CERTIFICATE in
+  raft_tpu.distance.knn_fused: every non-candidate point is ≥ its
+  group's 3rd-min, so ``min_groups a3 ≥ θ`` proves the candidate top-k
   is the true top-k (see knn_fused for the fixup path when it fails).
 
-Padded index rows are masked to +inf inside the kernel (the caller passes
-the real row count); padded rows therefore never pollute slots.
+Padded index rows carry ``yy/2 = +inf`` from the caller, so they never
+win a slot.
 """
 
 from __future__ import annotations
@@ -91,15 +89,15 @@ def vmem_footprint(T: int, Qb: int, d: int, passes: int,
     """Estimated scoped-VMEM bytes of one fused-kernel grid cell.
 
     Calibrated against measured Mosaic compiles/rejections on v5e:
-    - slot kernel: (T=2048, Qb=1024, d=128, p3) rejected at 20.35 MB
-      vs the 16 MB limit; same shape at p1 compiled; (4096, 512, p3)
-      rejected. Model: [Qb, T] f32 score tile × ~1.25 (p1) / ~2.25 (p3)
-      live copies incl. the col-iota mask temporaries.
-    - group kernel (production): (2048, 512, d=128, p1) rejected at
-      16.36 MB WITH in-kernel masking; masking is since removed (yy
-      carries +inf — two fewer [Qb, T] buffers) but the in-kernel merge
-      holds more fold state, so its factors stay higher than the slot
-      kernel's: ~2.2 (p1) / ~3.2 (p3).
+    - group kernel: (2048, 512, d=128, p1) rejected at 16.36 MB WITH
+      in-kernel masking; masking is since removed (yy carries +inf —
+      two fewer [Qb, T] buffers) but the in-kernel merge holds the
+      top-2 fold state, so its [Qb, T] f32 score tile is priced at
+      ~2.2 (p1) / ~3.2 (p3) live copies.
+
+    ``kernel`` names one of the kernels below: "group", "packed",
+    "stream", "stream_db", "stream_db_q8", "stream_dbuf" or
+    "stream_dbuf_q8"; any other name raises.
 
     ``g`` (tiles per group) only enters the database-major models —
     "stream_db" holds a whole [g·T, d] y super-block resident,
@@ -172,8 +170,7 @@ def vmem_footprint(T: int, Qb: int, d: int, passes: int,
         d2_bufs = 1.8 if passes == 1 else 2.8
         n_out = 3
     else:
-        d2_bufs = 1.25 if passes == 1 else 2.25
-        n_out = 3
+        raise ValueError(f"vmem_footprint: unknown kernel {kernel!r}")
     dc = min(d, 256) if dchunk else d
     bytes_ = int(Qb * T * 4 * d2_bufs)
     bytes_ += T * dc * 2 * 2 * (2 if passes == 3 else 1)  # y hi(/lo), 2 bufs
@@ -235,122 +232,7 @@ def _contract_q8(x, yq, passes: int):
     return s
 
 
-def _fold_and_write(d2, j, m_real_ref, m1_ref, i1_ref, m2min_ref,
-                    T: int, Qb: int, mask: bool = True, track: bool = True):
-    """Mask padded index rows, fold the [Qb, T] distance tile into LANES
-    slots (per-slot top-2 + argmin-1), and write/accumulate the outputs.
-    Shared by the single-shot and d-chunked kernels.
-
-    ``mask=False`` / ``track=False`` are MEASUREMENT-ONLY knobs
-    (benchmarks/profile_fused.py bounds the cost of the mask and of the
-    index/2nd-min bookkeeping with them): mask=False requires pre-masked
-    operands; track=False returns i1 = 0 and m2min = the slot MIN — not
-    valid certificate inputs."""
-    n_chunks = T // _LANES
-    if mask:
-        # mask padded index rows (global col ≥ m_real) to +inf
-        col = j * T + jax.lax.broadcasted_iota(jnp.int32, (Qb, T), 1)
-        d2 = jnp.where(col < m_real_ref[0], d2, jnp.inf)
-
-    # slot class c collects columns {c, c+128, c+256, ...} of this tile
-    # (chunk r contributes its lane c as global column j*T + r*128 + c).
-    inf = jnp.full((Qb, _LANES), jnp.inf, jnp.float32)
-    if not track:
-        a1 = inf
-        for r in range(n_chunks):
-            a1 = jnp.minimum(a1, d2[:, r * _LANES:(r + 1) * _LANES])
-        a2 = a1
-        i1 = jnp.zeros((Qb, _LANES), jnp.int32)
-    else:
-        a1, a2 = inf, inf
-        i1 = jnp.full((Qb, _LANES), -1, jnp.int32)
-        lane = jax.lax.broadcasted_iota(jnp.int32, (Qb, _LANES), 1)
-        for r in range(n_chunks):
-            c = d2[:, r * _LANES:(r + 1) * _LANES]
-            ci = j * T + r * _LANES + lane
-            lt1 = c < a1
-            a2 = jnp.where(lt1, a1, jnp.minimum(a2, c))
-            a1 = jnp.where(lt1, c, a1)
-            i1 = jnp.where(lt1, ci, i1)
-
-    m1_ref[...] = a1
-    i1_ref[...] = i1
-    # running min over slots of the slot-2nd-min (certificate input);
-    # the m2min output block is revisited by every tile of this q-block
-    @pl.when(j == 0)
-    def _():
-        m2min_ref[...] = a2
-
-    @pl.when(j != 0)
-    def _():
-        m2min_ref[...] = jnp.minimum(m2min_ref[...], a2)
-
-
-def _fused_kernel(m_real_ref, x_ref, yhi_ref, xx_ref, yy_ref,
-                  m1_ref, i1_ref, m2min_ref,
-                  *, T: int, Qb: int, ylo_ref=None,
-                  mask: bool = True, track: bool = True):
-    """One (query-block, index-tile) cell. ``ylo_ref`` present ⇒ bf16x3."""
-    j = pl.program_id(1)
-    s = _contract(x_ref[...], yhi_ref[...],
-                  None if ylo_ref is None else ylo_ref[...])
-    d2 = xx_ref[...] + yy_ref[...] - 2.0 * s         # [Qb,1]+[1,T]-[Qb,T]
-    _fold_and_write(d2, j, m_real_ref, m1_ref, i1_ref, m2min_ref,
-                    T=T, Qb=Qb, mask=mask, track=track)
-
-
-def _fused_kernel_dchunk(m_real_ref, x_ref, yhi_ref, xx_ref, yy_ref,
-                         m1_ref, i1_ref, m2min_ref, acc_ref,
-                         *, T: int, Qb: int, ylo_ref=None):
-    """d-chunked cell (grid (nq, n_tiles, n_dchunks), d innermost): the
-    partial contraction accumulates into a VMEM scratch [Qb, T]; the
-    mask+fold runs only on the LAST d-chunk. Lifts the d ≤ 512 envelope
-    — the d2 tile still never touches HBM."""
-    j = pl.program_id(1)
-    l = pl.program_id(2)
-    n_dc = pl.num_programs(2)
-    s = _contract(x_ref[...], yhi_ref[...],
-                  None if ylo_ref is None else ylo_ref[...])
-
-    @pl.when(l == 0)
-    def _():
-        acc_ref[...] = s
-
-    @pl.when(l != 0)
-    def _():
-        acc_ref[...] = acc_ref[...] + s
-
-    @pl.when(l == n_dc - 1)
-    def _():
-        d2 = xx_ref[...] + yy_ref[...] - 2.0 * acc_ref[...]
-        _fold_and_write(d2, j, m_real_ref, m1_ref, i1_ref, m2min_ref,
-                        T=T, Qb=Qb)
-
-
-# --- scaffolding shared by the single-shot and d-chunked calls (the
-# out-spec index maps take (i, j, *rest) so the same lambdas serve both
-# grid arities; *rest swallows the extra grid index + prefetch refs) ---
-
-def _slot_out_specs(Qb: int):
-    return [
-        pl.BlockSpec((Qb, _LANES), lambda i, j, *_: (i, j),
-                     memory_space=pltpu.VMEM),          # m1
-        pl.BlockSpec((Qb, _LANES), lambda i, j, *_: (i, j),
-                     memory_space=pltpu.VMEM),          # i1
-        pl.BlockSpec((Qb, _LANES), lambda i, j, *_: (i, 0),
-                     memory_space=pltpu.VMEM),          # m2min (revisited)
-    ]
-
-
-def _slot_out_shape(Q: int, S: int):
-    return [
-        jax.ShapeDtypeStruct((Q, S), jnp.float32),
-        jax.ShapeDtypeStruct((Q, S), jnp.int32),
-        jax.ShapeDtypeStruct((Q, _LANES), jnp.float32),
-    ]
-
-
-def _slot_cost(Q: int, M: int, d: int, S: int, passes: int):
+def _group_cost(Q: int, M: int, d: int, S: int, passes: int):
     return pl.CostEstimate(
         flops=2 * Q * M * d * passes,
         bytes_accessed=(Q * d * 4 + M * d * 2 * (2 if passes == 3 else 1)
@@ -387,163 +269,21 @@ def _check_pair_envelope(n_chunks: int):
             f"{n_chunks}")
 
 
-def _make_kernel(base, passes: int, T: int, Qb: int, **fold_kw):
-    """Bind the base kernel for the passes mode; for passes == 3 reorder
-    the y_lo ref out of the positional stream (*rest carries the output
-    refs and, for the d-chunked kernel, the scratch ref)."""
-    if passes != 3:
-        return functools.partial(base, T=T, Qb=Qb, ylo_ref=None, **fold_kw)
-
-    def kernel(m_real_ref, x_ref, yhi_ref, ylo_ref, xx_ref, yy_ref, *rest):
-        base(m_real_ref, x_ref, yhi_ref, xx_ref, yy_ref, *rest,
-             T=T, Qb=Qb, ylo_ref=ylo_ref, **fold_kw)
-
-    return kernel
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("T", "Qb", "passes", "mask", "track"))
-def fused_l2_slot_topk(x, y_hi, y_lo, xx, yy, m_real,
-                       T: int, Qb: int, passes: int,
-                       mask: bool = True, track: bool = True
-                       ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """Run the fused kernel. ``mask``/``track`` are measurement-only
-    knobs (see _fold_and_write) — production callers use the defaults.
-
-    Args:
-      x: [Q, d] f32 queries (Q a multiple of Qb).
-      y_hi, y_lo: [M, d] bf16 hi/lo split of the padded index (M a multiple
-        of T); ``y_lo`` is only DMA'd/read when passes == 3.
-      xx, yy: exact f32 squared norms, [Q, 1] and [1, M] (padded rows'
-        yy = 0 — they are masked in-kernel anyway).
-      m_real: [1] int32 — real (unpadded) index row count.
-      T: index tile length; Qb: query block; passes: 1 (bf16) or 3 (bf16x3).
-
-    Returns:
-      m1 [Q, S] f32, i1 [Q, S] int32, m2min [Q, LANES] f32 with
-      S = (M // T) * LANES; slot s = (tile = s // LANES) × (lane-class =
-      s % LANES); i1 holds GLOBAL index-row ids; padded-only slots keep
-      m1 = +inf, i1 = -1.
-    """
-    _check_tiling(T, Qb)
-    Q, d = x.shape
-    M = y_hi.shape[0]
-    n_tiles = M // T
-    nq = Q // Qb
-    S = n_tiles * _LANES
-
-    y_spec = pl.BlockSpec((T, d), lambda i, j, *_: (j, 0),
-                          memory_space=pltpu.VMEM)
-    in_specs = [
-        pl.BlockSpec((Qb, d), lambda i, j, *_: (i, 0),
-                     memory_space=pltpu.VMEM),          # x
-        y_spec,                                         # y_hi
-        pl.BlockSpec((Qb, 1), lambda i, j, *_: (i, 0),
-                     memory_space=pltpu.VMEM),          # xx
-        pl.BlockSpec((1, T), lambda i, j, *_: (0, j),
-                     memory_space=pltpu.VMEM),          # yy
-    ]
-    operands = [x, y_hi, xx, yy]
-    if passes == 3:
-        in_specs.insert(2, y_spec)                      # y_lo
-        operands.insert(2, y_lo)
-    kernel = _make_kernel(_fused_kernel, passes, T, Qb,
-                          mask=mask, track=track)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(nq, n_tiles),
-        in_specs=in_specs,
-        out_specs=_slot_out_specs(Qb),
-    )
-    m1, i1, m2min = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=_slot_out_shape(Q, S),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
-        ),
-        cost_estimate=_slot_cost(Q, M, d, S, passes),
-        interpret=interpret_mode(),
-        name="fused_l2_slot_topk",
-    )(m_real, *operands)
-    return m1, i1, m2min
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("T", "Qb", "passes", "dc"))
-def fused_l2_slot_topk_dchunk(x, y_hi, y_lo, xx, yy, m_real,
-                              T: int, Qb: int, passes: int, dc: int = 256
-                              ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """d-chunked variant of :func:`fused_l2_slot_topk` for wide features
-    (d > 512): grid (nq, n_tiles, d/dc) with the score tile accumulated
-    in VMEM scratch across d-chunks (see _fused_kernel_dchunk). Same
-    contract and outputs; caller pads d to a multiple of ``dc``."""
-    _check_tiling(T, Qb)
-    Q, d = x.shape
-    M = y_hi.shape[0]
-    if d % dc:
-        raise ValueError(
-            f"fused_l2_slot_topk_dchunk: d={d} must be a multiple of "
-            f"dc={dc} (the tail would be silently dropped)")
-    n_tiles = M // T
-    nq = Q // Qb
-    n_dc = d // dc
-    S = n_tiles * _LANES
-
-    y_spec = pl.BlockSpec((T, dc), lambda i, j, l, *_: (j, l),
-                          memory_space=pltpu.VMEM)
-    in_specs = [
-        pl.BlockSpec((Qb, dc), lambda i, j, l, *_: (i, l),
-                     memory_space=pltpu.VMEM),          # x
-        y_spec,                                         # y_hi
-        pl.BlockSpec((Qb, 1), lambda i, j, *_: (i, 0),
-                     memory_space=pltpu.VMEM),          # xx
-        pl.BlockSpec((1, T), lambda i, j, *_: (0, j),
-                     memory_space=pltpu.VMEM),          # yy
-    ]
-    operands = [x, y_hi, xx, yy]
-    if passes == 3:
-        in_specs.insert(2, y_spec)                      # y_lo
-        operands.insert(2, y_lo)
-    kernel = _make_kernel(_fused_kernel_dchunk, passes, T, Qb)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(nq, n_tiles, n_dc),
-        in_specs=in_specs,
-        out_specs=_slot_out_specs(Qb),
-        scratch_shapes=[pltpu.VMEM((Qb, T), jnp.float32)],  # score acc
-    )
-    m1, i1, m2min = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=_slot_out_shape(Q, S),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
-        ),
-        cost_estimate=_slot_cost(Q, M, d, S, passes),
-        interpret=interpret_mode(),
-        name="fused_l2_slot_topk_dchunk",
-    )(m_real, *operands)
-    return m1, i1, m2min
-
-
 # --- in-kernel group fold: top-2 (+3rd-min) per (lane, tile-group) ---
 #
-# The slot kernel above writes one (min, argmin) per (tile, lane) slot —
-# [Q, n_tiles·128] outputs that an XLA group-fold then compresses.
-# MEASURED (v5e, 2048×1M×128): that fold alone costs 15.6 ms — 3× the
-# whole Pallas kernel — because XLA re-reads the ~1 GB slot arrays from
-# HBM. This variant keeps the fold INSIDE the kernel: output blocks are
+# A per-(tile, lane) slot kernel, which wrote one (min, argmin) per slot
+# — [Q, n_tiles·128] outputs that an XLA group-fold then compressed —
+# was MEASURED (v5e, 2048×1M×128) at 15.6 ms for that fold alone, 3× the
+# whole Pallas kernel, because XLA re-read the ~1 GB slot arrays from
+# HBM. These kernels keep the fold INSIDE the kernel: output blocks are
 # revisited across `tpg` CONSECUTIVE index tiles (block index j // tpg —
 # consecutive, so Mosaic keeps the block VMEM-resident and writes it to
 # HBM once per group), accumulating per-(lane, group) top-2 values+ids
 # and the group 3rd-min. Outputs shrink ~tpg/2.5× and the XLA fold
 # disappears. Keeping top-2 per group also upgrades the exactness
 # certificate: a query now only fails when THREE true top-k share a
-# (lane, group) — O(k³/S²) instead of O(k²/S) — so the fixup path runs
-# orders of magnitude more rarely.
+# (lane, group) — O(k³/S²) instead of O(k²/S) for a top-1 slot — so the
+# fixup path runs orders of magnitude more rarely.
 
 
 def _merge_chunk_top2(c, ci, a1, id1, a2, id2, a3):
@@ -1223,7 +963,7 @@ def _group_pallas_call(kernel_base, packed: bool,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=semantics,
         ),
-        cost_estimate=_slot_cost(Q, M, d, G * _LANES, passes),
+        cost_estimate=_group_cost(Q, M, d, G * _LANES, passes),
         interpret=interpret_mode(),
         name=name,
     )(m_real, *operands)
@@ -1408,7 +1148,7 @@ def _group_pallas_call_db(dbuf: bool, x, y_hi, y_lo, yy_half, m_real,
         in_specs=in_specs,
         out_specs=[out_spec] * 3,
     )
-    cost = _slot_cost(Q, M, d, n_groups * _LANES, passes)
+    cost = _group_cost(Q, M, d, n_groups * _LANES, passes)
     if q8_mode:
         # the y stream is 1 byte/element (int8), not bf16 hi(/lo)
         cost = pl.CostEstimate(

@@ -163,7 +163,7 @@ def spmv_tiled(tiled, x, eb=None) -> jax.Array:
     larger eb = fewer grid steps (less per-step overhead) at more VMEM
     per step — the one-hot intermediates are [C, eb] / [R, eb].
     Default: the whole chunk (measured best at 2M nnz on v5e: 4.9 ms at
-    eb=2048 vs 6.1 at the round-2 eb=512 — see R3_SPMV_EXP.json)."""
+    eb=2048 vs 6.1 at eb=512)."""
     n_rows, n_cols = tiled.shape
     if eb is None:
         # largest divisor of E ≤ 2048 (E is a 512-multiple, so one of

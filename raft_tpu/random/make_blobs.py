@@ -8,8 +8,8 @@ Ground truth is first-class: labels are always returned, ``cluster_std``
 may be a per-center array, ``proportions`` produces controllably
 IMBALANCED cluster sizes, and ``return_centers=True`` hands back the
 true centers — together the controllable oracle the k-means and
-IVF-recall suites (tests/test_kmeans.py, tests/test_ivf_flat.py,
-benchmarks/bench_ann.py) measure against.
+IVF-recall suites (tests/test_kmeans.py, tests/test_ivf_flat.py)
+measure against.
 """
 
 from __future__ import annotations

@@ -186,9 +186,8 @@ def degradation_count(registry=None) -> float:
 
 def degradation_reasons(registry=None) -> list:
     """The recorded ladder steps as ``"site:action ×count"`` strings —
-    the evidence a NAMED-artifact refresh prints when it REFUSES to
-    overwrite committed evidence with a degraded round (see
-    ``benchmarks/bench_ann.py``)."""
+    the evidence a measurement prints when it refuses to stand for a
+    degraded round."""
     reg = registry if registry is not None else _registry()
     out = []
     for metric in reg.collect():

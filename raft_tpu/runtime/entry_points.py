@@ -112,10 +112,8 @@ def knn_query(res, index, x, k: int, rescore: Optional[bool] = None,
     geometry happens INSIDE the compiled program — the key is the raw
     bucket shape the engine dispatches.
     """
-    from raft_tpu.distance.knn_fused import (_LANES, _POOL_PAD, KnnIndex,
-                                             _knn_fused_core,
-                                             pool_select_algo,
-                                             resolve_pool_algo)
+    from raft_tpu.distance.knn_fused import (_LANES, KnnIndex,
+                                             _knn_fused_core)
     from raft_tpu.core.error import expects
 
     res = ensure_resources(res)
@@ -148,17 +146,13 @@ def knn_query(res, index, x, k: int, rescore: Optional[bool] = None,
     if certify == "f32" and not rescore:
         raise ValueError("knn_query: certify='f32' needs the exact "
                          "rescore (store_yp=True)")
-    # pool geometry + effective selection algo, resolved per call like
-    # knn_fused's own wrapper (the non-jitted decision point)
+    # pool geometry, as knn_fused's own wrapper computes it
     n_tiles = idx.yyh_k.shape[1] // idx.T
     S_pool = -(-n_tiles // idx.g) * _LANES
     packed = idx.g * (idx.T // _LANES) <= (1 << idx.pbits)
-    pool_len = S_pool if packed else 2 * S_pool
     if k > 2 * S_pool:
         raise NotImplementedError(
             f"knn_query: k={k} too large for pool {2 * S_pool}")
-    pool_algo = resolve_pool_algo(pool_select_algo(), pool_len,
-                                  min(k + _POOL_PAD, pool_len))
     Qb_eff = min(idx.Qb, ((Q + 7) // 8) * 8)
     has_yp = idx.yp is not None
     has_ylo = idx.y_lo is not None
@@ -197,7 +191,7 @@ def knn_query(res, index, x, k: int, rescore: Optional[bool] = None,
             xq, yp, y_hi, y_lo, yyh_k, yy_raw,
             k=k, T=T_, Qb=Qb_eff, g=g_, passes=passes_, metric=metric_,
             m=m_, rescore=rescore, pbits=pbits_, certify=certify,
-            pool_algo=pool_algo, grid_order=order_, db_dtype=dtype_,
+            grid_order=order_, db_dtype=dtype_,
             with_stats=True, y_q=y_q, y_scale_k=scale_k, eq_groups=eq)
         if qpad:
             vals, ids, margin = vals[:Q], ids[:Q], margin[:Q]
@@ -206,7 +200,7 @@ def knn_query(res, index, x, k: int, rescore: Optional[bool] = None,
         return vals, ids, n_fail, margin, n_over
 
     statics = (k, T_, Qb_eff, g_, passes_, metric_, m_, bool(rescore),
-               pbits_, certify, pool_algo, order_, dtype_, has_yp,
+               pbits_, certify, order_, dtype_, has_yp,
                has_ylo, Q)
     ops = [o for o in (idx.yp,) if o is not None]
     if quant:
@@ -237,7 +231,7 @@ def knn_query(res, index, x, k: int, rescore: Optional[bool] = None,
             explain.note_margin("runtime.knn_query", margin)
             explain.note(plane="brute", db_dtype=dtype_,
                          grid_order=order_, passes=passes_,
-                         pool_algo=pool_algo, certify=certify, k=k)
+                         certify=certify, k=k)
     except Exception:
         pass
     return vals, ids

@@ -15,10 +15,9 @@ the multi-chip data plane.
 - :mod:`~raft_tpu.serving.snapshot` — immutable snapshots +
   :class:`~raft_tpu.serving.snapshot.SnapshotStore`.
 
-SLO evidence: ``benchmarks/bench_serving.py`` drives a closed-loop
-Poisson load through the engine and writes ``BENCH_SERVING.json``
-(p50/p99 latency, throughput, shed/compile-miss counts), gated by
-``tools/bench_report.py --check`` like the other artifacts.
+SLO evidence: the ``sift1m-ivf.single`` benchmark cell
+(``bench_suite/``) drives one-row requests through the engine and
+reports p50/p95 latency and throughput.
 """
 
 from raft_tpu.serving.buckets import (bucket_for, bucket_ladder,

@@ -33,13 +33,12 @@ Observability: every admitted request, flush, shed and swap emits a
 timeline.emit_serving`); queue depth is a live gauge, request latency a
 p50/p99-capable histogram, and every batch/bucket/shed transition a
 labeled counter through the MetricsRegistry — the evidence surface
-``benchmarks/bench_serving.py`` turns into the ``BENCH_SERVING.json``
-SLO artifact. Each dispatched request's queue wait (enqueue → batch
-pop) is a histogram sample (``raft_tpu_serving_queue_wait_seconds``);
-on the batcher thread the ``serving.flush_wait`` span times the wait
-for co-riders, and ``serving.device_wait`` (inside
-``serving.execute_batch``) the wait for the device and the copy of the
-answers to the host.
+the served benchmark cell (``bench_suite/``) reads. Each dispatched
+request's queue wait (enqueue → batch pop) is a histogram sample
+(``raft_tpu_serving_queue_wait_seconds``); on the batcher thread the
+``serving.flush_wait`` span times the wait for co-riders, and
+``serving.device_wait`` (inside ``serving.execute_batch``) the wait for
+the device and the copy of the answers to the host.
 
 Quality plane (ISSUE 10):
 

@@ -1,8 +1,8 @@
 """Persistent sparse tile-plan cache.
 
 The tiled-ELL / pair layouts (:mod:`raft_tpu.sparse.tiled`) are one-time
-HOST conversions — 62.7 s cold / 39.8 s for the pairs layout at the
-R3_SPECTRAL_PROFILE 2M-nnz scale — that previously amortized only
+HOST conversions — 62.7 s cold / 39.8 s for the pairs layout at a
+2M-nnz scale — that previously amortized only
 within one process. This module persists prepared plans to disk, keyed
 by a SPARSITY-STRUCTURE fingerprint (shape + tiling params + a digest
 of the row/col id streams), so a spectral job restarted tomorrow pays a

@@ -250,8 +250,8 @@ def tile_pairs(structure, R: int = 256, C: int = 512,
     (tested).
 
     Plans for large structures persist ACROSS PROCESSES through
-    :mod:`raft_tpu.sparse.plan_cache` (the 39.8 s pairs prepare at the
-    SPMV_BENCH 2M-nnz scale becomes a ~ms ``np.load`` on the second
+    :mod:`raft_tpu.sparse.plan_cache` (the 39.8 s pairs prepare at a
+    2M-nnz scale becomes a ~ms ``np.load`` on the second
     process), keyed purely by the sparsity structure — the pair layout
     carries no values."""
     if impl not in ("auto", "numpy"):

@@ -49,8 +49,7 @@ class IdentityBinner:
 
 
 # dense strategies hold an [n_bins, chunk-or-SUB, batch] one-hot temp;
-# past this bin count the scatter path wins (measured envelope, see
-# benchmarks/bench_prims.py histogram rows)
+# past this bin count the scatter path wins (measured envelope)
 _DENSE_MAX_BINS = 1024
 # Blocked kernel VMEM budget for the one-hot temp + accumulator + input
 # block; past it Mosaic would fail to fit the kernel

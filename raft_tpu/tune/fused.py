@@ -96,7 +96,7 @@ def _reset_degraded_warnings() -> None:
     """Test hook: re-arm the once-per-process WARN."""
     _degraded_warned.clear()
 
-# the driver benchmark shape (bench.py / BASELINE config 2, one-chip)
+# the exact-KNN benchmark shape (BASELINE config 2, one-chip)
 DRIVER_SHAPE = (2048, 1_000_000, 128, 64)
 
 _GRID_AXES = {
@@ -320,9 +320,9 @@ def autotune_fused(res=None, shape: Sequence[int] = DRIVER_SHAPE,
 
     ``measure=None`` auto-selects: real timing on TPU, the
     deterministic model-ranked fallback elsewhere. Measured mode builds
-    the index ONCE per candidate (steady-state query throughput, the
-    bench.py metric), times through ``benchmark.Fixture`` (cost capture
-    + roofline fields ride along via ``res.profiler``), honors the
+    the index ONCE per candidate (steady-state query throughput), times
+    through ``benchmark.Fixture`` (cost capture + roofline fields ride
+    along via ``res.profiler``), honors the
     ``budget_s`` deadline between points, and writes incrementally so a
     killed sweep loses one point. Returns the table (also written to
     ``out_path`` unless None)."""

@@ -48,7 +48,7 @@ from raft_tpu.tune.fused import (TUNE_SCHEMA_VERSION, provenance,
                                  table_degraded, validate_tune_table,
                                  write_tune_table)
 
-# the north-star workload (BENCH_NORTHSTAR.json) — the shape that is at
+# the north-star workload — the shape that is at
 # the one-chip capacity wall and exists to be sharded
 NORTHSTAR_SHAPE = (2048, 10_000_000, 256, 64)
 

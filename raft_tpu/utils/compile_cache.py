@@ -1,8 +1,9 @@
 """Where the entry scripts keep JAX's persistent compilation cache.
 
-Called by ``chip_smoke.py``, ``bench.py`` and the ``benchmarks/`` entry
-scripts before their first compile — never at library import, so a
-program that embeds raft_tpu keeps whatever cache policy it set.
+Called by ``chip_smoke.py``, ``bench_suite/run.py`` and the
+``benchmarks/`` entry scripts before their first compile — never at
+library import, so a program that embeds raft_tpu keeps whatever cache
+policy it set.
 """
 
 from __future__ import annotations

@@ -255,7 +255,7 @@ def test_traffic_model_stream_once():
 def test_fixture_run_merges_model():
     """benchmark.Fixture.run(model=...) lands the analytic prediction
     next to the measurement under model_* keys — the BENCH-artifact
-    contract bench.py and the tuner rely on."""
+    contract the tuner relies on."""
     from raft_tpu.benchmark import Fixture
 
     fx = Fixture(reps=1)

@@ -301,16 +301,6 @@ def test_vmem_footprint_guard():
     from raft_tpu.ops.fused_l2_topk_pallas import (
         VMEM_BUDGET, vmem_footprint)
 
-    # slot kernel: measured rejections (tune sweep + driver bench, v5e)
-    assert vmem_footprint(2048, 1024, 128, passes=3,
-                          kernel="slot") > VMEM_BUDGET
-    assert vmem_footprint(4096, 512, 128, passes=3,
-                          kernel="slot") > VMEM_BUDGET
-    # slot kernel: measured compiles
-    assert vmem_footprint(2048, 1024, 128, passes=1,
-                          kernel="slot") <= VMEM_BUDGET
-    assert vmem_footprint(2048, 512, 128, passes=3,
-                          kernel="slot") <= VMEM_BUDGET
     # group kernel (the production default): big-tile p3 prunes to a
     # smaller Qb; the post-mask-removal p1 point fits
     assert vmem_footprint(2048, 512, 128, passes=1) <= VMEM_BUDGET
@@ -623,52 +613,6 @@ def test_lite_index_no_rescore():
     idx1 = prepare_knn_index(y, passes=1, store_yp=False, T=512, Qb=64, g=8)
     with pytest.raises(ValueError):
         knn_fused(x, idx1, k, rescore=True)
-
-
-def test_pool_select_routings_agree(monkeypatch):
-    # RAFT_TPU_POOL_SELECT routes the twin-pool selection through the
-    # repo's exact selection algorithms; results must be identical to
-    # the XLA routing (exactness is what keeps the certificate sound),
-    # and the algo must be threaded as a STATIC arg (a fresh trace per
-    # routing — the jit cache must not serve the first-traced algo)
-    import jax.numpy as jnp
-
-    from raft_tpu.distance.knn_fused import (_pool_smallest,
-                                             pool_select_algo,
-                                             prepare_knn_index,
-                                             resolve_pool_algo)
-
-    rng = np.random.default_rng(5)
-    a = jnp.asarray(rng.standard_normal((32, 512)).astype(np.float32))
-    ref_v, _ = _pool_smallest(a, 48, "xla")
-    for algo in ("two_stage", "slotted", "chunked"):
-        # the wrapper resolves the shape envelope BEFORE the jitted core
-        # (slotted's short-row pool caps below 48 here → downgrade to
-        # xla, decided and logged per call, not at trace time)
-        eff = resolve_pool_algo(algo, a.shape[1], 48)
-        v, p = _pool_smallest(a, 48, eff)
-        np.testing.assert_array_equal(np.asarray(ref_v), np.asarray(v))
-        np.testing.assert_array_equal(
-            np.take_along_axis(np.asarray(a), np.asarray(p), 1),
-            np.asarray(v))
-    assert resolve_pool_algo("slotted", 512, 48) == "xla"
-    assert resolve_pool_algo("two_stage", 512, 48) == "two_stage"
-    assert resolve_pool_algo("chunked", 4, 2) == "xla"  # len < 2·nc
-    monkeypatch.setenv("RAFT_TPU_POOL_SELECT", "two_stage")
-    assert pool_select_algo() == "two_stage"
-    monkeypatch.setenv("RAFT_TPU_POOL_SELECT", "bogus")
-    assert pool_select_algo() == "xla"
-
-    # end-to-end through the public wrapper under a non-default routing
-    y = rng.standard_normal((3000, 32)).astype(np.float32)
-    x = y[:64]
-    idx = prepare_knn_index(jnp.asarray(y), passes=3, T=512, Qb=64, g=8)
-    monkeypatch.setenv("RAFT_TPU_POOL_SELECT", "chunked")
-    vals, ids = knn_fused(jnp.asarray(x), idx, 8)
-    _, ref_ids, _ = _oracle(x, y, 8)
-    recall = np.mean([len(set(np.asarray(ids)[i]) & set(ref_ids[i])) / 8
-                      for i in range(64)])
-    assert recall >= 0.999
 
 
 # ------------------------------------------------------------------

@@ -436,24 +436,6 @@ def test_mutable_plane_under_rotated_mode(res, monkeypatch):
     assert not (set(victims) & survivors)
 
 
-# ------------------------------------------------- gate constant mirror
-def test_bench_report_rerun_ceiling_pinned():
-    """tools/bench_report stays raft_tpu-import-free, so its diffuse
-    rerun ceiling is pinned against the bench writer's."""
-    import importlib.util
-    import os
-
-    import tools.bench_report as br
-
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    spec = importlib.util.spec_from_file_location(
-        "_bench_ann_pin19", os.path.join(root, "benchmarks",
-                                         "bench_ann.py"))
-    ba = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(ba)
-    assert br.PQ_RERUN_CEIL == ba.PQ_RERUN_CEIL
-
-
 def test_check_ann_diffuse_rerun_gates(tmp_path):
     """The diffuse-rerun gate: ceiling violation REGRESSES, a >0.05
     absolute rise vs the previous comparable round REGRESSES, and

@@ -602,17 +602,6 @@ def test_check_quantized_pq_tier_gate_matrix():
             "pq": {"ok": True, "pq_bytes_ratio": 0.05}}
     s, msg = br.check_quantized([("ann", both)])
     assert s == br.PASS and "pq=0.0500" in msg
-    # the ceiling constant is pinned against the bench writer's
-    import importlib.util
-    import os
-
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    spec = importlib.util.spec_from_file_location(
-        "_bench_ann_pin", os.path.join(root, "benchmarks",
-                                       "bench_ann.py"))
-    ba = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(ba)
-    assert br.PQ_RATIO_CEIL == ba.PQ_RATIO_CEIL
 
 
 def test_committed_artifacts_carry_quantized_blocks():

@@ -76,15 +76,22 @@ def _compile(fn, *args, **static):
 
 
 @pytest.mark.parametrize("T,Qb", [(512, 128), (1024, 256), (2048, 256)])
-def test_fused_l2_slot_topk_compiles(chip, T, Qb):
-    from raft_tpu.ops.fused_l2_topk_pallas import fused_l2_slot_topk
+def test_fused_l2_group_topk_packed_tiles_compile(chip, T, Qb):
+    """The packed streamed kernel the exact path runs, at tiles other
+    than the pipeline test's: 8 tiles a group keeps every (T, Qb) here
+    inside the 8-bit packing envelope (8 · T/128 ≤ 256)."""
+    from raft_tpu.ops.fused_l2_topk_pallas import fused_l2_group_topk_packed
+
+    def run(x, y_hi, y_lo, yy_half, m_real, xxh):
+        return fused_l2_group_topk_packed(x, y_hi, y_lo, yy_half, m_real,
+                                          T=T, Qb=Qb, passes=3, tpg=8,
+                                          stream=True, xxh=xxh)
 
     s = functools.partial(_spec, chip)
-    _compile(fused_l2_slot_topk,
-             s((N_QUERIES, DIM), jnp.float32),
+    _compile(run, s((N_QUERIES, DIM), jnp.float32),
              s((N_ROWS, DIM), jnp.bfloat16), s((N_ROWS, DIM), jnp.bfloat16),
-             s((N_QUERIES, 1), jnp.float32), s((1, N_ROWS), jnp.float32),
-             s((1,), jnp.int32), T=T, Qb=Qb, passes=3)
+             s((8, N_ROWS), jnp.float32), s((1,), jnp.int32),
+             s((N_QUERIES, 1), jnp.float32))
 
 
 def test_knn_fused_selected_pipeline_compiles(chip):

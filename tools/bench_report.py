@@ -1,9 +1,9 @@
 #!/usr/bin/env python
 """Perf-evidence pipeline: BENCH_*.json → one trajectory + CI gate.
 
-The repo accumulates one ``BENCH_r<NN>.json`` per measurement round (the
-driver wraps ``bench.py``'s one-line JSON in ``{"n", "cmd", "rc",
-"tail", "parsed"}``) plus ``BENCH_LAST_GOOD.json`` — the last known-good
+The repo accumulates one ``BENCH_r<NN>.json`` per measurement round (each
+wraps the benchmark's one-line JSON in ``{"n", "cmd", "rc", "tail",
+"parsed"}``) plus ``BENCH_LAST_GOOD.json`` — the last known-good
 flat record. This tool turns that pile of disconnected artifacts into:
 
 1. a **trajectory report** (default): per-round series of the headline
@@ -108,17 +108,14 @@ QUANTIZED_RATIO_CEIL = 0.55
 #: PQ-tier gate (the quantized gate extended to product quantization):
 #: the modeled codes-slab stream must be ≤ this fraction of the f32
 #: slab stream (1/16 at 8-bit codes with pq_dim = d/4, 1/32 at 4-bit)
-#: AND the id-parity-after-rescore flag must hold. Mirror of
-#: benchmarks/bench_ann.PQ_RATIO_CEIL (this tool stays
-#: raft_tpu-import-free); tests pin the two equal.
+#: AND the id-parity-after-rescore flag must hold.
 PQ_RATIO_CEIL = 0.10
 
 #: PQ certificate-rerun gate (ISSUE 19): on the diffuse-Gaussian
 #: (worst-case) benchmark distribution the certificate's exact-rerun
 #: fraction at the recall floor must be ≤ this ceiling, and must not
 #: rise more than ``PQ_RERUN_SLACK`` absolute vs the previous
-#: comparable round. Mirror of benchmarks/bench_ann.PQ_RERUN_CEIL
-#: (this tool stays raft_tpu-import-free); tests pin the two equal.
+#: comparable round.
 PQ_RERUN_CEIL = 0.10
 PQ_RERUN_SLACK = 0.05
 
@@ -301,7 +298,7 @@ def check_multichip(rounds: Sequence[Tuple[int, str, Optional[Dict]]],
 
 
 def load_serving(path: str) -> Optional[Dict]:
-    """Flat serving-SLO record (benchmarks/bench_serving.py): unwraps
+    """Flat serving-SLO record (``BENCH_SERVING.json``): unwraps
     the driver's envelope like :func:`load_multichip`. A record must
     carry at least an ``ok`` verdict or a latency/throughput field to
     count."""
@@ -461,7 +458,7 @@ def serving_trajectory(rounds: Sequence[Tuple[int, str,
 
 
 def load_ann(path: str) -> Optional[Dict]:
-    """Flat ANN speed/recall frontier record (benchmarks/bench_ann.py):
+    """Flat ANN speed/recall frontier record (``BENCH_ANN.json``):
     unwraps the driver's envelope like :func:`load_serving`. A record
     must carry an ``ok`` verdict or a frontier to count."""
     try:
@@ -620,9 +617,7 @@ def check_ann(rounds: Sequence[Tuple[int, str, Optional[Dict]]],
                 + (f" ({rd:g} resilience degradation step(s))"
                    if isinstance(rd, (int, float)) and rd > 0 else "")
                 + " — committed baseline evidence must never be an "
-                  "outage round; regenerate it clean "
-                  "(benchmarks/bench_ann.py refuses degraded "
-                  "overwrites)")
+                  "outage round; regenerate it clean")
         return SKIP, (
             f"latest ANN round is degraded"
             + (f" ({rd:g} degradation step(s))"
@@ -765,7 +760,7 @@ def ann_trajectory(rounds: Sequence[Tuple[int, str,
 
 
 def load_mutation(path: str) -> Optional[Dict]:
-    """Flat mixed read/write record (benchmarks/bench_mutation.py):
+    """Flat mixed read/write record (``BENCH_MUTATION.json``):
     unwraps the driver's envelope like :func:`load_serving`. A record
     must carry an ``ok`` verdict, a recall, or a compaction count to
     count."""
@@ -930,7 +925,7 @@ def mutation_trajectory(rounds: Sequence[Tuple[int, str,
 
 
 def load_recovery(path: str) -> Optional[Dict]:
-    """Flat durability/recovery record (benchmarks/bench_recovery.py):
+    """Flat durability/recovery record (``BENCH_RECOVERY.json``):
     unwraps the driver's envelope like :func:`load_serving`. A record
     must carry an ``ok`` verdict, the zero-acked-loss flag, or a
     recovery time to count."""
@@ -1404,7 +1399,7 @@ def multichip_trajectory(rounds: Sequence[Tuple[int, str,
                                                 Optional[Dict]]]) -> str:
     """Multichip series: dryrun verdicts for the bare early rounds,
     sharded-KNN throughput + best busbw fraction once artifacts carry
-    them (benchmarks/bench_sharded.py)."""
+    them."""
     lines = ["multichip trajectory (MULTICHIP_r*.json)",
              "========================================="]
     if not rounds:
@@ -1444,8 +1439,8 @@ def check_quantized(records: Sequence[Tuple[str, Optional[Dict]]],
     (id-parity int8-vs-f32 held) and its modeled bytes ratio
     (``quantized_y_ratio`` for the fused stream,
     ``quantized_gather_ratio`` for the IVF probe gather) ≤ ``ceil``.
-    Records carrying a ``"pq"`` block (the IVF-PQ compressed tier —
-    benchmarks/bench_ann.py) are additionally gated at the much
+    Records carrying a ``"pq"`` block (the IVF-PQ compressed tier of
+    ``BENCH_ANN.json``) are additionally gated at the much
     tighter :data:`PQ_RATIO_CEIL`: ``pq_bytes_ratio`` ≤ 0.10× of the
     f32 slab stream AND the id-parity-after-rescore ``ok`` flag —
     AND-ed into the same verdict. Families without the block are
@@ -1599,7 +1594,7 @@ def check_slo(rounds: Sequence[Tuple[int, str, Optional[Dict]]],
     if not isinstance(slo, dict):
         return MISSING_BASELINE, (
             "latest serving round carries no slo block — regenerate "
-            "BENCH_SERVING.json (benchmarks/bench_serving.py)")
+            "BENCH_SERVING.json")
     if not newest.get("ok", True):
         return SKIP, ("latest serving round failed (ok=false) — the "
                       "[serving] gate owns that regression")
@@ -1666,8 +1661,7 @@ def check_blackbox(rounds: Sequence[Tuple[int, str, Optional[Dict]]],
     if not isinstance(bb, dict):
         return MISSING_BASELINE, (
             "latest serving round carries no blackbox block — "
-            "regenerate BENCH_SERVING.json "
-            "(benchmarks/bench_serving.py)")
+            "regenerate BENCH_SERVING.json")
     if not newest.get("ok", True):
         return SKIP, ("latest serving round failed (ok=false) — the "
                       "[serving] gate owns that regression")
@@ -1755,9 +1749,9 @@ def main(argv: Sequence[str] = None) -> int:
         print(f"bench_report --check [mutation]: {mustatus}: {mumsg}")
         rstatus, rmsg = check_recovery(rrounds, args.threshold)
         print(f"bench_report --check [recovery]: {rstatus}: {rmsg}")
-        # multichip: the bare benchmark artifact (written by
-        # benchmarks/bench_sharded.py) is the freshest carrier of the
-        # quantized block — driver rounds lag it by one round
+        # multichip: the bare benchmark artifact MULTICHIP_SHARDED.json
+        # is the freshest carrier of the quantized block — the numbered
+        # rounds lag it by one round
         newest_m = load_multichip(
             os.path.join(args.dir, "MULTICHIP_SHARDED.json"))
         if newest_m is None:

@@ -20,8 +20,8 @@ operator reads FIRST when paged:
   the last thing that happened before you looked;
 - the full registry summary table for everything else.
 
-Import :func:`render_statusz` inside a serving process (tests and
-``benchmarks/bench_serving.py`` do), or run ``python tools/statusz.py
+Import :func:`render_statusz` inside a serving process (the tests
+do), or run ``python tools/statusz.py
 --demo`` for a self-contained deterministic serving round followed by
 its own snapshot — the zero-to-evidence smoke an operator can run on
 any checkout without a TPU.
