@@ -52,12 +52,15 @@ events (probed-rows fraction rides the search event), and the fine
 scan's XLA cost is captured through ``res.profiler.capture_fn`` once
 per bucket shape at warm-up (:func:`warm_fine_scan`). Each host
 boundary of a search is a span nested under ``ann.search_ivf_flat``:
-``ann.coarse_probe``, ``ann.probe_fetch`` (the probe table's wait and
-copy to the host), ``ann.fine_scan_plan`` (schedule resolution and the
-per-chunk list schedule), ``ann.fine_scan`` (one per chunk's scan
-dispatch), ``ann.certificate_sync`` (the per-chunk rerun decision's
-host sync) and ``ann.fine_scan_rerun``. The probe table the plan
-already holds counts :data:`PROBED_ROWS` with no device work.
+``ann.coarse_probe``, ``ann.fine_scan_plan`` (the list-major chunk and
+the schedule resolution), ``ann.fine_scan`` (one per chunk's scan
+dispatch — on the list-major path one program that builds the list
+schedule on the device from the probe table and scans),
+``ann.certificate_sync`` (the per-chunk rerun decision's host sync)
+and ``ann.fine_scan_rerun``; ``ann.probe_fetch`` (the probe table's
+wait and copy to the host) only where the host needs the table
+(:data:`PROBE_FETCH`). :data:`PROBED_ROWS` rides the list-major
+certificate's host copy, or the fetched table, with no sync of its own.
 """
 
 from __future__ import annotations
@@ -82,8 +85,15 @@ from raft_tpu.resilience import fault_point
 from raft_tpu.resilience.policy import record_degradation
 
 #: database rows the fine scan's probe tables name (the sum of the
-#: probed lists' real sizes), counted on the host
+#: probed lists' real sizes): the list-major program's own sum, read
+#: with its certificate; the host probe table's on the query-major path
 PROBED_ROWS = "raft_tpu_ivf_probed_rows_total"
+
+#: searches whose probe table came to the host, by ``reason``:
+#: ``explain`` (explain capture), ``query`` (the probed-rows count of a
+#: search resolved query-major), ``degrade`` (the same after a
+#: list-major dispatch degraded)
+PROBE_FETCH = "raft_tpu_ivf_probe_fetch_total"
 
 #: inverted-list row quantum: every list pads to a multiple of this
 #: (the fused pipeline's 8-row sublane multiple — a slab built at this
@@ -195,6 +205,9 @@ class IvfFlatIndex:
         # lazy per-list host/device geometry for the list-major fine
         # scan (per-list scale + Eq + max row norms)
         self._list_host = None
+        # the fine-scan cost model's pick per call shape (nq, k, P, W):
+        # a pure function of this frozen geometry, priced once per shape
+        self._fine_scan_picks = {}
 
     @property
     def n_lists(self) -> int:
@@ -437,29 +450,23 @@ def _fine_scan_q8(x, slab, slab_q, row_scale, ids, yy_q, starts, psizes,
 # completeness certificate reruns any uncovered query query-major.)
 
 class _ListSchedule:
-    """Host-built list-major schedule for one query chunk: the
+    """Host-built list-major schedule for one query chunk (IVF-PQ's
+    schedule, and the oracle of :func:`_device_list_schedule`): the
     transposed probe table. ``sched [4, Lp]`` int32 rows are (clamped
     window start, real rows in the window, their offset within the
     window, list id) — one entry per kernel window of a probed list;
     Lp is padded to the 8-entry cell quantum with the cell count
     rounded to a power of two (capped at the index's own cell count),
-    so one compiled program serves a whole probes sweep. The
-    [L_probed, q_max] query-group table (q_max padded to 8) + its
-    never-wins mask ride along for the cost model and tests — the
-    kernel itself consumes the resident probe table directly."""
+    so one compiled program serves a whole probes sweep. The kernel
+    consumes the resident probe table directly for the queries of each
+    list."""
 
-    __slots__ = ("sched", "scale_l", "n_lists_probed", "q_max",
-                 "group", "group_mask", "stream_rows")
+    __slots__ = ("sched", "scale_l", "n_lists_probed")
 
-    def __init__(self, sched, scale_l, n_lists_probed, q_max, group,
-                 group_mask, stream_rows):
+    def __init__(self, sched, scale_l, n_lists_probed):
         self.sched = sched
         self.scale_l = scale_l
         self.n_lists_probed = n_lists_probed
-        self.q_max = q_max
-        self.group = group
-        self.group_mask = group_mask
-        self.stream_rows = stream_rows
 
 
 def _list_cells(n_entries: int, max_entries: int) -> int:
@@ -492,9 +499,11 @@ def _max_entries(index: IvfFlatIndex,
 
 def build_list_schedule(index: IvfFlatIndex, probes_np) -> _ListSchedule:
     """Invert a chunk's per-query probe lists [nq, P] into the
-    per-list query-group schedule (see :class:`_ListSchedule`); a list
+    per-list window schedule (see :class:`_ListSchedule`); a list
     longer than the kernel window takes one entry per window of rows.
-    Host-side numpy — the probe table is tiny next to the slab."""
+    Host-side numpy: IVF-PQ's schedule builder, and the oracle the
+    served IVF-Flat search's device builder
+    (:func:`_device_list_schedule`) is tested against."""
     from raft_tpu.ops.fine_scan_pallas import (LISTS_PER_CELL,
                                                pad_window)
 
@@ -520,31 +529,15 @@ def build_list_schedule(index: IvfFlatIndex, probes_np) -> _ListSchedule:
     scale_l = np.ones(Lp_pad, np.float32)
     if index.db_dtype == "int8":
         scale_l[:n_ent] = _list_host(index)["scale"][lid]
-    # the transposed [L_probed, q_max] query-group table: group g holds
-    # the query indices probing plist[g] in query order, padded to the
-    # 8-row quantum with the never-wins mask marking real entries
-    qi, pj = np.nonzero(probes_np >= 0)          # (query, slot) order
-    grp = np.searchsorted(plist, probes_np[qi, pj])
-    order = np.argsort(grp, kind="stable")
-    grp, qi = grp[order], qi[order]
-    counts = np.bincount(grp, minlength=Lp)
-    q_max = -(-int(counts.max(initial=1)) // 8) * 8
-    group = np.zeros((max(Lp, 1), q_max), np.int32)
-    gmask = np.zeros((max(Lp, 1), q_max), bool)
-    col = np.arange(grp.size) - np.repeat(np.cumsum(counts) - counts,
-                                          counts)
-    group[grp, col] = qi
-    gmask[grp, col] = True
-    stream_rows = int(index._np_padded[plist].sum())
-    return _ListSchedule(sched, scale_l, Lp, int(q_max), group, gmask,
-                         stream_rows)
+    return _ListSchedule(sched, scale_l, Lp)
 
 
 def _list_host(index: IvfFlatIndex) -> dict:
-    """Lazy per-list host geometry for the list-major path: the
-    symmetric int8 scale, the Eq quantization bound and the max
-    (dequantized) row norm of each list — certificate inputs gathered
-    per probe at search time. Computed once per index."""
+    """Lazy per-list geometry for the list-major path: the symmetric
+    int8 scale (host ``scale`` and device ``scale_l``), the Eq
+    quantization bound and the max (dequantized) row norm of each list
+    — schedule and certificate inputs gathered per probe at search
+    time. Computed once per index."""
     if index._list_host is not None:
         return index._list_host
     offs = index._np_offsets
@@ -568,6 +561,7 @@ def _list_host(index: IvfFlatIndex) -> dict:
                 scale_list[l] = scale[int(offs[l])]
                 eq_list[l] = eq[int(offs[l])]
         host["scale"] = scale_list
+        host["scale_l"] = jnp.asarray(scale_list)
         host["eq_list"] = jnp.asarray(eq_list)
     index._list_host = host
     return host
@@ -582,10 +576,12 @@ def _schedule_len(index: IvfFlatIndex) -> int:
 
 
 def _kernel_schedule(index: IvfFlatIndex, sched: _ListSchedule):
-    """One chunk's list-major kernel operands: the schedule (and the
-    int8 scales) padded to :func:`_schedule_len`, so every chunk of one
-    row count runs one compiled program, and the grid cells that hold
-    the chunk's entries — all the kernel streams."""
+    """One chunk's list-major kernel operands from a host schedule: the
+    schedule (and the int8 scales) padded to :func:`_schedule_len`, so
+    every chunk of one row count runs one compiled program, and the
+    grid cells that hold the chunk's entries — all the kernel streams.
+    The host form of :func:`_device_list_schedule`, kept as its
+    oracle."""
     from raft_tpu.ops.fine_scan_pallas import LISTS_PER_CELL
 
     n = _schedule_len(index)
@@ -597,6 +593,56 @@ def _kernel_schedule(index: IvfFlatIndex, sched: _ListSchedule):
     scale[:Lp] = sched.scale_l
     n_ent = int(np.count_nonzero(sched.sched[3] >= 0))
     return full, scale, max(1, -(-n_ent // LISTS_PER_CELL))
+
+
+def _device_list_schedule(probes, sizes, offsets, Wk: int, R: int,
+                          n: int, scale_list=None):
+    """One chunk's list-major kernel operands built on the device from
+    its probe table [nq, P] (traceable; ``Wk``, the slab rows ``R`` and
+    the schedule length ``n`` = :func:`_schedule_len` are static):
+    ``sched [4, n]`` int32 and the int32 count of grid cells holding
+    its entries, and, given the per-list int8 scales ``scale_list``
+    [L], each entry's scale ``[n]`` (1 on pads) — entry for entry what
+    :func:`_kernel_schedule` makes of :func:`build_list_schedule`.
+
+    The sorted probe ids, first of each run, are the probed lists in
+    ascending id order (pad probes < 0 name no list). A list takes one
+    entry per kernel window of its rows (an empty list one); each of
+    the ``n`` entry slots finds its list by a search over the running
+    entry count, and slots past the last entry are pads
+    ``(0, 0, 0, -1)``."""
+    from raft_tpu.ops.fine_scan_pallas import LISTS_PER_CELL
+
+    flat = jnp.sort(probes.reshape(-1).astype(jnp.int32))
+    first = (flat >= 0) & jnp.concatenate(
+        [jnp.ones((1,), bool), flat[1:] != flat[:-1]])
+    lists = jnp.maximum(flat, 0)
+    sizes = sizes.astype(jnp.int32)
+    nseg = jnp.where(first, jnp.maximum(
+        1, (jnp.take(sizes, lists) + Wk - 1) // Wk), 0)
+    ends = jnp.cumsum(nseg)                # entries through each run
+    n_ent = ends[-1]
+    slot = jnp.arange(n, dtype=jnp.int32)
+    # one compare-and-count fusion: the default binary search is a
+    # device while loop of log2(nq·P) dependent steps
+    run = jnp.minimum(jnp.searchsorted(ends, slot, side="right",
+                                       method="compare_all"),
+                      flat.shape[0] - 1)
+    lid = jnp.take(lists, run)
+    seg = slot - (jnp.take(ends, run) - jnp.take(nseg, run))
+    starts = jnp.take(offsets.astype(jnp.int32), lid) + seg * Wk
+    clamped = jnp.maximum(jnp.minimum(starts, R - Wk), 0)
+    rows = jnp.clip(jnp.take(sizes, lid) - seg * Wk, 0, Wk)
+    real = slot < n_ent
+    sched = jnp.where(real[None],
+                      jnp.stack([clamped, rows, starts - clamped, lid]),
+                      jnp.array([0, 0, 0, -1], jnp.int32)[:, None])
+    n_cells = jnp.maximum(1, (n_ent + LISTS_PER_CELL - 1)
+                          // LISTS_PER_CELL)
+    if scale_list is None:
+        return sched, n_cells
+    return sched, n_cells, jnp.where(real, jnp.take(scale_list, lid),
+                                     jnp.float32(1.0))
 
 
 def _pool_finish(x, xx, rows, slab, ids, yy_slab, starts_qm, psizes,
@@ -663,16 +709,17 @@ def _probe_windows(offsets, padded_sizes, probes):
     return jnp.take(offsets, probes), jnp.take(padded_sizes, probes)
 
 
-@partial(jax.jit, static_argnames=("k", "P", "W", "Wk"))
-def _fine_scan_list(x, sched, n_cells, probes, slab, ids, yy_slab,
-                    offsets, padded_sizes, yy_lmax, k: int, P: int,
-                    W: int, Wk: int):
-    """List-major fine scan over the f32 slab (see the block comment):
-    kernel pools → exact rescore + canonical reorder → certificate.
-    Returns (vals, ids, certified, margin) like :func:`_fine_scan_q8`
-    — the caller reruns failed queries query-major, so ids never
-    drift. The query-major windows the reorder needs come from the
-    index's ``offsets`` / ``padded_sizes`` here, on the device."""
+def _list_major_scan(x, sched, n_cells, probes, slab, ids, yy_slab,
+                     offsets, padded_sizes, yy_lmax, k: int, P: int,
+                     W: int, Wk: int):
+    """List-major fine scan over the f32 slab (see the block comment)
+    for a given kernel schedule: kernel pools → exact rescore +
+    canonical reorder → certificate. Returns (vals, ids, certified,
+    margin) like :func:`_fine_scan_q8` — the caller reruns failed
+    queries query-major, so ids never drift. The query-major windows
+    the reorder needs come from the index's ``offsets`` /
+    ``padded_sizes`` here, on the device. Traced inside
+    :func:`_fine_scan_list`."""
     from raft_tpu.ops.fine_scan_pallas import fine_scan_list_major
 
     nq, d = x.shape
@@ -697,16 +744,16 @@ def _fine_scan_list(x, sched, n_cells, probes, slab, ids, yy_slab,
     return vals, out_ids, certified, bound - (theta + widen)
 
 
-@partial(jax.jit, static_argnames=("k", "P", "W", "Wk"))
-def _fine_scan_list_q8(x, sched, scale_l, n_cells, probes, slab_q, slab,
-                       ids, yy_slab, yy_lmax, eq_list, offsets,
-                       padded_sizes, k: int, P: int, W: int, Wk: int):
-    """INT8 list-major fine scan: streams the quantized slab (~¼ the
-    probed bytes) through :func:`ops.fine_scan_pallas.
-    fine_scan_list_major_q8` with per-list dequant-in-register scales,
-    then the same exact-rescore/reorder/certificate pipeline — the
-    certificate additionally widens by the probed lists' recorded Eq
-    bound exactly like the query-major :func:`_fine_scan_q8`."""
+def _list_major_scan_q8(x, sched, scale_l, n_cells, probes, slab_q,
+                        slab, ids, yy_slab, yy_lmax, eq_list, offsets,
+                        padded_sizes, k: int, P: int, W: int, Wk: int):
+    """INT8 list-major fine scan for a given kernel schedule: streams
+    the quantized slab (~¼ the probed bytes) through
+    :func:`ops.fine_scan_pallas.fine_scan_list_major_q8` with per-list
+    dequant-in-register scales, then the same exact-rescore/reorder/
+    certificate pipeline — the certificate additionally widens by the
+    probed lists' recorded Eq bound exactly like the query-major
+    :func:`_fine_scan_q8`. Traced inside :func:`_fine_scan_list_q8`."""
     from raft_tpu.ops.fine_scan_pallas import fine_scan_list_major_q8
 
     nq, d = x.shape
@@ -730,6 +777,51 @@ def _fine_scan_list_q8(x, sched, scale_l, n_cells, probes, slab_q, slab,
     widen = 2.0 * sq_t * eq_w + eq_w * eq_w + e_k
     certified = _kernel_envelope(bound, theta, widen)
     return vals, out_ids, certified, bound - (theta + widen)
+
+
+def _chunk_stats(probes, sizes, n_cells):
+    """int32 [nq + 1]: each query row's probed rows (the sum of its
+    probed lists' real sizes — :data:`PROBED_ROWS`, summed on the host
+    in 64 bits), then the grid cells the chunk's kernel streamed."""
+    rows = jnp.where(probes >= 0,
+                     jnp.take(sizes, jnp.maximum(probes, 0)), 0)
+    return jnp.concatenate([jnp.sum(rows, axis=1, dtype=jnp.int32),
+                            n_cells[None]])
+
+
+@partial(jax.jit, static_argnames=("k", "P", "W", "Wk", "n"))
+def _fine_scan_list(x, probes, slab, ids, yy_slab, offsets, sizes,
+                    padded_sizes, yy_lmax, k: int, P: int, W: int,
+                    Wk: int, n: int):
+    """One chunk's list-major program over the f32 slab: the kernel
+    schedule built on the device from the chunk's probe table
+    (:func:`_device_list_schedule`, ``n`` = :func:`_schedule_len`
+    entries), then :func:`_list_major_scan`. Returns (vals, ids,
+    certified, margin, stats) — ``stats`` as :func:`_chunk_stats`,
+    brought to the host with the certificate."""
+    sched, n_cells = _device_list_schedule(probes, sizes, offsets, Wk,
+                                           slab.shape[0], n)
+    out = _list_major_scan(x, sched, n_cells, probes, slab, ids,
+                           yy_slab, offsets, padded_sizes, yy_lmax,
+                           k, P, W, Wk)
+    return out + (_chunk_stats(probes, sizes, n_cells),)
+
+
+@partial(jax.jit, static_argnames=("k", "P", "W", "Wk", "n"))
+def _fine_scan_list_q8(x, probes, slab_q, slab, ids, yy_slab, yy_lmax,
+                       eq_list, scale_list, offsets, sizes,
+                       padded_sizes, k: int, P: int, W: int, Wk: int,
+                       n: int):
+    """The INT8 sibling of :func:`_fine_scan_list`: the device-built
+    schedule carries each entry's per-list scale (``scale_list`` [L])
+    into :func:`_list_major_scan_q8`."""
+    sched, n_cells, scale_l = _device_list_schedule(
+        probes, sizes, offsets, Wk, slab_q.shape[0], n, scale_list)
+    out = _list_major_scan_q8(x, sched, scale_l, n_cells, probes,
+                              slab_q, slab, ids, yy_slab, yy_lmax,
+                              eq_list, offsets, padded_sizes, k, P, W,
+                              Wk)
+    return out + (_chunk_stats(probes, sizes, n_cells),)
 
 
 def _list_major_chunk(index: IvfFlatIndex, nq: int) -> int:
@@ -773,10 +865,12 @@ def resolve_fine_scan(index: IvfFlatIndex, nq: int, k: int, P: int,
 
     ``auto`` consults the schema-5 ``fine_scan`` tune-table column
     (:func:`raft_tpu.tune.ivf.fine_scan_config`) first, then falls to
-    the cost-model crossover on the index's ACTUAL probed-list-size
+    the cost-model crossover: on a caller's actual probe table
+    (``probes_np``) where it holds one, else on the index's list-size
     histogram (:func:`~raft_tpu.observability.costmodel.
     choose_fine_scan` over :func:`~raft_tpu.observability.costmodel.
-    ivf_traffic_model`)."""
+    ivf_traffic_model`, priced once per call shape) — what the served
+    search decides from, its probe table staying on the device."""
     from raft_tpu.observability.costmodel import (DB_DTYPE_BYTES,
                                                   FINE_SCAN_MARGIN,
                                                   choose_fine_scan,
@@ -847,11 +941,15 @@ def resolve_fine_scan(index: IvfFlatIndex, nq: int, k: int, P: int,
                 * d * 4.0
         return "list" if gather > FINE_SCAN_MARGIN * max(stream, 1.0) \
             else "query"
-    model = ivf_traffic_model(
-        nq, index.n_rows, d, k, index.n_lists, P, W, index.slab_rows,
-        db_dtype=index.db_dtype if quant else "f32",
-        list_sizes=sizes, padded_sizes=padded)
-    return choose_fine_scan(model)
+    pick = index._fine_scan_picks.get((nq, k, P, W))
+    if pick is None:
+        model = ivf_traffic_model(
+            nq, index.n_rows, d, k, index.n_lists, P, W, index.slab_rows,
+            db_dtype=index.db_dtype if quant else "f32",
+            list_sizes=sizes, padded_sizes=padded)
+        pick = index._fine_scan_picks[(nq, k, P, W)] = \
+            choose_fine_scan(model)
+    return pick
 
 
 def warm_fine_scan(res, index: IvfFlatIndex, nq: int, k: int,
@@ -860,26 +958,24 @@ def warm_fine_scan(res, index: IvfFlatIndex, nq: int, k: int,
     queries can reach: the query-major gather programs (through the
     public wrapper, so its chunking/rerun programs warm too) and, at
     each list-major chunk size the bucket runs (:func:`_list_major_chunk`),
-    the one list-major program every schedule of that chunk runs
-    (:func:`_kernel_schedule` pads them all to one length and streams
-    only their own cells), plus the chunk's certificate-failure rerun
-    (its tile operands and row merge). Called from the snapshot warmup
-    so a live request can never pay a compile whichever way the
-    :func:`resolve_fine_scan` crossover lands. Returns the count of
+    the one list-major program every probe table of that chunk runs
+    (:func:`_fine_scan_list`: its device-built schedule is padded to
+    one length and streams only its own cells), plus the chunk's
+    certificate-failure rerun (its tile operands and row merge).
+    Called from the snapshot warmup so a live request can never pay a
+    compile whichever way the :func:`resolve_fine_scan` crossover
+    lands. Returns the count of
     list-major scan programs warmed (0 = the bucket is outside the
     list-major envelope).
 
     The query-major chunk's XLA cost is captured here, once per bucket
     shape, through ``res.profiler.capture_fn`` — never on a live
     search."""
-    from raft_tpu.ops.fine_scan_pallas import pad_window
-
     P = min(max(1, int(n_probes)), index.n_lists)
     if P >= index.n_lists or nq < 1:
         return 0            # the degenerate-exact plane — one schedule
     res = ensure_resources(res)
     W = index.probe_window
-    Wk = pad_window(W)
     d = index.d_orig
     x0 = np.zeros((nq, d), np.float32)
     out = search_ivf_flat(res, index, x0, k, n_probes=P,
@@ -898,28 +994,14 @@ def warm_fine_scan(res, index: IvfFlatIndex, nq: int, k: int,
     if resolve_fine_scan(index, nq, k, P, W, "list", chunk=chunk) \
             != "list":
         return 0
-    host = _list_host(index)
-    n = _schedule_len(index)
-    sched = np.zeros((4, n), np.int32)
-    sched[3] = -1
     sizes = sorted({min(nq, chunk), nq % chunk or min(nq, chunk)})
     for rows in sizes:
         xc = jnp.zeros((rows, d), jnp.float32)
         probes0 = jnp.zeros((rows, P), jnp.int32)
-        if index.db_dtype == "int8":
-            out = _fine_scan_list_q8(
-                xc, jnp.asarray(sched), jnp.ones(n, jnp.float32), 1,
-                probes0, index.slab_q, index.slab, index.ids,
-                index.yy_slab, host["yy_lmax"], host["eq_list"],
-                index.offsets, index.padded_sizes, k=k, P=P, W=W, Wk=Wk)
-        else:
-            out = _fine_scan_list(
-                xc, jnp.asarray(sched), 1, probes0, index.slab,
-                index.ids, index.yy_slab, index.offsets,
-                index.padded_sizes, host["yy_lmax"], k=k, P=P, W=W, Wk=Wk)
+        vals, ids_c, ok, _, _ = _list_major_dispatch(
+            index, xc, probes0, k, P, W)
         # the rerun of a chunk whose first and last rows fail: both
         # tile shapes it can cut, and the merge at each
-        vals, ids_c, ok, _ = out
         ok_h = np.ones(rows, bool)
         ok_h[[0, -1]] = False
         jax.block_until_ready(_rerun_failed_tiles(
@@ -1109,53 +1191,50 @@ def _rerun_failed_tiles(index: IvfFlatIndex, x, probes, ok, ok_h, vals,
     return vals, ids_c
 
 
-def _search_list_major(res, index: IvfFlatIndex, x, probes,
-                       probes_host, k: int, P: int, W: int, chunk: int):
-    """The list-major driver: per chunk of ``chunk`` rows
-    (:func:`_list_major_chunk`), invert the probe table into the list
-    schedule, run the stream-once kernel, and rerun the query-major
-    tiles that hold a certificate-failing row through the query-major
-    scan — the returned ids are bit-identical to the query-major oracle
-    either way. A search the chunk covers passes its operands whole."""
+def _list_major_dispatch(index: IvfFlatIndex, x, probes, k: int,
+                         P: int, W: int):
+    """Dispatch one chunk's list-major program (:func:`_fine_scan_list`
+    or its int8 sibling) over the index's operands: (vals, ids,
+    certified, margin, stats), all on the device."""
     from raft_tpu.ops.fine_scan_pallas import pad_window
 
-    Wk = pad_window(W)
     host = _list_host(index)
-    quant = index.db_dtype == "int8"
+    static = dict(k=k, P=P, W=W, Wk=pad_window(W), n=_schedule_len(index))
+    if index.db_dtype == "int8":
+        return _fine_scan_list_q8(
+            x, probes, index.slab_q, index.slab, index.ids,
+            index.yy_slab, host["yy_lmax"], host["eq_list"],
+            host["scale_l"], index.offsets, index.sizes,
+            index.padded_sizes, **static)
+    return _fine_scan_list(x, probes, index.slab, index.ids,
+                           index.yy_slab, index.offsets, index.sizes,
+                           index.padded_sizes, host["yy_lmax"], **static)
+
+
+def _search_list_major(res, index: IvfFlatIndex, x, probes, k: int,
+                       P: int, W: int, chunk: int):
+    """The list-major driver: per chunk of ``chunk`` rows
+    (:func:`_list_major_chunk`), one program builds the list schedule
+    on the device from the chunk's probe table and runs the stream-once
+    kernel; one host copy (``ann.certificate_sync``) brings back the
+    certificate and the chunk's probed rows, and the query-major tiles
+    that hold a certificate-failing row rerun through the query-major
+    scan — the returned ids are bit-identical to the query-major oracle
+    either way. A search the chunk covers passes its operands whole.
+    Returns (vals, ids, probed rows)."""
     nq = x.shape[0]
 
     def run_chunk(s0: int, s1: int):
-        with span("ann.fine_scan_plan"):
-            sched = build_list_schedule(index, probes_host[s0:s1])
-            full, scale, n_cells = _kernel_schedule(index, sched)
-            if s0 == 0:
-                emit_marker("ivf_fine_scan_schedule", schedule="list",
-                            lists_probed=sched.n_lists_probed,
-                            q_max=sched.q_max, cells=n_cells,
-                            stream_rows=sched.stream_rows,
-                            chunk_rows=s1 - s0,
-                            db_dtype=index.db_dtype)
-            sched_d = jnp.asarray(full)
-            scale_d = jnp.asarray(scale) if quant else None
         with span("ann.fine_scan"):
             if s1 - s0 == nq:
                 xs, pr = x, probes
             else:
                 xs, pr = x[s0:s1], probes[s0:s1]
-            if quant:
-                vals, ids_c, ok, margin = _fine_scan_list_q8(
-                    xs, sched_d, scale_d, n_cells, pr, index.slab_q,
-                    index.slab, index.ids, index.yy_slab,
-                    host["yy_lmax"], host["eq_list"], index.offsets,
-                    index.padded_sizes, k=k, P=P, W=W, Wk=Wk)
-            else:
-                vals, ids_c, ok, margin = _fine_scan_list(
-                    xs, sched_d, n_cells, pr, index.slab, index.ids,
-                    index.yy_slab, index.offsets, index.padded_sizes,
-                    host["yy_lmax"], k=k, P=P, W=W, Wk=Wk)
+            vals, ids_c, ok, margin, stats = _list_major_dispatch(
+                index, xs, pr, k, P, W)
         explain.note_margin("ann.search_ivf_flat", margin)
         with span("ann.certificate_sync"):
-            ok_h = np.asarray(ok)
+            ok_h, stats_h = jax.device_get((ok, stats))
             n_fail = int(ok_h.size - np.count_nonzero(ok_h))
             # same host sync the q8 gather path already pays — the
             # list-major slice of the certificate/fixup evidence plane
@@ -1166,6 +1245,10 @@ def _search_list_major(res, index: IvfFlatIndex, x, probes,
                                rerun=bool(n_fail),
                                db_dtype=index.db_dtype,
                                fine_scan="list")
+        if s0 == 0:
+            emit_marker("ivf_fine_scan_schedule", schedule="list",
+                        cells=int(stats_h[-1]), chunk_rows=s1 - s0,
+                        db_dtype=index.db_dtype)
         if n_fail:
             # pool-completeness certificate failed: the true top-k
             # (or one of its ties) may hide outside the 256-slot pool
@@ -1177,14 +1260,43 @@ def _search_list_major(res, index: IvfFlatIndex, x, probes,
                              rerun_rows=n_fail)
                 vals, ids_c = _rerun_failed_tiles(
                     index, xs, pr, ok, ok_h, vals, ids_c, k, P, W)
-        return vals, ids_c
+        return vals, ids_c, int(stats_h[:-1].sum(dtype=np.int64))
 
     if nq <= chunk:
         return run_chunk(0, nq)
     outs = [run_chunk(s, min(s + chunk, nq))
             for s in range(0, nq, chunk)]
     return (jnp.concatenate([o[0] for o in outs]),
-            jnp.concatenate([o[1] for o in outs]))
+            jnp.concatenate([o[1] for o in outs]),
+            sum(o[2] for o in outs))
+
+
+def _fetch_probes(res, probes, reason: str) -> np.ndarray:
+    """Bring a search's probe table to the host, counted by
+    :data:`PROBE_FETCH` under ``reason``."""
+    with span("ann.probe_fetch"):
+        out = np.asarray(probes)
+    res.metrics.counter(
+        PROBE_FETCH, {"reason": reason},
+        help="IVF searches whose probe table came to the host").inc()
+    return out
+
+
+def _note_search(res, base, nq: int, k: int, P: int, sharded: bool,
+                 probed_rows: Optional[int] = None) -> None:
+    """Count a search's probed rows (:data:`PROBED_ROWS`, where known)
+    and emit its ``ivf_search`` flight marker, the probed fraction
+    riding along only where the count is known: no sync of its own."""
+    if probed_rows is not None:
+        res.metrics.counter(
+            PROBED_ROWS, help="Database rows named by the IVF fine "
+                              "scan's probe tables").inc(probed_rows)
+    if get_flight_recorder().enabled:
+        frac = ({} if probed_rows is None else
+                {"probed_frac": round(
+                    probed_rows / max(1, nq * base.n_rows), 6)})
+        emit_marker("ivf_search", nq=nq, k=k, n_probes=P,
+                    n_lists=base.n_lists, sharded=bool(sharded), **frac)
 
 
 @instrument("ann.search_ivf_flat")
@@ -1212,8 +1324,12 @@ def search_ivf_flat(res, index, queries, k: int,
     queries probing it (the ``ops.fine_scan_pallas`` kernels — f32 ids
     certified bit-identical to the query-major scan; int8 id sets
     identical, ties canonicalized to f32 position order), ``auto`` runs
-    the :func:`resolve_fine_scan` cost-model crossover on the index's
-    actual probed-list histogram. A list-major dispatch that raises a
+    the :func:`resolve_fine_scan` tuned table or cost-model crossover
+    on the index's list-size histogram. The list-major schedule is built
+    on the device from the device-resident probe table, inside the
+    chunk's scan program: the probe table comes to the host only under
+    explain capture or for a search that runs query-major. A list-major
+    dispatch that raises a
     :class:`~raft_tpu.core.error.DeviceError` (an injected fault or a
     classified device failure) degrades back to query-major with a
     logged degradation (fault site ``fine_scan_list``); any other
@@ -1270,31 +1386,24 @@ def search_ivf_flat(res, index, queries, k: int,
     with span("ann.coarse_probe"):
         probes = _coarse_probe(res, base.centroids, x, P)   # [nq, P]
 
-    # the probe table comes to the host once, for the schedule
-    # (resolve_fine_scan's crossover, build_list_schedule) and the
-    # probed-rows count; an explicitly query-major or sharded call
-    # keeps it on the device
+    # the probe table stays on the device: the list-major schedule is
+    # built from it there and the program counts its probed rows. It
+    # comes to the host (ann.probe_fetch, counted by reason) only under
+    # explain capture, or for the probed-rows count of a search the
+    # resolution (or a degraded list-major dispatch) sends query-major;
+    # an explicitly query-major or sharded call never fetches it
     req = fine_scan if fine_scan is not None \
         else env.get("RAFT_TPU_IVF_FINE_SCAN")
-    probes_host = probed_rows = None
-    if req != "query" and not sharded:
-        with span("ann.probe_fetch"):
-            probes_host = np.asarray(probes)
-        probed_rows = int(base._np_sizes[probes_host].sum())
-        res.metrics.counter(
-            PROBED_ROWS, help="Database rows named by the IVF fine "
-                              "scan's probe tables").inc(probed_rows)
-
+    probes_host = None
     if explain.active() is not None:
         # explain capture: probed list ids (first query's probe set —
         # the record is per-request-batch) + the probed-size histogram
-        # and pool width; the host transfer only happens under capture
-        pr_np = (probes_host if probes_host is not None
-                 else np.asarray(probes))
-        sz = np.asarray(base.sizes)[pr_np]
+        # and pool width
+        probes_host = _fetch_probes(res, probes, "explain")
+        sz = base._np_sizes[probes_host]
         explain.note(plane="ivf_flat", n_probes=P, n_lists=L, k=k,
                      db_dtype=base.db_dtype,
-                     probed_lists=pr_np[0].tolist(),
+                     probed_lists=probes_host[0].tolist(),
                      probed_rows=int(sz.sum()),
                      probed_size_hist={
                          "min": int(sz.min()), "p50": float(
@@ -1304,38 +1413,27 @@ def search_ivf_flat(res, index, queries, k: int,
                                      P * index.probe_window)
                                  if base.db_dtype == "int8" else k))
 
-    if get_flight_recorder().enabled:
-        # the probed fraction rides the marker only where the host
-        # already holds the probe table: no sync of its own
-        frac = ({} if probed_rows is None else
-                {"probed_frac": round(
-                    probed_rows / max(1, nq * base.n_rows), 6)})
-        emit_marker("ivf_search", nq=nq, k=k, n_probes=P, n_lists=L,
-                    sharded=bool(sharded), **frac)
-
     if sharded:
+        _note_search(res, base, nq, k, P, sharded=True)
         return _search_sharded(res, index, x, probes, k, P, W, merge)
 
     # fine-scan schedule: env/arg request resolved against the
-    # list-major envelope + the cost-model crossover on the ACTUAL
-    # probe table (resolve_fine_scan). A list-major failure — real or
-    # injected at the fine_scan_list site — degrades back to the
-    # query-major scan for this call, with identical ids. The
-    # list-major chunk follows its own kernel's VMEM envelope; the
-    # query-major one, the gather tile.
+    # list-major envelope, then the tuned table or the cost-model
+    # crossover on the index's list-size histogram (resolve_fine_scan).
+    # A list-major failure — real or injected at the fine_scan_list
+    # site — degrades back to the query-major scan for this call, with
+    # identical ids. The list-major chunk follows its own kernel's VMEM
+    # envelope; the query-major one, the gather tile.
     with span("ann.fine_scan_plan"):
         chunk = _list_major_chunk(index, nq)
-        schedule = resolve_fine_scan(index, nq, k, P, W, req,
-                                     probes_np=probes_host, chunk=chunk)
-        if schedule != "list":
-            starts, psizes = _probe_windows(index.offsets,
-                                            index.padded_sizes, probes)
+        schedule = resolve_fine_scan(index, nq, k, P, W, req, chunk=chunk)
     explain.note(fine_scan=schedule)
+    fetch_reason = "query"
     if schedule == "list":
         try:
             fault_point("fine_scan_list")
-            return _search_list_major(res, index, x, probes,
-                                      probes_host, k, P, W, chunk)
+            vals, ids, probed_rows = _search_list_major(
+                res, index, x, probes, k, P, W, chunk)
         except DeviceError as e:
             # injected faults and classified device failures only: a
             # kernel that fails to compile or lower propagates
@@ -1348,9 +1446,21 @@ def search_ivf_flat(res, index, queries, k: int,
             log_warn("list-major fine scan failed (%s: %s) — "
                      "degrading to the query-major scan for this "
                      "call", type(e).__name__, e)
-        starts, psizes = _probe_windows(index.offsets,
-                                        index.padded_sizes, probes)
+            fetch_reason = "degrade"
+        else:
+            _note_search(res, base, nq, k, P, sharded=False,
+                         probed_rows=probed_rows)
+            return vals, ids
 
+    probed_rows = None
+    if req != "query":
+        if probes_host is None:
+            probes_host = _fetch_probes(res, probes, fetch_reason)
+        probed_rows = int(base._np_sizes[probes_host].sum())
+    _note_search(res, base, nq, k, P, sharded=False,
+                 probed_rows=probed_rows)
+    starts, psizes = _probe_windows(index.offsets, index.padded_sizes,
+                                    probes)
     chunk = _query_major_rows(P, W, x.shape[1])
     if nq <= chunk:
         return _query_major_chunk(index, x, starts, psizes, k, P, W)

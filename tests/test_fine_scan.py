@@ -3,8 +3,9 @@ bit-exact id parity vs the query-major oracle across the full matrix
 (f32/int8 × ragged/imbalanced lists × degenerate-exact × the
 single-hot-list adversarial case), the fine_scan_list degradation rung
 (injected error → query-major with a logged degradation + identical
-ids), the schedule builder's group-table invariants, the
-resolve_fine_scan envelope/crossover, the histogram-aware traffic
+ids), the host schedule builder's invariants and the device
+builder's parity with it, the served search's probe table staying on
+the device, the resolve_fine_scan envelope/crossover, the histogram-aware traffic
 model, the schema-5 fine_scan tune column, and the bench_report
 overread gate."""
 
@@ -87,8 +88,7 @@ def test_list_major_id_parity(fixture, dtype, P):
 def test_single_hot_list_adversarial(fixture):
     """Every query probes the SAME list (queries drawn from one
     centroid's neighborhood, P=1) — the maximal-overread case the
-    list-major schedule exists for, and the maximal-group-width case
-    for the query-group table."""
+    list-major schedule exists for."""
     res, X, _, idx, idx8 = fixture
     centroid = np.asarray(idx.centroids)[0]
     Qh = (centroid[None, :]
@@ -109,7 +109,8 @@ def test_single_hot_list_adversarial(fixture):
     probes = np.asarray(_coarse_probe(res, idx.centroids, Qh, 1))
     sched = build_list_schedule(idx, probes)
     assert sched.n_lists_probed == len(np.unique(probes))
-    assert sched.q_max >= 32 and sched.q_max % 8 == 0
+    lids = sched.sched[3]
+    assert set(lids[lids >= 0].tolist()) == set(np.unique(probes).tolist())
 
 
 def test_degenerate_exact_unchanged(fixture):
@@ -248,17 +249,272 @@ def test_schedule_builder_invariants(fixture):
         assert st + off == offs[lid]
         assert lsize == sizes[lid]
         assert off + lsize <= Wk
-    # the query-group table: one row per probed list, every (q, list)
-    # probe accounted for exactly once, q_max padded to the 8 quantum
-    assert sched.group.shape == (Lp, sched.q_max)
-    assert sched.q_max % 8 == 0
-    assert sched.group_mask.sum() == (probes >= 0).sum()
-    inv = {int(l): g for g, l in enumerate(s[3, :Lp])}
-    for q in range(probes.shape[0]):
-        for l in probes[q]:
-            g = inv[int(l)]
-            hits = sched.group[g][sched.group_mask[g]]
-            assert q in hits
+    # every probed list takes an entry, in ascending id order, and the
+    # real entries lead the pads
+    assert np.array_equal(s[3, :Lp], np.unique(probes))
+    assert np.all(s[3, Lp:] == -1)
+
+
+# ------------------------------------------- device schedule builder
+def _probe_table(case, index, Q, res):
+    """A seeded probe table for the schedule parity cases."""
+    from raft_tpu.ann.ivf_flat import _coarse_probe
+
+    L = index.n_lists
+    r = np.random.default_rng(7)
+    if case == "duplicates":       # rows share most of their lists
+        return r.integers(0, 4, (48, 5)).astype(np.int32)
+    if case == "pads":             # the kernel's −2 pad probes
+        t = r.integers(0, L, (40, 6)).astype(np.int32)
+        t[r.random(t.shape) < 0.4] = -2
+        t[-8:] = -2
+        return t
+    if case == "hot":              # every row probes one list
+        return np.full((32, 1), 5, np.int32)
+    if case == "every_list":       # each row probes every list
+        return np.stack([r.permutation(L) for _ in range(16)]
+                        ).astype(np.int32)
+    return np.asarray(_coarse_probe(res, index.centroids, Q, 3))
+
+
+@pytest.mark.parametrize("dtype", ["f32", "int8"])
+@pytest.mark.parametrize("case", ["duplicates", "pads", "long_lists",
+                                  "hot", "every_list"])
+def test_device_schedule_matches_host_builder(fixture, monkeypatch,
+                                              dtype, case):
+    """The device-built schedule equals the host builder's padded
+    kernel operands entry for entry: ``sched``, the streamed cell count
+    and the int8 per-entry scales."""
+    import jax.numpy as jnp
+
+    import raft_tpu.ops.fine_scan_pallas as fsp
+    from raft_tpu.ann.ivf_flat import (_device_list_schedule,
+                                       _kernel_schedule, _list_host,
+                                       _schedule_len)
+
+    res, _, Q, idx, idx8 = fixture
+    index = idx8 if dtype == "int8" else idx
+    if case == "long_lists":       # lists split into 128-row windows
+        monkeypatch.setattr(fsp, "SEGMENT_ROWS", 128)
+        assert index.probe_window > 128
+    probes = _probe_table(case, index, Q, res)
+    full, scale, n_cells = _kernel_schedule(
+        index, build_list_schedule(index, probes))
+    scales = (jnp.asarray(_list_host(index)["scale"])
+              if dtype == "int8" else None)
+    out = jax.jit(_device_list_schedule, static_argnums=(3, 4, 5))(
+        jnp.asarray(probes), index.sizes, index.offsets,
+        fsp.pad_window(index.probe_window), index.slab_rows,
+        _schedule_len(index), scales)
+    assert np.asarray(out[0]).dtype == np.int32
+    assert np.array_equal(np.asarray(out[0]), full)
+    assert int(out[1]) == n_cells
+    if dtype == "int8":
+        assert np.array_equal(np.asarray(out[2]), scale)
+
+
+def _host_schedule_programs(monkeypatch, index):
+    """Route the list-major dispatch through the host schedule builder
+    (:func:`build_list_schedule` + :func:`_kernel_schedule`): the
+    schedule the served search uploaded before it was built on the
+    device, with everything else of the program unchanged."""
+    import jax.numpy as jnp
+
+    from raft_tpu.ann import ivf_flat
+    from raft_tpu.ann.ivf_flat import _chunk_stats, _kernel_schedule
+
+    static = ("k", "P", "W", "Wk")
+    scan = jax.jit(ivf_flat._list_major_scan, static_argnames=static)
+    scan_q8 = jax.jit(ivf_flat._list_major_scan_q8,
+                      static_argnames=static)
+
+    def operands(probes):
+        full, scale, n_cells = _kernel_schedule(
+            index, build_list_schedule(index, np.asarray(probes)))
+        return jnp.asarray(full), jnp.asarray(scale), jnp.int32(n_cells)
+
+    def host_f32(x, probes, slab, ids, yy_slab, offsets, sizes, padded,
+                 yy_lmax, n, **kw):
+        sched, _, n_cells = operands(probes)
+        out = scan(x, sched, n_cells, probes, slab, ids, yy_slab,
+                   offsets, padded, yy_lmax, **kw)
+        return out + (_chunk_stats(probes, sizes, n_cells),)
+
+    def host_q8(x, probes, slab_q, slab, ids, yy_slab, yy_lmax, eq_list,
+                scale_list, offsets, sizes, padded, n, **kw):
+        sched, scale, n_cells = operands(probes)
+        out = scan_q8(x, sched, scale, n_cells, probes, slab_q, slab,
+                      ids, yy_slab, yy_lmax, eq_list, offsets, padded,
+                      **kw)
+        return out + (_chunk_stats(probes, sizes, n_cells),)
+
+    monkeypatch.setattr(ivf_flat, "_fine_scan_list", host_f32)
+    monkeypatch.setattr(ivf_flat, "_fine_scan_list_q8", host_q8)
+
+
+@pytest.mark.parametrize("dtype", ["f32", "int8"])
+@pytest.mark.parametrize("chunk", [None, 8])
+def test_device_schedule_search_matches_host_schedule(fixture,
+                                                      monkeypatch,
+                                                      dtype, chunk):
+    """The served list-major search answers bit for bit as the same
+    search over the host-built schedule, values and ids, at the f32 and
+    int8 slabs, as one chunk and as 8-row chunks."""
+    from raft_tpu.ann import ivf_flat
+
+    res, _, Q, idx, idx8 = fixture
+    index = idx8 if dtype == "int8" else idx
+    if chunk is not None:
+        monkeypatch.setattr(ivf_flat, "_list_major_chunk",
+                            lambda index, nq: chunk)
+    vd, idd = search_ivf_flat(res, index, Q, 10, n_probes=4,
+                              fine_scan="list")
+    _host_schedule_programs(monkeypatch, index)
+    vh, ih = search_ivf_flat(res, index, Q, 10, n_probes=4,
+                             fine_scan="list")
+    assert np.array_equal(np.asarray(idd), np.asarray(ih))
+    assert np.array_equal(np.asarray(vd), np.asarray(vh))
+
+
+def _fetches():
+    from raft_tpu.ann.ivf_flat import PROBE_FETCH
+    from raft_tpu.observability import get_registry
+
+    reg = get_registry()
+    return {r: reg.counter(PROBE_FETCH, {"reason": r}).value
+            for r in ("explain", "query", "degrade")}
+
+
+def _probed_rows():
+    from raft_tpu.ann.ivf_flat import PROBED_ROWS
+    from raft_tpu.observability import get_registry
+
+    return get_registry().counter(PROBED_ROWS).value
+
+
+def test_list_major_search_fetches_no_probe_table(fixture, monkeypatch):
+    """A list-major search brings no probe table to the host: no
+    ``ann.probe_fetch`` span, no fetch counted, and the probe array is
+    never turned into a host array; its probed rows still count, read
+    with the certificate."""
+    from raft_tpu.ann import ivf_flat
+    from raft_tpu.ann.ivf_flat import _coarse_probe
+    from raft_tpu.observability import FlightRecorder, set_flight_recorder
+
+    res, _, Q, idx, _ = fixture
+    probes = np.asarray(_coarse_probe(res, idx.centroids, Q, 3))
+    want = int(np.asarray(idx.sizes)[probes].sum())
+    real = ivf_flat._coarse_probe
+    handed = []
+
+    def probe(*a, **kw):
+        handed.append(real(*a, **kw))
+        return handed[-1]
+
+    asarray = np.asarray
+
+    def guarded(a, *args, **kw):
+        assert not any(a is p for p in handed), "probe table fetched"
+        return asarray(a, *args, **kw)
+
+    monkeypatch.setattr(ivf_flat, "_coarse_probe", probe)
+    monkeypatch.setattr(np, "asarray", guarded)
+    rec = FlightRecorder(capacity=4096)
+    prev = set_flight_recorder(rec)
+    before, rows0 = _fetches(), _probed_rows()
+    try:
+        search_ivf_flat(res, idx, Q, 10, n_probes=3, fine_scan="list")
+    finally:
+        set_flight_recorder(prev)
+        monkeypatch.setattr(np, "asarray", asarray)
+    assert handed
+    names = [e["name"] for e in rec.events() if e["kind"] == "span"]
+    assert "ann.fine_scan" in names and "ann.probe_fetch" not in names
+    assert _fetches() == before
+    assert _probed_rows() - rows0 == want
+
+
+def test_probe_fetch_counted_by_reason(fixture):
+    """Each search that still brings its probe table to the host counts
+    once, under why: explain capture, a search resolved query-major, a
+    degraded list-major dispatch. An explicitly query-major search
+    fetches none."""
+    from raft_tpu.observability.explain import capture
+
+    res, _, Q, idx, _ = fixture
+    W = idx.probe_window
+
+    def delta(**search):
+        before = _fetches()
+        search_ivf_flat(res, idx, Q, 10, n_probes=3, **search)
+        return {r: v - before[r] for r, v in _fetches().items()
+                if v != before[r]}
+
+    assert delta(fine_scan="list") == {}
+    assert delta(fine_scan="query") == {}
+    with capture(rids=[1]):
+        assert delta(fine_scan="list") == {"explain": 1}
+    # k past the list-major pool: an explicit list request resolves
+    # query-major and fetches the table for its probed-rows count
+    assert resolve_fine_scan(idx, 48, _LIST_K_MAX + 1, 3, W,
+                             "list") == "query"
+    before = _fetches()
+    search_ivf_flat(res, idx, Q, _LIST_K_MAX + 1, n_probes=3,
+                    fine_scan="list")
+    assert _fetches()["query"] == before["query"] + 1
+    resilience.configure_faults("fine_scan_list:error")
+    assert delta(fine_scan="list") == {"degrade": 1}
+
+
+def test_served_list_major_fetches_no_probe_table(fixture):
+    """Behind the serving engine, after its warm-up, every batch runs
+    the list-major schedule and no search brings its probe table to the
+    host, while the probed-rows counter keeps counting."""
+    from raft_tpu.serving import ServingEngine
+
+    res, X, Q, _, _ = fixture
+    eng = ServingEngine(X, k=10, buckets=(8, 32), flush_interval_s=0.002,
+                        algorithm="ivf_flat", n_lists=12, n_probes=3,
+                        explain_frac=0.0)
+    eng.start()
+    try:
+        before, rows0 = _fetches(), _probed_rows()
+        for q in range(4):
+            eng.query(Q[q:q + 1], timeout=120)
+        eng.query(Q[:20], timeout=120)
+    finally:
+        eng.stop()
+    assert _fetches() == before
+    assert _probed_rows() > rows0
+
+
+def test_served_shape_resolves_list_from_the_histogram(monkeypatch):
+    """Without a probe table the served search decides from the tuned
+    table, else the list-size histogram model: at the sift1m-ivf
+    deployment (1M x 128 f32, 1,024 lists, n_probes 32, list sizes
+    331–2,766 rows) every serving bucket resolves list-major."""
+    import types
+
+    from raft_tpu.ann.ivf_flat import _list_major_chunk
+    from raft_tpu.tune import fine_scan_config
+
+    r = np.random.default_rng(11)
+    sizes = np.clip(r.lognormal(np.log(964), 0.3, 1024), 331,
+                    2766).astype(np.int64)
+    sizes[:3] = (331, 964, 2766)
+    padded = -(-sizes // 8) * 8
+    stub = types.SimpleNamespace(
+        n_lists=1024, n_rows=int(sizes.sum()), d_orig=128,
+        db_dtype="f32", row_quantum=8, slab_rows=int(padded.sum()),
+        probe_window=int(padded.max()), _np_sizes=sizes,
+        _np_padded=padded, _fine_scan_picks={})
+    monkeypatch.delenv("RAFT_TPU_IVF_FINE_SCAN", raising=False)
+    assert fine_scan_config(1024, 32) is None
+    for bucket in (32, 128, 512):
+        chunk = _list_major_chunk(stub, bucket)
+        assert resolve_fine_scan(stub, bucket, 10, 32,
+                                 stub.probe_window, None,
+                                 chunk=chunk) == "list"
 
 
 # ------------------------------------------- chooser + model
@@ -378,12 +634,37 @@ def test_resolve_consults_tuned_table(fixture, tmp_path, monkeypatch):
 
 
 # ------------------------------------------- serving warmup
-def test_warm_fine_scan_compiles_rungs(fixture):
-    res, _, _, idx, _ = fixture
-    rungs = warm_fine_scan(res, idx, 16, 5, 3)
+def _warm_then_search(fixture, dtype):
+    """Warm one bucket, then search it live: the list-major program the
+    live path runs (its device-built schedule included) must already be
+    compiled."""
+    from raft_tpu.ann import ivf_flat
+
+    res, _, Q, idx, idx8 = fixture
+    index = idx8 if dtype == "int8" else idx
+    program = (ivf_flat._fine_scan_list_q8 if dtype == "int8"
+               else ivf_flat._fine_scan_list)
+    rungs = warm_fine_scan(res, index, 16, 5, 3)
     assert rungs >= 1
+    warmed = program._cache_size()
+    out = search_ivf_flat(res, index, Q[:16], 5, n_probes=3,
+                          fine_scan="list")
+    jax.block_until_ready(out)
+    assert program._cache_size() == warmed
     # degenerate geometry has one schedule — nothing to warm
-    assert warm_fine_scan(res, idx, 16, 5, idx.n_lists) == 0
+    assert warm_fine_scan(res, index, 16, 5, index.n_lists) == 0
+
+
+def test_warm_fine_scan_compiles_rungs(fixture):
+    """After the warm-up a live f32 bucket search compiles no new
+    list-major program."""
+    _warm_then_search(fixture, "f32")
+
+
+def test_warm_fine_scan_compiles_rungs_int8(fixture):
+    """The same at the int8 slab: the warm-up compiles the int8 program
+    with its device-built per-entry scales."""
+    _warm_then_search(fixture, "int8")
 
 
 # ------------------------------------------- bench_report gate

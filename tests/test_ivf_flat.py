@@ -356,8 +356,8 @@ def test_ivf_build_validation(fixture):
 # ------------------------------------------------ spans and counters
 #: the host boundaries of a search, each a span under the search's own
 _CHILD_SPANS = {
-    "list": {"ann.coarse_probe", "ann.probe_fetch", "ann.fine_scan_plan",
-             "ann.fine_scan", "ann.certificate_sync"},
+    "list": {"ann.coarse_probe", "ann.fine_scan_plan", "ann.fine_scan",
+             "ann.certificate_sync"},
     "query": {"ann.coarse_probe", "ann.fine_scan_plan", "ann.fine_scan"},
 }
 
@@ -388,9 +388,9 @@ def _probed_rows():
 def test_search_spans_cover_each_host_boundary(fixture, flight,
                                                monkeypatch, schedule):
     """One span per host boundary, each under ``ann.search_ivf_flat``,
-    one ``ann.fine_scan`` per chunk; the probed-rows counter comes from
-    the host probe table, which an explicitly query-major call never
-    fetches."""
+    one ``ann.fine_scan`` per chunk; the list-major search fetches no
+    probe table (its program counts the probed rows, read with the
+    certificate), and an explicitly query-major call counts none."""
     from raft_tpu.ann import ivf_flat
     from raft_tpu.ann.ivf_flat import _coarse_probe
 
@@ -413,7 +413,7 @@ def test_search_spans_cover_each_host_boundary(fixture, flight,
     host_rows = int(np.asarray(idx.sizes)[probes].sum())
     if schedule == "list":
         assert names.count("ann.certificate_sync") == 4
-        assert names.count("ann.fine_scan_plan") == 1 + 4
+        assert names.count("ann.fine_scan_plan") == 1
         assert _probed_rows() - rows0 == host_rows
     else:
         assert _probed_rows() == rows0
@@ -434,8 +434,8 @@ def test_list_major_rerun_is_one_span_per_chunk(fixture, flight,
     scan = ivf_flat._fine_scan_list
 
     def uncertified(*a, **kw):
-        vals, ids, ok, margin = scan(*a, **kw)
-        return vals, ids, jnp.zeros_like(ok), margin
+        vals, ids, ok, margin, stats = scan(*a, **kw)
+        return vals, ids, jnp.zeros_like(ok), margin, stats
 
     monkeypatch.setattr(ivf_flat, "_fine_scan_list", uncertified)
     flight.clear()
@@ -505,15 +505,15 @@ def test_list_major_chunk_follows_the_vmem_envelope(fixture):
 
 def test_list_major_search_is_one_chunk(fixture, flight):
     """An unforced 32-row list-major search fits one kernel call: one
-    scan dispatch, one certificate sync, the search's plan and the
-    chunk's, and the schedule marker names the chunk's rows."""
+    scan dispatch, one certificate sync, the search's one plan, and the
+    schedule marker names the chunk's rows."""
     res, _, Q, _, idx = fixture
     flight.clear()
     search_ivf_flat(res, idx, Q[:32], 10, n_probes=3, fine_scan="list")
     names = [e["name"] for e in _ann_spans(flight)]
     assert names.count("ann.fine_scan") == 1
     assert names.count("ann.certificate_sync") == 1
-    assert names.count("ann.fine_scan_plan") == 1 + 1
+    assert names.count("ann.fine_scan_plan") == 1
     marker = [e for e in flight.events()
               if e["name"] == "ivf_fine_scan_schedule"]
     assert len(marker) == 1 and marker[0]["chunk_rows"] == 32
@@ -560,8 +560,8 @@ def test_list_major_rerun_is_only_the_failed_tiles(fixture, flight,
     failed[[3, 20, 22]] = True
 
     def uncertified(*a, **kw):
-        vals, ids, ok, margin = scan(*a, **kw)
-        return vals, ids, jax.device_put(~failed), margin
+        vals, ids, ok, margin, stats = scan(*a, **kw)
+        return vals, ids, jax.device_put(~failed), margin, stats
 
     tiles = []
     operands = ivf_flat._tile_operands
@@ -604,8 +604,8 @@ def test_warm_list_major_search_compiles_nothing(fixture, monkeypatch,
         ok[[5, 30]] = False
 
         def uncertified(*a, **kw):
-            vals, ids, _, margin = scan(*a, **kw)
-            return vals, ids, jax.device_put(ok), margin
+            vals, ids, _, margin, stats = scan(*a, **kw)
+            return vals, ids, jax.device_put(ok), margin, stats
 
         monkeypatch.setattr(ivf_flat, "_fine_scan_list", uncertified)
     events = []

@@ -181,6 +181,34 @@ def test_fine_scan_list_major_compiles(chip, nqp, q8):
                  s((rows, DIM), jnp.float32), Wk=WK)
 
 
+@pytest.mark.parametrize("q8", [False, True])
+def test_fine_scan_list_program_compiles(chip, q8):
+    """The served bucket's whole list-major program: the schedule built
+    on the device from the [32, 32] probe table (sort, run starts,
+    searchsorted expansion to the padded schedule), the kernel with
+    its traced cell count, and the exact rescore and certificate."""
+    from raft_tpu.ann import ivf_flat
+
+    s = functools.partial(_spec, chip)
+    rows = N_ROWS + N_LISTS * 8
+    nq, P, W = 32, 32, 2768
+    lists = functools.partial(s, (N_LISTS,))
+    common = dict(k=10, P=P, W=W, Wk=WK, n=SCHED)
+    x, probes = s((nq, DIM), jnp.float32), s((nq, P), jnp.int32)
+    slab, ids = s((rows, DIM), jnp.float32), s((rows,), jnp.int32)
+    yy, offsets = s((rows,), jnp.float32), s((N_LISTS + 1,), jnp.int32)
+    if q8:
+        _compile(ivf_flat._fine_scan_list_q8, x, probes,
+                 s((rows, DIM), jnp.int8), slab, ids, yy,
+                 lists(jnp.float32), lists(jnp.float32),
+                 lists(jnp.float32), offsets, lists(jnp.int32),
+                 lists(jnp.int32), **common)
+    else:
+        _compile(ivf_flat._fine_scan_list, x, probes, slab, ids, yy,
+                 offsets, lists(jnp.int32), lists(jnp.int32),
+                 lists(jnp.float32), **common)
+
+
 def test_pq_scan_list_major_compiles(chip):
     from raft_tpu.ops.fused_l2_topk_pallas import vmem_budget
     from raft_tpu.ops.pq_scan_pallas import (kernel_rows,
